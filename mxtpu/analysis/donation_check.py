@@ -321,7 +321,6 @@ def check_trainer_donation(trainer, data, label,
     import jax.numpy as jnp
 
     from .. import ndarray as nd
-    from .. import random as _random
 
     data = data if isinstance(data, nd.NDArray) else nd.array(data)
     label = label if isinstance(label, nd.NDArray) else nd.array(label)
@@ -365,14 +364,10 @@ def check_trainer_donation(trainer, data, label,
             donatable_argnums=(0, 1, 2), arg_names=names,
             compile=compile)
 
-    step_fn = trainer._build_step(*sig)
-    args = [diff_leaves, aux_leaves, tuple(trainer._opt_states),
-            jnp.float32(trainer._effective_lr()), jnp.float32(1.0),
-            batch, lab, _random.next_key()]
+    step_fn, args = trainer.step_program(data, label)
     names = ["params", "aux_params", "opt_states", "lr", "t", "batch",
              "label", "rng_key"]
     if trainer._guard:
-        args.append(trainer._scale_state)
         names.append("scale_state")
 
     # step_fn is already a jax.jit stage with its donate/shardings baked

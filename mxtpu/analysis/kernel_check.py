@@ -28,8 +28,9 @@ K001        ERROR     last block dim splits an axis into chunks that are
 K002        ERROR     second-to-last block dim not a multiple of the
                       dtype's sublane tile (8 fp32 / 16 bf16 / 32 int8 —
                       the "block_size ≥ 32 for int8" rule, enforced via
-                      ``strict_dims``); size-1 and full-axis dims are
-                      otherwise exempt (padded partial tiles)
+                      ``strict_dims``); a full-axis dim is otherwise
+                      exempt (padded partial tile) — a size-1 window
+                      into a wider axis is NOT: Mosaic refuses it
 K003        ERROR     per-grid-step VMEM estimate (double-buffered in/out
                       blocks + scratch) exceeds the budget (default
                       16 MiB)
@@ -223,12 +224,14 @@ def _geometry_violations(spec: KernelSpec) -> List[Tuple[str, str, str]]:
 
     The lane/sublane rules flag tilings that split an axis into
     non-tile-aligned chunks — misaligned strided windows Mosaic cannot
-    lower.  Two exemptions, neither applying to an operand's
+    lower.  One exemption, not applying to an operand's
     ``strict_dims``: a block dim equal to the FULL array extent (no
     tiling choice exists; the hardware pads a partial tile — the
-    rep*W-lane query block, conv's H+2 rows), and a size-1
-    second-to-last dim (a single-sublane window lowers as a broadcast
-    row — the lse/scale-vector pattern).  ``strict_dims`` marks
+    rep*W-lane query block, conv's H+2 rows).  A size-1 second-to-last
+    dim is exempt only on that ground (the array's dim is 1 too): the
+    chip's compiler refuses a (1, n) window into a taller 2-D array and
+    a (1, 1, n) window into a (N, KV, n) one — the pre-PR-22 lse and
+    int8 scale specs.  ``strict_dims`` marks
     engine-CHOSEN tile parameters (head_dim, block_size, q_block): a
     sub-tile value there is the fixable defect this pass exists for —
     the ROADMAP "block_size >= 32 for int8" rule."""
@@ -256,9 +259,7 @@ def _geometry_violations(spec: KernelSpec) -> List[Tuple[str, str, str]]:
             sub = sublane_tile(op.dtype)
             second = bs[-2]
             strict_second = (len(bs) - 2) in strict
-            exempt = (not strict_second
-                      and (second == 1
-                           or (len(ar) >= 2 and second == ar[-2])))
+            exempt = not strict_second and second == ar[-2]
             if second % sub != 0 and not exempt:
                 out.append((
                     "K002", op.name,

@@ -300,10 +300,10 @@ def estimate_graph_memory(sym, known_shapes: Optional[dict] = None,
 
 # -- jaxpr path -----------------------------------------------------------
 
-_CALL_PRIMITIVES = {"pjit", "closed_call", "core_call", "xla_call",
-                    "named_call", "custom_jvp_call", "custom_vjp_call",
-                    "custom_vjp_call_jaxpr", "remat", "remat2",
-                    "checkpoint", "custom_lin"}
+# primitives that carry an inner jaxpr, by the names JAX 0.9 gives them
+# (a nested ``jax.jit`` is "jit" there; it was "pjit")
+_CALL_PRIMITIVES = {"jit", "closed_call", "call", "custom_jvp_call",
+                    "custom_vjp_call", "remat2", "custom_lin"}
 
 
 def _inner_jaxpr(eqn):
@@ -337,7 +337,7 @@ def _jaxpr_liveness_peak(jaxpr) -> int:
     accounts them as resident).  Layout ops (transpose/reshape/...)
     alias their input: they add no bytes, and extend the aliased
     value's liveness instead."""
-    import jax
+    from jax.extend.core import Literal
 
     eqns = jaxpr.eqns
     defined = set()
@@ -351,7 +351,7 @@ def _jaxpr_liveness_peak(jaxpr) -> int:
     for eqn in eqns:
         if eqn.primitive.name in _LAYOUT_PRIMS and len(eqn.outvars) == 1:
             srcs = [v for v in eqn.invars
-                    if not isinstance(v, jax.core.Literal)]
+                    if not isinstance(v, Literal)]
             out = eqn.outvars[0]
             if len(srcs) == 1 and _aval_nbytes(
                     getattr(out, "aval", None)) == _aval_nbytes(
@@ -364,13 +364,13 @@ def _jaxpr_liveness_peak(jaxpr) -> int:
     last_use: Dict[Any, int] = {}
     for n, eqn in enumerate(eqns):
         for v in eqn.invars:
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, Literal):
                 continue
             c = canon(v)
             if c in defined:
                 last_use[c] = n
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, Literal):
             c = canon(v)
             if c in defined:
                 last_use[c] = len(eqns)
@@ -389,7 +389,7 @@ def _jaxpr_liveness_peak(jaxpr) -> int:
             # again below, so subtract exactly that overlap
             out_bytes = sum(_aval_nbytes(getattr(v, "aval", None))
                             for v in inner.outvars
-                            if not isinstance(v, jax.core.Literal))
+                            if not isinstance(v, Literal))
             transient = max(0, _jaxpr_liveness_peak(inner) - out_bytes)
         elif eqn.primitive.name == "scan":
             body = _inner_jaxpr(eqn)
@@ -436,6 +436,7 @@ def estimate_jit_memory(fn, *sample_args,
     bound for a fit check.
     """
     import jax
+    from jax.extend.core import Literal
 
     closed = jax.make_jaxpr(
         fn, static_argnums=tuple(static_argnums))(*sample_args)
@@ -470,7 +471,7 @@ def estimate_jit_memory(fn, *sample_args,
         int(activation_shards), 1)
     est.output_bytes = sum(
         _aval_nbytes(getattr(v, "aval", None)) for v in jaxpr.outvars
-        if not isinstance(v, jax.core.Literal))
+        if not isinstance(v, Literal))
     est.n_values = sum(len(e.outvars) for e in jaxpr.eqns)
     return est
 

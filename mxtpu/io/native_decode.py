@@ -4,8 +4,9 @@ src/io/iter_image_recordio_2.cc).
 
 The shared library is built on demand with the in-image g++ against the
 system libjpeg the first time it is needed (and rebuilt when the source
-is newer than the binary); everything degrades gracefully to the PIL
-path when the toolchain or libjpeg is absent.
+is newer than the binary).  Where the toolchain or libjpeg is absent the
+PIL path decodes instead — after one warning that says why, so a slow
+input pipeline is never a silent one (``available()`` is the check).
 """
 
 from __future__ import annotations
@@ -67,7 +68,14 @@ def _load():
                 ctypes.c_int, ctypes.c_int, ctypes.c_void_p,
                 ctypes.c_int, ctypes.c_int]
             _lib = lib
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
+            import warnings
+
+            warnings.warn(
+                "native JPEG decoder unavailable (%s: %s) — PIL decodes "
+                "instead" % (type(e).__name__,
+                             (getattr(e, "stderr", "") or str(e)).strip()
+                             [-400:]), RuntimeWarning)
             _lib = None
         return _lib
 

@@ -558,10 +558,7 @@ def _wrap_result(res, ctx, cls=None):
     return cls(res, ctx=ctx)
 
 
-try:
-    from jax.core import Tracer as _Tracer
-except ImportError:  # pragma: no cover - jax layout drift
-    from jax._src.core import Tracer as _Tracer
+from jax.core import Tracer as _Tracer  # noqa: E402
 
 # sentinel: "this op was not bulked, dispatch it normally"
 _NOT_BULKED = object()

@@ -587,15 +587,9 @@ class _Random:
         return ndarray(jnp.where(u <= mu / (mu + x), x, mu * mu / x))
 
     def binomial(self, n, p, size=None):
-        if hasattr(jax.random, "binomial"):
-            return ndarray(jax.random.binomial(
-                self._key(), _unwrap(n), _unwrap(p),
-                self._pshape(size, n, p)).astype(jnp.int32))
-        # older jax: n Bernoulli draws summed (n must be a python int)
-        shape = self._pshape(size, p)
-        draws = jax.random.bernoulli(self._key(), _unwrap(p),
-                                     (int(n),) + shape)
-        return ndarray(draws.sum(axis=0).astype(jnp.int32))
+        return ndarray(jax.random.binomial(
+            self._key(), _unwrap(n), _unwrap(p),
+            self._pshape(size, n, p)).astype(jnp.int32))
 
     def negative_binomial(self, n, p, size=None):
         """Failures before the n-th success: Poisson with

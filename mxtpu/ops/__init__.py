@@ -21,14 +21,4 @@ from . import custom  # noqa: F401
 from . import moe  # noqa: F401
 from . import random_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
-
-try:  # pallas kernels (gated: interpret-mode on CPU, absent on old jax)
-    from . import pallas  # noqa: F401
-except Exception:  # pragma: no cover
-    import math as _math
-    import warnings
-    import jax as _jax
-    import jax.numpy as _jnp
-    from .base_fallbacks import register_dense_flash_attention
-    warnings.warn("pallas unavailable; flash_attention falls back to XLA")
-    register_dense_flash_attention()
+from . import pallas  # noqa: F401

@@ -27,10 +27,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...base import register_op
+from . import counters
+from .partition import shard_attention
 
 __all__ = ["flash_attention", "kernel_specs"]
 
 _NEG_INF = -1e30
+
+KERNEL_NAME = "flash_attention"
 
 
 def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
@@ -49,17 +53,16 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
     BH = B * H
 
     def blk(name, kind, shape, array, dt, imap):
-        # D (head_dim) and the q/kv block tiles are chosen parameters:
-        # rank-3 blocks are strict on both trailing dims, the rank-2
-        # lse/delta rows on their (q_block-sized) last dim only
-        strict = (-1, -2) if len(shape) == 3 else (-1,)
+        # D (head_dim) and the q/kv block tiles are chosen parameters,
+        # strict on both trailing dims; the lse/delta columns carry a
+        # trailing unit dim (their array's full extent), so only their
+        # q_block-sized sublane dim is a choice
+        strict = (-2,) if shape[-1] == 1 else (-1, -2)
         return BlockOperand(name, kind, shape, array, dt, imap,
                             strict_dims=strict)
 
     q_im = lambda b, i: (b, i, 0)      # noqa: E731 — mirrors _flash_fwd
     full_im = lambda b, i: (b, 0, 0)   # noqa: E731
-    row_im = lambda b, i: (b, i)       # noqa: E731
-    row0_im = lambda b, i: (b, 0)      # noqa: E731
     specs = [KernelSpec(
         "flash_attention.fwd[%s,T=%d,D=%d]" % (dtype, T, D),
         grid=(BH, Tq // qb),
@@ -68,7 +71,7 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
             blk("k", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
             blk("v", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
             blk("o", "out", (1, qb, D), (BH, Tq, D), dtype, q_im),
-            blk("lse", "out", (1, qb), (BH, Tq), "float32", row_im),
+            blk("lse", "out", (1, qb, 1), (BH, Tq, 1), "float32", q_im),
         ],
         interpret=interpret)]
     if not backward:
@@ -81,8 +84,8 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
             blk("k", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
             blk("v", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
             blk("do", "in", (1, qb, D), (BH, Tq, D), dtype, q_im),
-            blk("lse", "in", (1, qb), (BH, Tq), "float32", row_im),
-            blk("delta", "in", (1, qb), (BH, Tq), "float32", row_im),
+            blk("lse", "in", (1, qb, 1), (BH, Tq, 1), "float32", q_im),
+            blk("delta", "in", (1, qb, 1), (BH, Tq, 1), "float32", q_im),
             blk("dq", "out", (1, qb, D), (BH, Tq, D), dtype, q_im),
         ],
         interpret=interpret))
@@ -95,8 +98,8 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
             blk("k", "in", (1, kb, D), (BH, Tk, D), dtype, kv_im),
             blk("v", "in", (1, kb, D), (BH, Tk, D), dtype, kv_im),
             blk("do", "in", (1, Tq, D), (BH, Tq, D), dtype, full_im),
-            blk("lse", "in", (1, Tq), (BH, Tq), "float32", row0_im),
-            blk("delta", "in", (1, Tq), (BH, Tq), "float32", row0_im),
+            blk("lse", "in", (1, Tq, 1), (BH, Tq, 1), "float32", full_im),
+            blk("delta", "in", (1, Tq, 1), (BH, Tq, 1), "float32", full_im),
             blk("dk", "out", (1, kb, D), (BH, Tk, D), dtype, kv_im),
             blk("dv", "out", (1, kb, D), (BH, Tk, D), dtype, kv_im),
         ],
@@ -153,7 +156,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     # logsumexp residual for the Pallas backward (fp32; the softmax is
     # re-derived there as exp(s - lse) without a second online pass)
-    lse_ref[0] = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+    lse_ref[0] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def _pad_to(x, axis, multiple):
@@ -185,7 +188,7 @@ def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-                   jax.ShapeDtypeStruct((B * H, Tq), jnp.float32)],
+                   jax.ShapeDtypeStruct((B * H, Tq, 1), jnp.float32)],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
@@ -193,7 +196,7 @@ def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
             pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, q_block), lambda b, i: (b, i))],
+                   pl.BlockSpec((1, q_block, 1), lambda b, i: (b, i, 0))],
         interpret=interpret,
     )(qp, kp, vp)
     return out.reshape(B, H, Tq, D)[:, :, :t_orig], lse
@@ -208,8 +211,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)              # (Bq, D), UNscaled
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, None]                     # (Bq, 1)
-    delta = delta_ref[0][:, None]
+    lse = lse_ref[0]                              # (Bq, 1)
+    delta = delta_ref[0]
     bq, d = q.shape
     nkv_total = seq_len // kv_block
     if causal:
@@ -266,8 +269,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk, dv = carry
         qb = q_ref[0, pl.ds(i * q_block, q_block), :].astype(jnp.float32)
         do = do_ref[0, pl.ds(i * q_block, q_block), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * q_block, q_block)][:, None]
-        delta = delta_ref[0, pl.ds(i * q_block, q_block)][:, None]
+        lse = lse_ref[0, pl.ds(i * q_block, q_block), :]    # (Bq, 1)
+        delta = delta_ref[0, pl.ds(i * q_block, q_block), :]
         s = scale * jnp.dot(qb, k.T, preferred_element_type=jnp.float32,
                             precision=prec)       # (Bq, Bkv)
         if valid_len != seq_len:
@@ -307,9 +310,9 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
     vp = vp.reshape(BH, Tk, D)
     gp = gp.reshape(BH, Tq, D)
     op = op.reshape(BH, Tq, D)
-    # lse comes padded from the forward already (BH, Tq_padded)
+    # lse comes padded from the forward already (BH, Tq_padded, 1)
     delta = jnp.sum(gp.astype(jnp.float32) * op.astype(jnp.float32),
-                    axis=-1)                # (BH, Tq)
+                    axis=-1, keepdims=True)  # (BH, Tq, 1)
 
     common = dict(scale=scale, causal=causal, q_block=q_block,
                   kv_block=kv_block, seq_len=Tk, q_seq_len=Tq,
@@ -323,8 +326,8 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
             pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, q_block), lambda b, i: (b, i)),
-            pl.BlockSpec((1, q_block), lambda b, i: (b, i)),
+            pl.BlockSpec((1, q_block, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, q_block, 1), lambda b, i: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
         interpret=interpret,
@@ -340,8 +343,8 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
             pl.BlockSpec((1, kv_block, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, kv_block, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, Tq, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, Tq), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, Tq), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, Tq, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, Tq, 1), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, kv_block, D), lambda b, j: (b, j, 0)),
@@ -407,6 +410,8 @@ def flash_attention(q, k, v, causal=False, scale=None, q_block=128,
 
     Pallas kernel on TPU; interpret-mode on CPU (slow — tests only).
     Falls back to the dense XLA path when shapes are too small to tile.
+    Inside a ``head_sharding_scope`` (ops/pallas/partition.py) the call
+    is shard_mapped over the scope's batch and heads axes.
     """
     from ...base import env_bool
 
@@ -418,8 +423,12 @@ def flash_attention(q, k, v, causal=False, scale=None, q_block=128,
     kv_block = min(kv_block, T)
     interpret = jax.default_backend() == "cpu"
     pallas_bwd = env_bool("MXTPU_FLASH_BWD", True)
-    return _make_flash(scale, causal, q_block, kv_block, interpret,
-                       pallas_bwd)(q, k, v)
+    counters.bump(KERNEL_NAME)
+    fa = _make_flash(scale, causal, q_block, kv_block, interpret,
+                     pallas_bwd)
+    # inside a sharded training step or tp>1 decoder program GSPMD
+    # cannot partition the kernel: split it over batch and heads
+    return shard_attention(fa, B, H)(q, k, v)
 
 
 @register_op("flash_attention", aliases=("_contrib_flash_attention",))

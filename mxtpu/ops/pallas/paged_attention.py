@@ -52,6 +52,7 @@ CPU-test geometries are interpret-mode-only (K007).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import os
@@ -66,7 +67,7 @@ from . import counters
 from .partition import current_head_sharding, head_shard_map
 
 __all__ = ["paged_decode_attention", "paged_attention_enabled",
-           "paged_attention_mode", "kernel_spec",
+           "paged_attention_mode", "recording_paths", "kernel_spec",
            "validate_call_geometry"]
 
 _NEG_INF = -1e30
@@ -86,26 +87,79 @@ def paged_attention_mode() -> str:
     return "auto"
 
 
+#: trace-time stack of ``{"kernel[geometry]": "pallas|xla: why"}``
+#: sinks — the program builder (``ShardedDecoder``) opens
+#: :func:`recording_paths` around the body it traces, so every gate
+#: verdict lands on the decoder whose program it was baked into.  Host
+#: state read only while a trace runs, like ``partition._SCOPE``.
+_RECORD: list = []
+
+
+@contextlib.contextmanager
+def recording_paths(sink: dict):
+    """Collect the gate verdicts resolved inside this block in ``sink``
+    — the paged engine's ``stats["attention_paths"]``."""
+    _RECORD.append(sink)
+    try:
+        yield sink
+    finally:
+        _RECORD.pop()
+
+
+def resolve_path(kernel, geometry=None, violations=None) -> bool:
+    """Resolve the tri-state gate for one call site and make the choice
+    visible: ``auto`` = the kernel where the backend is a real
+    accelerator AND ``violations()`` (the kernel's
+    ``validate_call_geometry``) is empty; the XLA gather path on
+    interpret-only CPU hosts (K007: interpret mode accepts geometry
+    hardware would reject) unless forced with ``1``.  The verdict and
+    its reason go under ``kernel[geometry]`` into the innermost
+    :func:`recording_paths` sink; declining on an accelerator warns,
+    once per sink, naming the violated K-rule — a served model must not
+    land on the gather path in silence.  Without a geometry only the
+    mode and the backend decide (the jit-key read), and nothing is
+    recorded."""
+    mode = paged_attention_mode()
+    backend = jax.default_backend()
+    declined = False
+    if mode != "auto":
+        on, why = mode == "1", "MXTPU_PALLAS_PAGED_ATTN=%s" % mode
+    elif backend == "cpu":
+        on, why = False, "K007: the cpu backend is interpret-only"
+    else:
+        errs = violations() if violations else []
+        on, declined = not errs, bool(errs)
+        why = "; ".join(errs) if errs else "geometry legal on %s" % backend
+    if geometry is None:
+        return on
+    key = "%s[%s]" % (kernel, geometry)
+    verdict = "%s: %s" % ("pallas" if on else "xla", why)
+    sink = _RECORD[-1] if _RECORD else {}
+    if declined and sink.get(key) != verdict:
+        import warnings
+
+        warnings.warn(
+            "%s takes the XLA gather path on %s — %s"
+            % (key, backend, why), RuntimeWarning, stacklevel=3)
+    sink[key] = verdict
+    return on
+
+
+def path_geometry(D, block_size, pool_dtype) -> str:
+    """The geometry every paged gate's record starts with."""
+    return "D=%d,bs=%d,%s" % (D, block_size, pool_dtype)
+
+
 def paged_attention_enabled(D=None, block_size=None,
                             pool_dtype=None) -> bool:
-    """Resolve the tri-state gate for one call site (docs/inference.md
-    "Serving Pallas kernels").  ``auto`` = on where the backend is a
-    real accelerator AND :func:`validate_call_geometry` accepts the
-    geometry (when the caller supplies it); off on interpret-only CPU
-    hosts — the K007 rule: interpret mode accepts geometry hardware
-    would reject, so CPU hosts stay on the bit-exact XLA path unless
-    forced with ``1``."""
-    mode = paged_attention_mode()
-    if mode == "0":
-        return False
-    if mode == "1":
-        return True
-    if jax.default_backend() == "cpu":
-        return False
-    if D is not None and validate_call_geometry(
-            D, block_size, pool_dtype):
-        return False
-    return True
+    """Resolve the tri-state gate for one decode/verify call site
+    (docs/inference.md "Serving Pallas kernels") — see
+    :func:`resolve_path`."""
+    if D is None:
+        return resolve_path(KERNEL_NAME)
+    return resolve_path(
+        KERNEL_NAME, path_geometry(D, block_size, pool_dtype),
+        lambda: validate_call_geometry(D, block_size, pool_dtype))
 
 
 def invocation_count(name=KERNEL_NAME) -> int:
@@ -133,6 +187,7 @@ def _kernel(tbl_ref, pos_ref, nv_ref, *rest,
     else:
         v_ref, o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    kv = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -148,8 +203,8 @@ def _kernel(tbl_ref, pos_ref, nv_ref, *rest,
         k = k_ref[0, 0].astype(jnp.float32)                 # (bs, D)
         v = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
+            k = k * ks_ref[0, kv].astype(jnp.float32)[:, None]
+            v = v * vs_ref[0, kv].astype(jnp.float32)[:, None]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         # logical key positions of this page vs each lane's extent:
         # lane l = r*W + w attends positions <= pos[b] + (l % W)
@@ -364,14 +419,14 @@ def kernel_spec(B, KV, rep, W, D, block_size, max_length,
     ]
     if quant:
         operands.append(BlockOperand(
-            "k_scales", "in", (1, 1, bs), (N, KV, bs), "float32",
+            "k_scales", "in", (1, KV, bs), (N, KV, bs), "float32",
             scale_im))
     operands.append(BlockOperand(
         "pool_v", "in", (1, 1, bs, D), (N, KV, bs, D), pool_dtype,
         page_im, strict_dims=(-1, -2)))
     if quant:
         operands.append(BlockOperand(
-            "v_scales", "in", (1, 1, bs), (N, KV, bs), "float32",
+            "v_scales", "in", (1, KV, bs), (N, KV, bs), "float32",
             scale_im))
     operands.append(BlockOperand(
         "o", "out", (1, 1, lanes, D), (B, KV, lanes, D), q_dtype, q_im,
@@ -433,7 +488,10 @@ def _page_index(b, kv, j, tbl, pos, nv):
 
 
 def _scale_index(b, kv, j, tbl, pos, nv):
-    return (jnp.where(j < nv[b], tbl[b, j], 0), kv, 0)
+    """Scale-plane selection: the block is the page's WHOLE (KV, bs)
+    plane — Mosaic refuses a size-1 second-to-last block dim on a KV-wide
+    array — and the kernel picks its own head's row."""
+    return (jnp.where(j < nv[b], tbl[b, j], 0), 0, 0)
 
 
 def _page_index_tree(b, kv, j, tbl, pos, nv, anc):
@@ -444,7 +502,7 @@ def _page_index_tree(b, kv, j, tbl, pos, nv, anc):
 
 
 def _scale_index_tree(b, kv, j, tbl, pos, nv, anc):
-    return (jnp.where(j < nv[b], tbl[b, j], 0), kv, 0)
+    return (jnp.where(j < nv[b], tbl[b, j], 0), 0, 0)
 
 
 def _call_local(qr, pool_k, pool_v, tables, pos, k_scales=None,
@@ -474,12 +532,12 @@ def _call_local(qr, pool_k, pool_v, tables, pos, k_scales=None,
     ]
     args = [qr, pool_k]
     if quant:
-        in_specs.append(pl.BlockSpec((1, 1, bs), scale_index))
+        in_specs.append(pl.BlockSpec((1, KV, bs), scale_index))
         args.append(k_scales)
     in_specs.append(pl.BlockSpec((1, 1, bs, D), page_index))
     args.append(pool_v)
     if quant:
-        in_specs.append(pl.BlockSpec((1, 1, bs), scale_index))
+        in_specs.append(pl.BlockSpec((1, KV, bs), scale_index))
         args.append(v_scales)
 
     kernel = functools.partial(_kernel, sm_scale=sm_scale, bs=bs,
@@ -573,10 +631,11 @@ def paged_decode_attention(q, pool_k, pool_v, tables, pos,
                              interpret=interpret)
 
     shard = current_head_sharding()
-    if shard is not None and KV % shard[2] == 0:
+    if shard is not None and shard.shards > 1 \
+            and KV % shard.shards == 0:
         from jax.sharding import PartitionSpec as P
 
-        jm, axes, _ = shard
+        jm, axes = shard.mesh, shard.axes
         ax = axes[0] if len(axes) == 1 else tuple(axes)
         heads4 = P(None, ax, None, None)   # qr/out and page pools
         heads3 = P(None, ax, None)         # int8 scale planes

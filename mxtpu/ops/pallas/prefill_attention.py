@@ -47,7 +47,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...base import register_op
 from . import counters
-from .paged_attention import (_NEG_INF, paged_attention_mode,
+from .paged_attention import (_NEG_INF, paged_attention_enabled,
+                              path_geometry, resolve_path,
                               validate_call_geometry as
                               _decode_call_geometry)
 from .partition import current_head_sharding, head_shard_map
@@ -70,18 +71,14 @@ def paged_prefill_enabled(D=None, block_size=None, pool_dtype=None,
                           T=None, rep=None, q_dtype="float32") -> bool:
     """Resolve the shared tri-state gate for one prefill call site —
     same rules as ``paged_attention_enabled`` plus this kernel's own
-    geometry guard."""
-    mode = paged_attention_mode()
-    if mode == "0":
-        return False
-    if mode == "1":
-        return True
-    if jax.default_backend() == "cpu":
-        return False
-    if D is not None and validate_call_geometry(
-            D, block_size, pool_dtype, T=T, rep=rep, q_dtype=q_dtype):
-        return False
-    return True
+    geometry guard, recorded per chunk length."""
+    if D is None:
+        return paged_attention_enabled()
+    return resolve_path(
+        KERNEL_NAME, "%s,T=%s,rep=%s,q=%s" % (
+            path_geometry(D, block_size, pool_dtype), T, rep, q_dtype),
+        lambda: validate_call_geometry(D, block_size, pool_dtype, T=T,
+                                       rep=rep, q_dtype=q_dtype))
 
 
 def invocation_count() -> int:
@@ -118,6 +115,7 @@ def _kernel(tbl_ref, start_ref, nv_ref, q_ref, k_ref, *rest,
         ks_ref, v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         v_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    kv = pl.program_id(0)
     i = pl.program_id(1)
     j = pl.program_id(2)
 
@@ -133,8 +131,8 @@ def _kernel(tbl_ref, start_ref, nv_ref, q_ref, k_ref, *rest,
         k = k_ref[0, 0].astype(jnp.float32)                  # (bs, D)
         v = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
+            k = k * ks_ref[0, kv].astype(jnp.float32)[:, None]
+            v = v * vs_ref[0, kv].astype(jnp.float32)[:, None]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         # causal mask within the chunk: tile lane l is fold lane
         # i*qb + l = r*T + t, so its logical query position is
@@ -177,7 +175,10 @@ def _page_index(kv, i, j, tbl, start, nv):
 
 
 def _scale_index(kv, i, j, tbl, start, nv):
-    return (jnp.where(j < nv[0], tbl[j], 0), kv, 0)
+    """The page's whole (KV, bs) scale plane (the decode kernel's
+    rule: no size-1 second-to-last block dim); the kernel picks its
+    head's row."""
+    return (jnp.where(j < nv[0], tbl[j], 0), 0, 0)
 
 
 def _model_table(M, n_pages, nv):
@@ -240,14 +241,14 @@ def kernel_spec(T, KV, rep, D, block_size, max_length, start_pos=0,
     ]
     if quant:
         operands.append(BlockOperand(
-            "k_scales", "in", (1, 1, bs), (N, KV, bs), "float32",
+            "k_scales", "in", (1, KV, bs), (N, KV, bs), "float32",
             _scale_index))
     operands.append(BlockOperand(
         "pool_v", "in", (1, 1, bs, D), (N, KV, bs, D), pool_dtype,
         _page_index, strict_dims=(-1, -2)))
     if quant:
         operands.append(BlockOperand(
-            "v_scales", "in", (1, 1, bs), (N, KV, bs), "float32",
+            "v_scales", "in", (1, KV, bs), (N, KV, bs), "float32",
             _scale_index))
     operands.append(BlockOperand(
         "o", "out", (1, 1, qb, D), (1, KV, lanes, D), q_dtype, q_im,
@@ -287,12 +288,12 @@ def _call_local(qr, pool_k, pool_v, table, start, k_scales=None,
     ]
     args = [qr, pool_k]
     if quant:
-        in_specs.append(pl.BlockSpec((1, 1, bs), _scale_index))
+        in_specs.append(pl.BlockSpec((1, KV, bs), _scale_index))
         args.append(k_scales)
     in_specs.append(pl.BlockSpec((1, 1, bs, D), _page_index))
     args.append(pool_v)
     if quant:
-        in_specs.append(pl.BlockSpec((1, 1, bs), _scale_index))
+        in_specs.append(pl.BlockSpec((1, KV, bs), _scale_index))
         args.append(v_scales)
 
     kernel = functools.partial(_kernel, sm_scale=sm_scale, bs=bs, T=T,
@@ -362,10 +363,11 @@ def paged_prefill_attention(q, pool_k, pool_v, table, start_pos,
                              interpret=interpret)
 
     shard = current_head_sharding()
-    if shard is not None and KV % shard[2] == 0:
+    if shard is not None and shard.shards > 1 \
+            and KV % shard.shards == 0:
         from jax.sharding import PartitionSpec as P
 
-        jm, axes, _ = shard
+        jm, axes = shard.mesh, shard.axes
         ax = axes[0] if len(axes) == 1 else tuple(axes)
         heads4 = P(None, ax, None, None)
         heads3 = P(None, ax, None)

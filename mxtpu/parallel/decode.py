@@ -154,6 +154,9 @@ class ShardedDecoder:
                               key=lambda p: p.name)
         self._staged = False
         self._jit_cache: Dict[Any, Any] = {}
+        #: "kernel[geometry]" -> "pallas|xla: why" for every attention
+        #: gate resolved while one of THIS decoder's programs was traced
+        self.attention_paths: Dict[str, str] = {}
         # live weight hot-swap (docs/serving.md "Elastic serving"):
         # when set, every compiled call runs with THESE placed leaves
         # instead of the parameters' own data — the serving engines
@@ -344,6 +347,7 @@ class ShardedDecoder:
             # paged-attention call inside body() shard_maps itself over
             # them, so tp>1 configurations ride the kernel per-shard
             # instead of falling back (ops/pallas/partition.py)
+            from ..ops.pallas.paged_attention import recording_paths
             from ..ops.pallas.partition import head_sharding_scope
             saved = []
             for p, leaf in zip(params, param_leaves):
@@ -352,7 +356,8 @@ class ShardedDecoder:
                 holder._data = leaf
             try:
                 with autograd.pause(train_mode=False), \
-                        head_sharding_scope(mesh, heads_axes):
+                        head_sharding_scope(mesh, heads_axes), \
+                        recording_paths(self.attention_paths):
                     caches = [(_wrap_leaf(ck), _wrap_leaf(cv))
                               for ck, cv in cache_leaves]
                     logits, new_caches = body(block, caches, *extra)

@@ -22,11 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["ring_attention_inner", "ring_self_attention"]
@@ -103,10 +99,6 @@ def ring_self_attention(q, k, v, mesh, causal: bool = False,
     spec = P(batch_axis, None, seq_axis, None)
     fn = functools.partial(ring_attention_inner, axis_name=seq_axis,
                            causal=causal, scale=scale)
-    try:  # jax >= 0.8 renamed check_rep -> check_vma
-        mapped = shard_map(fn, mesh=jm, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_vma=False)
-    except TypeError:  # pragma: no cover — older jax
-        mapped = shard_map(fn, mesh=jm, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_rep=False)
+    mapped = shard_map(fn, mesh=jm, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return mapped(q, k, v)

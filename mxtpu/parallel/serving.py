@@ -1995,6 +1995,14 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             "session_hit_requests": self._session_hits,
             "sessions_open": len(self._sessions),
             "prefill_tokens_avoided": self._prefill_tokens_avoided,
+            # which attention path each paged program family resolved
+            # to when it was traced, and why (ops/pallas/paged_attention
+            # .resolve_path): ``paged_attention[...]`` is the
+            # step_pages/verify_pages programs' cache read,
+            # ``paged_prefill[...,T=..]`` the page_prefill program of
+            # that chunk bucket; ``pallas: ...`` or ``xla: <K-rule>``
+            "attention_paths": dict(sorted(
+                self._dec.attention_paths.items())),
         })
         return out
 

@@ -42,6 +42,18 @@ class ShardingRules:
         introspection and mxtpu.analysis.check_sharding."""
         return [(pat.pattern, spec) for pat, spec in self._rules]
 
+    def axes(self) -> Tuple[str, ...]:
+        """The mesh axes these rules shard parameters over, in first-use
+        order — the model-parallel axes of the layout."""
+        seen: List[str] = []
+        for _, spec in self._rules:
+            for entry in spec:
+                for axis in ((entry,) if isinstance(entry, str)
+                             else entry or ()):
+                    if axis not in seen:
+                        seen.append(axis)
+        return tuple(seen)
+
     def first_match(self, name: str):
         """Index of the winning rule for `name` (first-match scan), or
         None when the name falls through to the replicate default."""

@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import autograd, ndarray as nd, optimizer as opt_mod
 from .. import random as _random
 from ..ndarray import NDArray
+from ..ops.pallas.partition import head_sharding_scope
 from .mesh import DeviceMesh
 from .sharding import ShardingRules
 
@@ -154,6 +155,17 @@ class SPMDTrainer:
         self._opt_states = []
         for i, p in enumerate(self._diff_params):
             st = self._optimizer.create_state(i, p.data())
+            # start every state leaf in the dtype the update rule hands
+            # it back in (the f32 learning rate promotes a bf16 momentum
+            # to f32): a state whose dtype changed after step 1 would
+            # compile the whole step program a second time.  Widening
+            # the initial value is exact.
+            after = jax.eval_shape(
+                lambda w, s, _i=i: self._optimizer._step_t(
+                    w, w, s, jnp.float32(0.0), self._optimizer._get_wd(_i),
+                    jnp.float32(1.0))[1], p.data()._data, st)
+            st = jax.tree_util.tree_map(
+                lambda a, to: a.astype(to.dtype), st, after)
             st = jax.tree_util.tree_map(
                 lambda a, _p=p: jax.device_put(
                     a, NamedSharding(jm, self._rules.spec_for(
@@ -173,6 +185,14 @@ class SPMDTrainer:
         aux_params = self._aux_params
         optimizer = self._optimizer
         clip_norm = self._clip_norm
+        mesh = self._mesh
+        batch_axes = self._batch_spec[0] if len(self._batch_spec) else ()
+        batch_axes = ((batch_axes,) if isinstance(batch_axes, str)
+                      else tuple(batch_axes or ()))
+        # attention heads follow the QKV projections: split over the
+        # axes the rules shard parameters over (never a batch axis)
+        heads_axes = tuple(a for a in self._rules.axes()
+                           if a not in batch_axes)
         wds = [self._optimizer._get_wd(i)
                for i in range(len(diff_params))]
 
@@ -185,7 +205,11 @@ class SPMDTrainer:
                 holder._data = leaf
             _random.push_trace_key(key)
             try:
-                with autograd.pause(train_mode=True):
+                # Pallas attention kernels split themselves over the
+                # batch axes and the heads axes (GSPMD cannot partition
+                # a Mosaic kernel — ops/pallas/partition.py)
+                with autograd.pause(train_mode=True), \
+                        head_sharding_scope(mesh, heads_axes, batch_axes):
                     out = block(NDArray(batch))
                     # multi-output blocks: by default the loss sees the
                     # FIRST output; a loss with accepts_full_output=True
@@ -511,6 +535,36 @@ class SPMDTrainer:
             p.data()._rebind(leaf)
         self._opt_states = list(new_states)
         return NDArray(loss)
+
+    def step_program(self, data, label):
+        """``(jitted, args)``: the per-step program for this batch and
+        the arguments the next :meth:`step` would call it with — built
+        without running anything, donating a buffer, drawing from the
+        RNG ring or advancing the step count.  For looking at the
+        program (``lower_step``, ``analysis.check_trainer_donation``)."""
+        self._ensure_staged(data)
+        data = data if isinstance(data, NDArray) else nd.array(data)
+        label = label if isinstance(label, NDArray) else nd.array(label)
+        sig = (tuple(data.shape), str(data._data.dtype),
+               tuple(label.shape), str(label._data.dtype))
+        args = [tuple(p.data()._data for p in self._diff_params),
+                tuple(p.data()._data for p in self._aux_params),
+                tuple(self._opt_states),
+                jnp.float32(self._effective_lr()),
+                jnp.float32(self._num_update + 1), data._data,
+                label._data, _random.generator().peek_key()]
+        if self._guard:
+            args.append(self._scale_state if self._scale_state is not None
+                        else self._init_scale_state())
+        return self._build_step(*sig), args
+
+    def lower_step(self, data, label):
+        """The per-step program lowered for this batch
+        (``jax.stages.Lowered``) — e.g. ``"tpu_custom_call" in
+        trainer.lower_step(X, y).as_text()`` says a Pallas kernel is in
+        the program the next :meth:`step` would run."""
+        jitted, args = self.step_program(data, label)
+        return jitted.lower(*args)
 
     def _init_scale_state(self):
         """Lazy initial (scale, clean) automaton state — the ONE

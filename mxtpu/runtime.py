@@ -9,10 +9,29 @@ pallas availability, distributed init state).
 from __future__ import annotations
 
 import collections
+import os
 
 import jax
 
-__all__ = ["Feature", "feature_list", "Features"]
+__all__ = ["Feature", "feature_list", "Features", "enable_compile_cache"]
+
+
+def enable_compile_cache():
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  For entry points only (chip_smoke.py, bench.py, the
+    serving worker, tools/) — never at ``import mxtpu`` and never from
+    tests.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    itself and the cache is there and nowhere else; otherwise it is
+    ``<checkout>/.jax_cache``, fixed by the package's location because
+    the path is part of the cache key — a directory that moves never
+    hits."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 Feature = collections.namedtuple("Feature", ["name", "enabled"])
 
@@ -34,7 +53,7 @@ def _detect():
     add("CUDNN", False)
     add("MKLDNN", False)
     add("XLA", True)
-    add("PALLAS", _has_pallas())
+    add("PALLAS", True)         # ships with the one JAX this repo runs on
     add("BF16", True)
     add("INT64_TENSOR_SIZE", True)
     add("DIST_KVSTORE", True)   # dist_tpu_sync (jax.distributed)
@@ -42,14 +61,6 @@ def _detect():
     add("PROFILER", True)
     add("OPENCV", _has_cv2())
     return feats
-
-
-def _has_pallas():
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        return True
-    except Exception:
-        return False
 
 
 def _has_cv2():

@@ -54,13 +54,14 @@ from .autoscale import Autoscaler
 from .gateway import Gateway
 from .router import Router
 from .supervisor import ReplicaSupervisor
-from .transport import (InProcessReplica, ReplicaDownError,
+from .transport import (ChipHeldError, InProcessReplica, ReplicaDownError,
                         ReplicaTransport, SubprocessReplica,
                         request_spec)
 
 __all__ = ["Autoscaler", "Gateway", "Router", "ReplicaSupervisor",
            "ReplicaTransport", "InProcessReplica", "SubprocessReplica",
-           "ReplicaDownError", "request_spec", "replica_pool"]
+           "ReplicaDownError", "ChipHeldError", "request_spec",
+           "replica_pool"]
 
 
 def replica_pool(factory, n: Optional[int] = None,
@@ -81,7 +82,9 @@ def replica_pool(factory, n: Optional[int] = None,
       dict, or a callable ``i -> dict`` for per-replica values (ledger
       tags, ports).  Extra keyword arguments pass through to
       :class:`SubprocessReplica` (``rpc_timeout_ticks``, ``codec``,
-      ``env``, ...).
+      ``env``, ...); ``env`` may likewise be a callable ``i -> dict`` —
+      ``env=chip_pin_env`` gives worker i the i-th chip of a host and
+      nothing else (a chip belongs to one process; docs/serving.md).
 
     ``n`` defaults to ``MXTPU_REPLICAS`` (itself defaulting to 1: one
     replica is a plain engine behind the gateway's QoS front).
@@ -119,11 +122,14 @@ def replica_pool(factory, n: Optional[int] = None,
                 "subprocess replica_pool needs a 'module:callable' "
                 "factory spec string (resolved in the worker process), "
                 "got %r" % (factory,))
+        env = spawn_kw.pop("env", None)
         return [SubprocessReplica(
             factory,
             kwargs=(kwargs(i) if callable(kwargs)
                     else dict(kwargs or {})),
-            replica_id="r%d" % i, **spawn_kw) for i in range(n)]
+            replica_id="r%d" % i,
+            env=env(i) if callable(env) else env,
+            **spawn_kw) for i in range(n)]
     raise ValueError(
         "unknown replica transport %r (MXTPU_REPLICA_TRANSPORT: "
         "'inprocess' or 'subprocess')" % (transport,))

@@ -35,6 +35,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as onp
 
 from ..base import MXTPUError
+from ..context import DeviceNotFoundError
 from ..ndarray import NDArray, array as nd_array
 from ..observability.trace import gateway_rid, get_tracer as _tracer
 from ..parallel.serving import _SpecTokens
@@ -83,6 +84,50 @@ class ReplicaDownError(MXTPUError):
     Typed so the router's reroute path can retry OTHER replicas under a
     ``RetryPolicy(retry_on=(ReplicaDownError,))`` while every other
     exception propagates."""
+
+
+class ChipHeldError(MXTPUError):
+    """A worker process was asked for from a process that already holds
+    the accelerator.  A chip belongs to one process at a time: the
+    worker would fail or hang reaching for it, so the spawn is refused
+    up front (docs/serving.md "One process per chip")."""
+
+
+def held_accelerator() -> Optional[str]:
+    """The non-CPU platform whose JAX backend THIS process has
+    initialised, or None.  Reads the table of live backends; asking
+    ``jax.devices()`` instead would be what takes the chip."""
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    for platform in getattr(bridge, "_backends", None) or ():
+        if platform != "cpu":
+            return platform
+    return None
+
+
+def chip_pin_env(index: int) -> Dict[str, str]:
+    """The libtpu settings that give one process the ``index``-th chip
+    this process may use, and nothing else — a one-chip slice of its
+    own, so N such workers run side by side (four did on a whole v5e
+    host, each with its own /dev/vfio/<i> open: PERF.md, PR 22).  Pass
+    it as a worker's ``env=``; nothing applies it unasked, because a
+    machine that is a share of a host hands out its chip some other way
+    and a pin there names a chip that is someone else's.  libtpu
+    numbers a host's chips from 0; a host that hands this process only
+    some of them says which in ``TPU_VISIBLE_CHIPS``, and that list is
+    what ``index`` counts into.  Ignored by the CPU backend."""
+    chip = str(index)
+    visible = [c.strip() for c in os.environ.get(
+        "TPU_VISIBLE_CHIPS", "").split(",") if c.strip()]
+    if visible:
+        if index >= len(visible):
+            raise DeviceNotFoundError(
+                "chip %d was asked for, but this process may use %d "
+                "chip(s) (TPU_VISIBLE_CHIPS=%s)"
+                % (index, len(visible), ",".join(visible)))
+        chip = visible[index]
+    return {"TPU_VISIBLE_CHIPS": chip,
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
 
 
 class ReplicaTransport:
@@ -523,6 +568,14 @@ class SubprocessReplica(ReplicaTransport):
     and worker trace events are forwarded per-RPC and re-emitted under
     the parent's counter clock (one timeline per request spanning both
     processes).  Pass ``env=`` to opt a worker into its own plan.
+
+    One process per chip: a worker is started from a process that has
+    not initialised an accelerator backend — from one that has, the
+    constructor raises :class:`ChipHeldError` instead of letting the
+    worker hang in the handshake reaching for a chip that is taken.
+    Which chip a worker takes is its environment's business: it inherits
+    this process's, and ``env=chip_pin_env(i)`` gives it chip ``i`` alone
+    (``replica_pool(..., env=chip_pin_env)`` does so per replica).
     """
 
     #: env vars NOT inherited by workers (see class docstring)
@@ -573,6 +626,14 @@ class SubprocessReplica(ReplicaTransport):
             pkg_root + os.pathsep + child_env["PYTHONPATH"]
             if child_env.get("PYTHONPATH") else pkg_root)
         child_env.update(env or {})
+        held = held_accelerator()
+        if held and child_env.get("JAX_PLATFORMS") != "cpu":
+            raise ChipHeldError(
+                "replica %s: this process has initialised the %s "
+                "backend and so holds the chip its worker would reach "
+                "for — start a subprocess replica pool from a process "
+                "that has not touched JAX's devices, or serve in-process "
+                "replicas from this one" % (self.replica_id, held))
         self._child_env = child_env
         self._python = python
         self._proc: Optional[subprocess.Popen] = None

@@ -304,6 +304,8 @@ def main(argv=None) -> int:
     init = json.loads(init_buf.decode())
     try:
         from ..observability.trace import get_tracer
+        from ..runtime import enable_compile_cache
+        enable_compile_cache()
         factory = resolve_factory(init["factory"])
         engine = factory(**(init.get("kwargs") or {}))
         from .transport import InProcessReplica

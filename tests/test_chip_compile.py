@@ -1,0 +1,183 @@
+"""The Pallas kernels of the two main paths compile for a TPU v5e.
+
+The sandbox has no chip but it has the chip's compiler: a topology that
+is described, not attached, is enough for ``jit(f).lower(shapes)
+.compile()`` to raise exactly what the chip would.  Interpret mode on
+the CPU cannot: before PR 22 every kernel here passed its parity suite
+and four of them were refused by Mosaic.
+
+Geometries are the real ones — BERT-base training (batch 32, 12 heads
+of 64, seq 128) and Llama-3-8B serving (32 Q / 8 KV heads of 128).
+Nothing runs, so results are covered by the interpret-mode suites
+(test_flash_attention / test_paged_attention_pallas /
+test_prefill_attention_pallas / test_sharded_paged_kernel).
+
+One process may load the TPU's library, so the topology is described
+inside a fixture (never at import) and every compile happens in this
+file, in the test's own process.
+"""
+
+import importlib
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from mxtpu.ops.pallas.partition import head_sharding_scope
+from mxtpu.parallel.mesh import make_mesh
+
+fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+pa = importlib.import_module("mxtpu.ops.pallas.paged_attention")
+pf = importlib.import_module("mxtpu.ops.pallas.prefill_attention")
+
+BF16, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
+
+# Llama-3-8B attention geometry and a serving-sized page pool
+KV, REP, D = 8, 4, 128
+SLOTS, PAGES, TABLE = 8, 129, 64
+#: smallest legal block_size per cache dtype (K002 sublane tile)
+BLOCK = {BF16: 16, F32: 8, I8: 32}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_chip(topo, monkeypatch):
+    """The kernels' public wrappers pick interpret mode from
+    ``jax.default_backend()``, which is the CPU here: answer for the
+    chip the shapes are placed on, so the wrapper's own non-interpret
+    path (geometry guard included) is what gets compiled."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiles_with_kernel(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("geometry,causal", [
+    ((32, 12, 128, 64), False),     # BERT-base, batch 32 x seq 128
+    ((1, 32, 2048, 128), True),     # Llama-3-8B full forward, T=2048
+], ids=["bert_base", "llama_3_8b"])
+def test_flash_attention(on_chip, geometry, causal, backward):
+    q = _shape(geometry, BF16, on_chip)
+
+    def fwd(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(F32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    _compiles_with_kernel(fn, q, q, q)
+
+
+def _decode_shapes(cache_dtype, W, tree, place):
+    bs = BLOCK[cache_dtype]
+    heads4, heads3, repl = place
+    shapes = dict(
+        q=_shape((SLOTS, KV * REP, W, D), BF16, heads4),
+        pool_k=_shape((PAGES, KV, bs, D), cache_dtype, heads4),
+        pool_v=_shape((PAGES, KV, bs, D), cache_dtype, heads4),
+        tables=_shape((SLOTS, TABLE), jnp.int32, repl),
+        pos=_shape((SLOTS,), jnp.int32, repl))
+    if cache_dtype == I8:
+        shapes["k_scales"] = _shape((PAGES, KV, bs), F32, heads3)
+        shapes["v_scales"] = _shape((PAGES, KV, bs), F32, heads3)
+    if tree:
+        shapes["anc"] = _shape((SLOTS, W), jnp.int32, repl)
+    return shapes
+
+
+@pytest.mark.parametrize("W,tree", [(1, False), (4, False), (8, True)],
+                         ids=["decode_W1", "verify_W4", "tree_W8"])
+@pytest.mark.parametrize("cache_dtype", [BF16, F32, I8],
+                         ids=["bf16", "f32", "int8"])
+def test_paged_decode(on_chip, cache_dtype, W, tree):
+    shapes = _decode_shapes(cache_dtype, W, tree, (on_chip,) * 3)
+    _compiles_with_kernel(
+        lambda kw: pa.paged_decode_attention(**kw), shapes)
+
+
+@pytest.mark.parametrize("cache_dtype", [BF16, I8], ids=["bf16", "int8"])
+def test_paged_prefill(on_chip, cache_dtype, T=512):
+    bs = BLOCK[cache_dtype]
+    shapes = dict(
+        q=_shape((1, KV * REP, T, D), BF16, on_chip),
+        pool_k=_shape((PAGES, KV, bs, D), cache_dtype, on_chip),
+        pool_v=_shape((PAGES, KV, bs, D), cache_dtype, on_chip),
+        table=_shape((TABLE,), jnp.int32, on_chip),
+        start_pos=_shape((), jnp.int32, on_chip))
+    if cache_dtype == I8:
+        shapes["k_scales"] = _shape((PAGES, KV, bs), F32, on_chip)
+        shapes["v_scales"] = _shape((PAGES, KV, bs), F32, on_chip)
+    _compiles_with_kernel(
+        lambda kw: pf.paged_prefill_attention(**kw), shapes)
+
+
+@pytest.mark.parametrize("cache_dtype", [BF16, I8], ids=["bf16", "int8"])
+def test_paged_decode_partitioned_over_four_chips(topo, on_chip,
+                                                  cache_dtype):
+    """tp=4: under the decoder's head_sharding_scope the kernel is
+    shard_mapped over the cache's heads axis — two KV heads a chip."""
+    mesh = make_mesh(tp=4, devices=list(topo.devices))
+    place = tuple(NamedSharding(mesh.jax_mesh, spec) for spec in
+                  (P(None, "tp", None, None), P(None, "tp", None), P()))
+    shapes = _decode_shapes(cache_dtype, 1, False, place)
+
+    def fn(kw):
+        with head_sharding_scope(mesh, "tp"):
+            return pa.paged_decode_attention(**kw)
+
+    before = pa.invocation_count()
+    _compiles_with_kernel(fn, shapes)
+    assert pa.invocation_count() == before + 1
+
+
+def test_flash_attention_partitioned_over_four_chips(topo, on_chip):
+    """A sharded training step (dp=2 x tp=2, BERT-base): GSPMD cannot
+    partition a Mosaic kernel, so under the trainer's scope the kernel
+    shard_maps itself over batch and heads — eight rows and six heads a
+    chip, forward and backward."""
+    mesh = make_mesh(dp=2, tp=2, devices=list(topo.devices))
+    q = _shape((32, 12, 128, 64), BF16,
+               NamedSharding(mesh.jax_mesh, P("dp", "tp", None, None)))
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(F32).sum()
+
+    def scoped(q, k, v):
+        with head_sharding_scope(mesh, "tp", "dp"):
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    _compiles_with_kernel(scoped, q, q, q)
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        jax.jit(jax.grad(loss)).lower(q, q, q).compile()

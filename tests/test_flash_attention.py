@@ -154,3 +154,38 @@ def test_pallas_backward_mixed_block_sizes(qkv):
     for gf, gd, name in zip(g_flash, g_dense, "qkv"):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
                                    rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("dp,tp", [(2, 2), (4, 1), (1, 2), (2, 3)])
+def test_flash_shard_mapped_under_a_sharding_scope(qkv, dp, tp):
+    """Inside a sharded program the kernel splits itself over the
+    scope's batch and heads axes (GSPMD cannot partition a Mosaic
+    kernel): same values and gradients as the unpartitioned call, and
+    an axis whose shard count does not divide stays whole (B=2 over
+    dp=4, H=2 over tp=3)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mxtpu.ops.pallas.partition import head_sharding_scope
+    from mxtpu.parallel import make_mesh
+
+    mesh = make_mesh(dp=dp, tp=tp, devices=jax.devices()[:dp * tp])
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, causal=True, q_block=64,
+                                kv_block=64) ** 2).sum()
+
+    want = jax.value_and_grad(loss, argnums=(0, 1, 2))(*qkv)
+
+    @jax.jit
+    def scoped(q, k, v):
+        with head_sharding_scope(mesh, "tp", "dp"):
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    B, H = qkv[0].shape[:2]
+    spec = P("dp" if B % dp == 0 else None, "tp" if H % tp == 0 else None)
+    got = scoped(*(jax.device_put(a, NamedSharding(mesh.jax_mesh, spec))
+                   for a in qkv))
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)

@@ -110,11 +110,25 @@ def test_k002_sublane_tile_per_dtype():
         assert check_kernels([ok]).ok, dtype
 
 
-def test_k002_size_one_sublane_is_exempt():
-    """(1, 128) windows — the lse/scale-row pattern — lower as a
-    single-sublane broadcast; not a defect."""
-    s = _spec((1, 128), (32, 1024), imap=lambda b: (b, 0))
-    assert check_kernels([s]).ok
+@pytest.mark.parametrize("block,array,legal", [
+    # the pre-PR-22 specs the v5e compiler refused ("last two dimensions
+    # of your block shape ... divisible by 8 and 128 ... or equal to the
+    # respective dimensions of the overall array"): flash lse rows and
+    # the int8 per-position scale plane
+    ((1, 128), (384, 128), False),
+    ((1, 1, 32), (129, 8, 32), False),
+    # their repaired forms: a trailing unit dim / the whole KV plane
+    ((1, 128, 1), (384, 128, 1), True),
+    ((1, 8, 32), (129, 8, 32), True),
+    # a size-1 window is exempt only when the axis itself is size 1
+    ((1, 1, 128), (4, 1, 1024), True),
+])
+def test_k002_size_one_sublane_needs_a_size_one_axis(block, array, legal):
+    s = _spec(block, array, imap=lambda b: (b,) + (0,) * (len(block) - 1))
+    rep = check_kernels([s])
+    assert rep.ok == legal, rep
+    if not legal:
+        assert _codes(rep) == ["K002", "M007"]
 
 
 def test_k003_vmem_budget_and_configurability():

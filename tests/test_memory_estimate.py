@@ -321,6 +321,20 @@ def test_crosscheck_decode_step_with_kv_cache_within_10pct():
     assert _rel_err(est.total_bytes, xla["total"]) < 0.10, (est, xla)
 
 
+def test_nested_jit_intermediates_are_counted():
+    """A nested ``jax.jit`` is a call primitive like any other: what is
+    live inside it is live in the program (``jax.nn.silu`` is one, which
+    is how the sharded block above lost its FFN transient)."""
+    def inner(x):
+        return jnp.exp(x) * jnp.sin(x)  # both factors live beside the product
+
+    x = jax.ShapeDtypeStruct((256, 256), jnp.float32)
+    flat = estimate_jit_memory(lambda x: inner(x) + 1.0, x)
+    nested = estimate_jit_memory(lambda x: jax.jit(inner)(x) + 1.0, x)
+    assert flat.activation_peak_bytes == 3 * 256 * 256 * 4
+    assert nested.activation_peak_bytes == flat.activation_peak_bytes
+
+
 # -- callable path of the registered pass ------------------------------
 
 def test_check_memory_callable_with_budget():

@@ -1,5 +1,6 @@
 """NDArray tests (parity model: tests/python/unittest/test_ndarray.py)."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -151,6 +152,31 @@ def test_cast_copy_context():
     d = a.as_in_context(mx.cpu())
     assert d.context.device_type == "cpu"
     assert mx.cpu() == mx.cpu() and mx.cpu() != mx.tpu()
+
+
+def test_tpu_context_without_the_device_raises(monkeypatch):
+    """An accelerator that was asked for and is not there is a typed
+    error (the reference raises for mx.gpu(i) without that GPU) — never
+    a silent CPU array, never chip 3 aliased onto the last chip."""
+    from mxtpu import context
+
+    # this host has no accelerator at all
+    for ctx in (mx.tpu(), mx.gpu(0)):
+        with pytest.raises(context.DeviceNotFoundError,
+                           match=r"tpu\(0\) was asked for.* 0 accel"):
+            nd.ones((2, 2), ctx=ctx)
+    # a host with two chips has no chip 99 (and no chip -1)
+    chips = jax.devices("cpu")[:2]
+    monkeypatch.setattr(context, "_accel_cache", chips)
+    assert mx.tpu(1).to_jax_device() is chips[1]
+    for bad in (99, 2, -1):
+        with pytest.raises(context.DeviceNotFoundError,
+                           match=r"tpu\(%d\) was asked for.* 2 accel"
+                           % bad):
+            mx.tpu(bad).to_jax_device()
+    # the lazy default was asked for nothing: it may pick the CPU
+    monkeypatch.setattr(context, "_accel_cache", [])
+    assert nd.ones((1,)).context.device_type == "cpu"
 
 
 def test_where_comparison():

@@ -163,7 +163,7 @@ def test_kernel_tree_window_past_bitmask_cap_raises_k004():
 
 # ------------------------------------------------- engine integration
 
-def _drive(cache_dtype, spec_k=0, spec_tree=None):
+def _engine(cache_dtype, spec_k=0, spec_tree=None, prefill_chunk=8):
     from mxtpu.models.transformer import (TransformerLM,
                                           transformer_lm_sharding_rules)
     from mxtpu.parallel import PagedContinuousBatchingEngine
@@ -173,10 +173,15 @@ def _drive(cache_dtype, spec_k=0, spec_tree=None):
     lm = TransformerLM(20, units=32, hidden_size=64, num_layers=1,
                        num_heads=4, num_kv_heads=2)
     lm.initialize()
-    eng = PagedContinuousBatchingEngine(
+    return PagedContinuousBatchingEngine(
         lm, DeviceMesh(dp=1), transformer_lm_sharding_rules(),
-        num_slots=2, max_length=64, block_size=8, prefill_chunk=8,
-        cache_dtype=cache_dtype, spec_k=spec_k, spec_tree=spec_tree)
+        num_slots=2, max_length=64, block_size=8,
+        prefill_chunk=prefill_chunk, cache_dtype=cache_dtype,
+        spec_k=spec_k, spec_tree=spec_tree)
+
+
+def _drive(cache_dtype, spec_k=0, spec_tree=None, eng=None):
+    eng = eng or _engine(cache_dtype, spec_k, spec_tree)
     rng = np.random.RandomState(0)
     pat = rng.randint(0, 20, (1, 4))
     r1 = eng.submit(nd.array(np.tile(pat, 4).astype(np.int32)), 12)
@@ -194,10 +199,17 @@ def test_step_pages_rides_kernel_when_gated(cache_dtype, monkeypatch):
     want, _ = _drive(cache_dtype)
     monkeypatch.setenv("MXTPU_PALLAS_PAGED_ATTN", "1")
     before = pa.invocation_count()
-    got, _ = _drive(cache_dtype)
+    got, st = _drive(cache_dtype)
     assert pa.invocation_count() > before, "kernel never traced"
     for w, g in zip(want, got):
         assert np.array_equal(w, g)
+    # the engine names the path its program families resolved to
+    paths = st["attention_paths"]
+    assert {k.partition("[")[0] for k in paths} == {
+        "paged_attention", "paged_prefill"}
+    assert all(k.partition("[")[2].startswith(
+        "D=8,bs=8,%s" % cache_dtype) for k in paths)
+    assert set(paths.values()) == {"pallas: MXTPU_PALLAS_PAGED_ATTN=1"}
 
 
 @pytest.mark.slow
@@ -288,7 +300,65 @@ def test_auto_default_keeps_xla_arm_on_cpu(monkeypatch):
 
     monkeypatch.delenv("MXTPU_PALLAS_PAGED_ATTN", raising=False)
     before = dict(counters.counts())
-    _drive("float32")
+    _, st = _drive("float32")
     after = counters.counts()
     for name in ("paged_attention", "paged_prefill"):
         assert after.get(name, 0) == before.get(name, 0)
+    assert st["attention_paths"] and all(
+        v.startswith("xla: K007") for v in st["attention_paths"].values())
+
+
+def test_attention_paths_are_the_engines_own(monkeypatch):
+    """Two live engines of one geometry: each reports the verdicts baked
+    into ITS programs — the second's forced kernel does not overwrite
+    the first's record, and the second's 16-token chunk bucket does not
+    show up in the first's."""
+    monkeypatch.delenv("MXTPU_PALLAS_PAGED_ATTN", raising=False)
+    first = _engine("float32")
+    assert first.stats["attention_paths"] == {}     # nothing traced yet
+    _drive("float32", eng=first)
+    monkeypatch.setenv("MXTPU_PALLAS_PAGED_ATTN", "1")
+    second = _engine("float32", prefill_chunk=16)
+    _drive("float32", eng=second)
+    mine, theirs = (e.stats["attention_paths"] for e in (first, second))
+    assert mine and all(v.startswith("xla: K007") for v in mine.values())
+    assert set(theirs.values()) == {"pallas: MXTPU_PALLAS_PAGED_ATTN=1"}
+    assert any(",T=16," in k for k in theirs)
+    assert not any(",T=16," in k for k in mine)
+
+
+def test_auto_declines_visibly_on_an_accelerator(monkeypatch):
+    """On a real accelerator `auto` still picks the XLA gather path for
+    geometry the chip would refuse — the engine's default block_size=16
+    under an int8 cache (sublane tile 32) — but never in silence: one
+    RuntimeWarning naming the violated K-rule per recording scope (one
+    decoder's programs), and the verdict recorded for
+    ``stats["attention_paths"]``."""
+    import warnings
+
+    import jax
+
+    from mxtpu.ops.pallas import prefill_attention as pf
+
+    monkeypatch.delenv("MXTPU_PALLAS_PAGED_ATTN", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pa.recording_paths({}) as paths:
+        with pytest.warns(RuntimeWarning, match="K002: block_size=16"):
+            assert pa.paged_attention_enabled(
+                D=128, block_size=16, pool_dtype="int8") is False
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")  # the second decline is silent
+            assert pa.paged_attention_enabled(
+                D=128, block_size=16, pool_dtype="int8") is False
+        assert pa.paged_attention_enabled(
+            D=128, block_size=32, pool_dtype="int8") is True
+        with pytest.warns(RuntimeWarning, match="K002: q tile 12"):
+            assert pf.paged_prefill_enabled(
+                D=128, block_size=16, pool_dtype="bfloat16", T=3, rep=4,
+                q_dtype="bfloat16") is False
+    assert paths["paged_attention[D=128,bs=16,int8]"].startswith(
+        "xla: K002")
+    assert paths["paged_attention[D=128,bs=32,int8]"] == \
+        "pallas: geometry legal on tpu"
+    assert paths["paged_prefill[D=128,bs=16,bfloat16,T=3,rep=4,"
+                 "q=bfloat16]"].startswith("xla: K002")

@@ -97,6 +97,93 @@ def test_spmd_trainer_tp_convergence():
     assert losses[-1] < 0.2 * losses[0]
 
 
+def test_spmd_trainer_lower_step_consumes_nothing():
+    """lower_step hands out the program the next step() would run —
+    without running it, donating a buffer, drawing from the RNG ring or
+    advancing the step count: the steps after it are the steps that
+    would have happened anyway."""
+    def run(look):
+        mx.random.seed(3)
+        net = nn.HybridSequential()
+        net.add(nn.Dense(16, activation="relu"), nn.Dropout(0.5),
+                nn.Dense(4))
+        net.initialize()
+        tr = SPMDTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                         "adam", make_mesh(dp=2),
+                         optimizer_params={"learning_rate": 0.01})
+        rng = np.random.RandomState(0)
+        X = mx.nd.array(rng.randn(8, 8).astype("float32"))
+        y = mx.nd.array((rng.rand(8) * 4).astype("int32"))
+        out = [float(tr.step(X, y).asnumpy())]
+        if look:
+            text = tr.lower_step(X, y).as_text()
+            assert "stablehlo" in text and "sharding" in text
+        return out + [float(tr.step(X, y).asnumpy()) for _ in range(3)]
+
+    assert run(look=True) == run(look=False)
+
+
+def test_spmd_step_compiles_once_with_bf16_sgd_momentum():
+    """The f32 learning rate promotes a bf16 momentum to f32 inside the
+    update rule.  The state must START in that dtype: found on the
+    chip, where ResNet-50's step program compiled twice (step 2 saw new
+    state dtypes) — invisible to the compile ledger, whose key is the
+    batch signature."""
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu"), nn.Dense(4))
+    net.initialize()
+    net.cast("bfloat16")
+    tr = SPMDTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+                     make_mesh(dp=2), optimizer_params={
+                         "learning_rate": 0.1, "momentum": 0.9})
+    rng = np.random.RandomState(0)
+    X = mx.nd.array(rng.randn(8, 8), dtype="bfloat16")
+    y = mx.nd.array((rng.rand(8) * 4).astype("int32"))
+    dtypes = []
+    for _ in range(3):
+        tr.step(X, y).asnumpy()
+        dtypes.append([str(s.dtype) for s in
+                       jax.tree_util.tree_leaves(tr._opt_states)])
+    assert dtypes[0] == dtypes[1] == dtypes[2]
+    (program,) = tr._jit_cache.values()
+    assert program._cache_size() == 1
+
+
+@pytest.mark.parametrize("rules,batch_spec,heads,batch", [
+    ([(r"weight$", P("tp", None))], P("dp"), ("tp",), ("dp",)),
+    ([(r"weight$", P(("tp", "ep"), None)), (r"bias$", P("dp"))],
+     P(("dp", "sp")), ("tp", "ep"), ("dp", "sp")),
+    ([], P("dp"), (), ("dp",)),
+], ids=["megatron_tp", "two_model_axes", "data_parallel_only"])
+def test_spmd_trainer_scopes_kernels_by_its_rules(rules, batch_spec,
+                                                  heads, batch):
+    """The scope the Pallas attention kernels partition themselves by
+    (ops/pallas/partition.py) comes from the trainer's own layout: heads
+    over the axes its rules shard parameters over, rows over the axes of
+    its batch_spec — a batch axis is never a heads axis."""
+    from mxtpu.ops.pallas.partition import _axes
+
+    rules = ShardingRules(rules)
+    assert set(heads) <= set(rules.axes())
+    seen = []
+
+    class Probe(nn.Dense):
+        def hybrid_forward(self, F, x, **params):
+            from mxtpu.ops.pallas.partition import _SCOPE
+            seen.extend(_SCOPE[-1:])    # the staging forward has none
+            return super().hybrid_forward(F, x, **params)
+
+    mesh = make_mesh(dp=2, tp=2, sp=2)
+    net = Probe(4, in_units=8)
+    net.initialize()
+    tr = SPMDTrainer(net, gluon.loss.L2Loss(), "sgd", mesh, rules=rules,
+                     batch_spec=batch_spec, label_spec=batch_spec)
+    tr.step(mx.nd.ones((8, 8)), mx.nd.ones((8, 4))).asnumpy()
+    scope = seen[-1]
+    assert (scope.axes, scope.shards) == _axes(mesh, heads)
+    assert (scope.batch_axes, scope.batch_shards) == _axes(mesh, batch)
+
+
 def test_spmd_transformer_lm_full_parallel():
     """The flagship path: dp x tp x sp with ring attention, loss drops."""
     np.random.seed(0)

@@ -735,3 +735,83 @@ def test_adopt_and_rollback_across_process_boundary(pool, tmp_path):
         assert np.array_equal(np.asarray(fin[2]), want_old)
     finally:
         rep.close()
+
+
+# --------------------------------------------------------------------------
+# one process per chip (docs/serving.md)
+# --------------------------------------------------------------------------
+
+def test_pool_workers_take_the_chip_their_env_names(monkeypatch):
+    """Which chip a worker takes is said, never guessed: left alone it
+    inherits this process's TPU settings untouched, and
+    ``env=chip_pin_env`` gives replica i the libtpu settings for the
+    i-th chip this process may use and nothing else (the CPU backend
+    ignores them)."""
+    from mxtpu.context import DeviceNotFoundError
+    from mxtpu.serving import replica_pool
+    from mxtpu.serving.transport import chip_pin_env
+
+    monkeypatch.setattr(SubprocessReplica, "_spawn", lambda self: None)
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2,3")
+    for rep in replica_pool(FACTORY, n=2, transport="subprocess"):
+        assert rep._child_env["TPU_VISIBLE_CHIPS"] == "2,3"
+        assert "TPU_PROCESS_BOUNDS" not in rep._child_env
+    # a host that hands this process chips 2 and 3 only
+    pinned = replica_pool(FACTORY, n=2, transport="subprocess",
+                          env=chip_pin_env)
+    for rep, chip in zip(pinned, "23"):
+        assert rep._child_env["TPU_VISIBLE_CHIPS"] == chip
+        assert rep._child_env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert rep._child_env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    with pytest.raises(DeviceNotFoundError, match="chip 2 .* 2 chip"):
+        replica_pool(FACTORY, n=3, transport="subprocess",
+                     env=chip_pin_env)
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS")
+    assert chip_pin_env(3)["TPU_VISIBLE_CHIPS"] == "3"
+    # one env for every worker still passes straight through
+    (rep,) = replica_pool(FACTORY, n=1, transport="subprocess",
+                          env={"MXTPU_X": "1"})
+    assert rep._child_env["MXTPU_X"] == "1"
+
+
+def test_pool_parent_that_holds_the_chip_is_refused(monkeypatch):
+    """A chip belongs to one process: a parent that has initialised an
+    accelerator backend gets a typed error at construction — no worker
+    is spawned to hang in the handshake."""
+    from jax._src import xla_bridge
+
+    from mxtpu.serving import ChipHeldError
+    from mxtpu.serving.transport import held_accelerator
+
+    assert held_accelerator() is None       # a CPU backend holds nothing
+    monkeypatch.setattr(xla_bridge, "_backends",
+                        dict(xla_bridge._backends, tpu=object()))
+    assert held_accelerator() == "tpu"
+    # workers held to the CPU backend reach for no chip and are exempt:
+    # this session's own JAX_PLATFORMS=cpu must not be inherited here
+    monkeypatch.delenv("JAX_PLATFORMS")
+    spawned = []
+    monkeypatch.setattr("subprocess.Popen",
+                        lambda *a, **k: spawned.append(a))
+    with pytest.raises(ChipHeldError, match="r0.*tpu backend"):
+        SubprocessReplica(FACTORY)
+    assert not spawned
+
+
+def test_importing_mxtpu_initialises_no_backend():
+    """A launcher or pool parent imports the package and must still not
+    hold a chip: ``import mxtpu`` and its serving/parallel/gluon
+    subpackages create no JAX backend (checked in a fresh interpreter —
+    this one has long since created its CPU backend)."""
+    import subprocess
+    import sys
+
+    code = ("import mxtpu, mxtpu.parallel, mxtpu.serving, mxtpu.gluon\n"
+            "from jax._src import xla_bridge\n"
+            "from mxtpu.serving.transport import held_accelerator\n"
+            "assert not xla_bridge._backends, xla_bridge._backends\n"
+            "assert held_accelerator() is None\n")
+    out = subprocess.run([sys.executable, "-c", code],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=240)
+    assert out.returncode == 0, out.stderr[-2000:]

@@ -33,10 +33,7 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as onp
-    try:  # jax >= 0.8
-        from jax import shard_map
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     devices = jax.devices()

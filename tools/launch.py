@@ -8,15 +8,34 @@ forks N processes on localhost with a shared coordinator — the same trick
 the reference's local tracker used, and what tests/nightly-style
 multi-process CI runs use (SURVEY §4 fixture 5).
 
+A chip belongs to one process at a time, and local workers start with
+identical environments: on a host with TPU chips every one of N > 1
+workers would reach for every chip — N workers a chip — and all but the
+first would fail or hang.  Giving each its own chip AND joining them
+into one jax.distributed world needs libtpu's multi-process slice
+settings, which nothing here has run with yet; one process drives every
+chip of a host (``make_mesh`` over ``jax.devices()``).  So there
+`--launcher local` with N > 1 is for CPU rehearsal only and is refused
+unless JAX_PLATFORMS=cpu.  The launcher itself never imports JAX — that
+would take the chips from its own workers.
+
 Usage:
     python tools/launch.py -n 4 --launcher local python train.py ...
 """
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
 import sys
+
+def tpu_chips(dev="/dev"):
+    """Accelerator chips this host exposes, counted from its device
+    nodes without JAX: one ``/dev/accel<N>`` or one VFIO group
+    ``/dev/vfio/<N>`` per chip, whichever driver the host runs."""
+    return (len(glob.glob(os.path.join(dev, "accel[0-9]*")))
+            + len(glob.glob(os.path.join(dev, "vfio", "[0-9]*"))))
 
 
 def main():
@@ -49,6 +68,18 @@ def main():
                       args.port, args.num_workers, i,
                       " ".join(args.command)))
         return
+
+    chips = tpu_chips()
+    if args.num_workers > 1 and chips \
+            and os.environ.get("JAX_PLATFORMS") != "cpu":
+        sys.exit(
+            "launch.py: refusing %d local workers on a host with %d "
+            "accelerator chip(s): they would start with identical "
+            "environments and all reach for the same chips, and a chip "
+            "belongs to one process.  Run ONE process — it drives every "
+            "chip of the host — or set JAX_PLATFORMS=cpu for a CPU "
+            "rehearsal."
+            % (args.num_workers, chips))
 
     procs = []
     try:

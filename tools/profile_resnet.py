@@ -30,9 +30,8 @@ MM1X1 = False  # 1x1-as-matmul measured slower (49.2 vs 46.8 ms): XLA's
 # for the conv-weight-grad bandwidth problem this tool diagnosed.
 import os as _os
 _PALLAS_BWD = _os.environ.get("MXTPU_PALLAS_CONV_BWD", "") not in ("", "0")
-if _PALLAS_BWD:
-    _os.sys.path.insert(0, _os.path.join(_os.path.dirname(
-        _os.path.abspath(__file__)), ".."))
+_os.sys.path.insert(0, _os.path.join(_os.path.dirname(
+    _os.path.abspath(__file__)), ".."))
 
 
 def conv(x, w, stride, layout):
@@ -218,6 +217,9 @@ def run(layout, batch, bn_mode, s2d=False, iters=40):
 
 
 if __name__ == "__main__":
+    from mxtpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
     configs = [
         ("NHWC", 128, "bf16chain", False),
         ("NHWC", 128, "bf16chain", True),
