@@ -21,6 +21,10 @@ from . import ndarray
 from . import ndarray as nd
 from . import autograd
 from .ndarray import NDArray
+from .observability.trace import attach_jax
+
+attach_jax()    # jax is imported by now: annotations and xla.compile spans
+del attach_jax
 
 __version__ = "0.1.0"
 

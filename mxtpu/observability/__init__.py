@@ -2,9 +2,11 @@
 tracing, failure flight recorder, and one metrics registry across
 serving and training (docs/observability.md).
 
-Three modules, one discipline — counter clocks, never wall clocks, so
-every trace, postmortem, and metrics delta is bit-reproducible under
-the same seeds + fault plan and assertable in tier-1:
+Three modules, one discipline — what is asserted rides a counter clock,
+so every trace, postmortem, and metrics delta is bit-reproducible under
+the same seeds + fault plan and assertable in tier-1 (the one wall
+clock, ``t_ns``, rides beside the tick and stays out of the
+deterministic bytes):
 
 - :mod:`~mxtpu.observability.trace` — process-wide :class:`Tracer`
   (off by default; ``MXTPU_TRACE=1`` or :func:`tracing`): typed
@@ -17,7 +19,9 @@ the same seeds + fault plan and assertable in tier-1:
   Chrome trace-event export (:func:`export_chrome_trace`) serves the
   tick traces and the legacy ``mxtpu.profiler`` events through one
   writer, and spans wrap in ``jax.profiler.TraceAnnotation`` when a
-  profiler session runs.
+  profiler session runs.  Boundary spans (one per trainer step, engine
+  iteration or phase) and XLA compilations are kept in a bounded ring
+  whether the tracer is on or not (``Tracer.boundary_spans()``).
 - :mod:`~mxtpu.observability.flight` — :class:`FlightRecorder`
   (``MXTPU_FLIGHT_BUFFER=N`` or :func:`flight_recording`): bounded
   per-request event rings that, on any failure path — quarantine,
@@ -43,12 +47,12 @@ from __future__ import annotations
 from .flight import (FlightRecorder, Postmortem, flight_recording,
                      get_flight)
 from .metrics import MetricsRegistry, default_registry, get_registry
-from .trace import (EVENT_TYPES, TraceEvent, Tracer, export_chrome_trace,
-                    gateway_rid, get_tracer, tracing)
+from .trace import (BOUNDARY_TYPES, EVENT_TYPES, Span, TraceEvent, Tracer,
+                    export_chrome_trace, gateway_rid, get_tracer, tracing)
 
 __all__ = [
     "Tracer", "TraceEvent", "get_tracer", "tracing", "gateway_rid",
-    "EVENT_TYPES", "export_chrome_trace",
+    "EVENT_TYPES", "BOUNDARY_TYPES", "Span", "export_chrome_trace",
     "FlightRecorder", "Postmortem", "get_flight", "flight_recording",
     "MetricsRegistry", "get_registry", "default_registry",
 ]

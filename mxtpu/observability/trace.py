@@ -1,21 +1,31 @@
-"""Deterministic structured tracing: typed spans/events on a tick clock.
+"""Deterministic structured tracing: typed spans/events on a tick clock,
+with one wall clock beside it.
 
 The reference MXNet's engine-integrated profiler stamps every engine op
 with wall-clock timestamps and emits chrome://tracing JSON.  At serving
 scale the question a trace must answer — "which replica/tier/fault ate
 my latency?" — has to be answerable from telemetry that REPLAYS: this
 tracer therefore stamps every event with a process-wide COUNTER tick,
-never a wall clock, so the trace of a seeded run under a fault plan is
-bit-reproducible and assertable in tier-1 (the same discipline as
-``mxtpu.resilience.faults``).  Optional wall-clock annotations ride in
-a separate ``noise`` payload that is NOISE-labeled and excluded from
-the deterministic serialization.
+so the trace of a seeded run under a fault plan is bit-reproducible and
+assertable in tier-1 (the same discipline as
+``mxtpu.resilience.faults``).  Beside the tick every event carries
+``t_ns = time.perf_counter_ns()`` and ``parent`` (the begin tick of the
+span open on the emitting thread).  ``t_ns`` is NOISE: it and
+``parent`` stay out of the deterministic serialization and ride only
+under ``include_noise=True``.
 
 Off by default.  Enable with ``MXTPU_TRACE=1`` (ambient, read once at
-tracer construction) or the :func:`tracing` context manager.  When the
-:mod:`mxtpu.profiler` session is running (``profiler.start()``), every
-span additionally wraps itself in a ``jax.profiler.TraceAnnotation`` so
-host-side spans land inside the XLA trace.
+tracer construction) or the :func:`tracing` context manager.
+
+Boundary spans (:data:`BOUNDARY_TYPES`: one per trainer step, engine
+iteration or phase of one — never one per token or request) are kept
+ALWAYS, tracer enabled or not, in a bounded ring of finished spans
+(:meth:`Tracer.boundary_spans`), and open a
+``jax.profiler.TraceAnnotation("mxtpu.<type>")`` whenever a profiler
+session runs, however it was started.  XLA compilations land in the
+same ring as ``xla.compile`` spans (:func:`attach_jax`).  ``perf_counter_ns`` is the clock a
+benchmark's own host spans use, so a reader lays the ring on a device
+trace by shifting with one span both sides hold.
 
 Event taxonomy (:data:`EVENT_TYPES`): every event carries a registered
 type — an unregistered type raises at the emit site, and the
@@ -43,13 +53,17 @@ and resilience hot paths import it unconditionally.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
+from collections import deque
+from time import perf_counter_ns
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
-__all__ = ["TraceEvent", "Tracer", "get_tracer", "tracing",
-           "gateway_rid", "EVENT_TYPES", "export_chrome_trace"]
+__all__ = ["TraceEvent", "Span", "Tracer", "get_tracer", "tracing",
+           "gateway_rid", "EVENT_TYPES", "BOUNDARY_TYPES",
+           "export_chrome_trace", "attach_jax"]
 
 
 #: alias entries (engine-rid -> gateway-rid) kept for at most this many
@@ -59,6 +73,10 @@ __all__ = ["TraceEvent", "Tracer", "get_tracer", "tracing",
 #: map without bound — the same bounded-bookkeeping discipline as the
 #: flight recorder's request rings.
 MAX_ALIASES = 8192
+
+#: finished boundary spans kept (oldest evicted past it): at a hundred
+#: engine iterations a second and seven spans an iteration, a minute
+MAX_BOUNDARY_SPANS = 65536
 
 #: the registered span/event taxonomy: type -> one-line description
 #: (docs/observability.md mirrors this table).  ``fault.<site>`` types
@@ -77,7 +95,7 @@ EVENT_TYPES: Dict[str, str] = {
     "gateway.expired": "tick deadline passed; finished with partial "
                        "stream",
     "gateway.finish": "terminal gateway status (ok/failed)",
-    "gateway.pump": "one gateway service iteration (span)",
+    "gateway.pump": "one gateway service iteration (boundary span)",
     # -- router / transport ---------------------------------------------
     "router.dispatch": "replica selected (locality score, chosen "
                        "replica, load)",
@@ -112,7 +130,22 @@ EVENT_TYPES: Dict[str, str] = {
     "serving.rollback": "previous param generation re-staged "
                         "(hot-swap rollback)",
     # -- engines (mxtpu.parallel.serving) -------------------------------
-    "engine.iteration": "one engine scheduler iteration (span)",
+    "engine.iteration": "one engine scheduler iteration (boundary "
+                        "span; the end carries the iteration's counts: "
+                        "decoding, prefilling, waiting, tokens, "
+                        "prefill_tokens)",
+    "engine.schedule": "eviction, adoption and the admission loop of "
+                       "one iteration (boundary span)",
+    "engine.prefill": "the chunked-prefill loop of one iteration, or "
+                      "an admission's first chunk inside its "
+                      "engine.schedule (boundary span)",
+    "engine.decode_step": "the pooled decode / verify step of one "
+                          "iteration (boundary span)",
+    "engine.host_read": "one blocking read of a device value by the "
+                        "engine's host loop, or (site=replica.poll) the "
+                        "in-process replica's drain of the new tokens, "
+                        "which waits for the step just dispatched "
+                        "(boundary span)",
     "engine.admit": "admission started (prompt tokens)",
     "engine.prefix_hit": "radix/host-tier prefix hit (tokens, pages "
                          "shared — prefill skipped)",
@@ -141,6 +174,17 @@ EVENT_TYPES: Dict[str, str] = {
     "guardian.checkpoint": "verified checkpoint written",
     "guardian.window": "one fused N-step window dispatched (the "
                        "once-per-N host sync)",
+    # -- SPMDTrainer (mxtpu.parallel.trainer) ---------------------------
+    "trainer.stage": "the eager shape-resolving forward and the staging "
+                     "of parameters and optimizer state onto the mesh "
+                     "(boundary span, once per trainer)",
+    "trainer.step": "one SPMDTrainer.step / step_window call as the "
+                    "host sees it: dispatch, not device time (boundary "
+                    "span; step, first, tokens on the end)",
+    # -- XLA (jax.monitoring, attach_jax) -------------------------------
+    "xla.compile": "one trace / lower / compile / cache_fetch of a "
+                   "program, eager ones included (ring only: seconds, "
+                   "kind; parent = the span open on that thread)",
     # -- profiler parity API (mxtpu.profiler) ---------------------------
     "profiler.counter": "profiler.Counter value change",
     "profiler.marker": "profiler.Marker instant",
@@ -182,12 +226,32 @@ EVENT_TYPES: Dict[str, str] = {
 }
 
 
+#: span types kept whether the tracer is enabled or not (module
+#: docstring).  Few per second by construction; anything per token or
+#: per request stays an instant behind ``Tracer.active``.
+BOUNDARY_TYPES = frozenset((
+    "trainer.stage", "trainer.step",
+    "engine.iteration", "engine.schedule", "engine.prefill",
+    "engine.decode_step", "engine.host_read",
+    "gateway.pump",
+))
+
+_COMPILE_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_fetch",
+}
+
+
 class TraceEvent(NamedTuple):
     """One recorded event.  ``tick`` is the deterministic counter clock
     (one tick per recorded event); ``phase`` is ``"I"`` (instant),
-    ``"B"``/``"E"`` (span begin/end); ``noise`` holds wall-clock or
-    otherwise non-deterministic annotations, excluded from the
-    deterministic serialization."""
+    ``"B"``/``"E"`` (span begin/end); ``noise`` holds non-deterministic
+    annotations.  ``t_ns`` is ``time.perf_counter_ns()`` at the emit and
+    ``parent`` the begin tick of the span open on the emitting thread;
+    both, like ``noise``, are excluded from the deterministic
+    serialization."""
 
     tick: int
     etype: str
@@ -195,6 +259,8 @@ class TraceEvent(NamedTuple):
     phase: str
     fields: Dict[str, Any]
     noise: Dict[str, Any]
+    t_ns: int = 0
+    parent: Optional[int] = None
 
     def to_dict(self, include_noise: bool = False) -> Dict[str, Any]:
         d: Dict[str, Any] = {"tick": self.tick, "type": self.etype,
@@ -203,9 +269,34 @@ class TraceEvent(NamedTuple):
             d["rid"] = self.rid
         if self.fields:
             d["fields"] = self.fields
-        if include_noise and self.noise:
-            d["noise"] = self.noise
+        if include_noise:
+            d["t_ns"] = self.t_ns
+            if self.parent is not None:
+                d["parent"] = self.parent
+            if self.noise:
+                d["noise"] = self.noise
         return d
+
+
+class Span(NamedTuple):
+    """One finished span of the boundary ring.  ``tick`` is its begin
+    tick, the id its children name as ``parent`` (None for
+    ``xla.compile``, which has no children and takes no tick);
+    ``start_ns``/``end_ns`` are ``time.perf_counter_ns()``; ``fields``
+    holds the begin fields and what :meth:`_Span.set` added before the
+    end."""
+
+    etype: str
+    tick: Optional[int]
+    parent: Optional[int]
+    start_ns: int
+    end_ns: int
+    rid: Optional[str]
+    fields: Dict[str, Any]
+
+    @property
+    def seconds(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e9
 
 
 def gateway_rid(tag) -> str:
@@ -218,55 +309,83 @@ def gateway_rid(tag) -> str:
 
 
 class _Span:
-    """Begin/end event pair; on-profiler runs additionally wrap the
-    region in a ``jax.profiler.TraceAnnotation`` so the host span lands
-    inside the XLA trace."""
+    """Begin/end event pair.  A boundary span (:data:`BOUNDARY_TYPES`)
+    is recorded always — ring, tick and ``TraceAnnotation`` — and as
+    events too while the tracer is active; any other span exists only
+    while the tracer is active."""
 
-    __slots__ = ("_tr", "_etype", "_rid", "_fields", "_ann", "_t0")
+    __slots__ = ("_tr", "_etype", "_rid", "_fields", "_end", "_ann",
+                 "_tick", "_parent", "_t0")
 
     def __init__(self, tracer, etype, rid, fields):
         self._tr = tracer
         self._etype = etype
         self._rid = rid
         self._fields = fields
+        self._end = None
         self._ann = None
-        self._t0 = None
+        self._tick = None       # set when the span is recorded at all
+
+    def set(self, **fields):
+        """Fields known only inside the span (an iteration's counts, the
+        step number): they ride on the end event and in the ring."""
+        if self._end is None:
+            self._end = fields
+        else:
+            self._end.update(fields)
+        return self
 
     def __enter__(self):
-        self._ann = _profiler_annotation(self._etype)
-        if self._ann is not None:
+        tr = self._tr
+        active = tr._enabled or tr._sinks
+        if not (active or self._etype in BOUNDARY_TYPES):
+            return self
+        stack = tr._stack()
+        self._parent = parent = stack[-1] if stack else None
+        self._t0 = t0 = perf_counter_ns()
+        if active:
+            tick = tr._record(self._etype, self._rid, "B", self._fields,
+                              None, t0, parent).tick
+        else:       # tracer off: the begin still takes a tick, the
+            tr._tick = tick = tr._next_tick()   # span's id in the ring
+        self._tick = tick
+        stack.append(tick)
+        cls = _ANNOTATION
+        if cls is not None and cls.is_enabled():    # TraceMe's own test,
+            # asked first: 20 ns against the 0.6 us of an idle annotation
+            self._ann = cls("mxtpu." + self._etype)
             self._ann.__enter__()
-        self._tr.emit(self._etype, rid=self._rid, phase="B",
-                      **self._fields)
-        if self._tr.record_wall:
-            import time
-            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        noise = None
-        if self._t0 is not None:
-            import time
-            noise = {"wall_s": time.perf_counter() - self._t0}
-        self._tr.emit(self._etype, rid=self._rid, phase="E",
-                      noise=noise)
+        tick = self._tick
+        if tick is None:
+            return False
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
+        end = perf_counter_ns()
+        tr = self._tr
+        stack = tr._stack()
+        if stack and stack[-1] == tick:
+            stack.pop()
+        etype, rid, fields = self._etype, self._rid, self._fields
+        if tr._enabled or tr._sinks:
+            tr._record(etype, rid, "E", self._end or {}, None, end,
+                       self._parent)
+        if etype in BOUNDARY_TYPES:
+            if self._end:
+                fields = dict(fields, **self._end) if fields else self._end
+            if rid is not None:
+                rid = tr._alias.get(rid, rid)
+            tr._ring.append((etype, tick, self._parent, self._t0, end, rid,
+                             fields))
         return False
 
 
-def _profiler_annotation(name):
-    """A jax TraceAnnotation when (and only when) a profiler session is
-    running — the only place this module touches jax, and only on an
-    already-active trace session."""
-    try:
-        from .. import profiler as _prof
-        if _prof.state() != "run":
-            return None
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — tracing must never take the
-        return None    # serving path down over a profiler hiccup
+#: ``jax.profiler.TraceAnnotation`` once :func:`attach_jax` has run (this
+#: module imports no jax itself).  Whichever way a profiler session was
+#: started, its ``is_enabled()`` says whether one runs.
+_ANNOTATION: Any = None
 
 
 def _env_truthy(name: str) -> bool:
@@ -295,11 +414,14 @@ class Tracer:
             except ValueError:
                 max_events = 200000
         self._max_events = int(max_events)
-        self.record_wall = _env_truthy("MXTPU_TRACE_WALL")
         self._events: List[TraceEvent] = []
-        self._profiler_events: List[Tuple[int, str, str, float]] = []
+        self._ring: deque = deque(maxlen=MAX_BOUNDARY_SPANS)
+        self._local = threading.local()
+        # (tick, kind, name, wall_s, t_ns)
+        self._profiler_events: List[Tuple[int, str, str, float, int]] = []
         self._alias: Dict[str, str] = {}
-        self._tick = 0
+        self._tick = 0      # the last tick handed out
+        self._next_tick = itertools.count(1).__next__   # atomic, lock-free
         self._dropped = 0
         self._sinks: List[Any] = []   # flight recorders
 
@@ -326,14 +448,16 @@ class Tracer:
             self._enabled = False
 
     def reset(self) -> None:
-        """Clear events, the tick clock, aliases, and the profiler
-        channel — the start-of-run point the determinism contract is
-        relative to."""
+        """Clear events, the boundary ring, the tick clock, aliases, and
+        the profiler channel — the start-of-run point the determinism
+        contract is relative to."""
         with self._lock:
             self._events = []
+            self._ring.clear()
             self._profiler_events = []
             self._alias = {}
             self._tick = 0
+            self._next_tick = itertools.count(1).__next__
             self._dropped = 0
 
     # -- sinks (flight recorder) -----------------------------------------
@@ -372,6 +496,12 @@ class Tracer:
         here is a taxonomy bug and raises."""
         if not (self._enabled or self._sinks):
             return None
+        stack = self._stack()
+        return self._record(etype, rid, phase, fields, noise,
+                            perf_counter_ns(),
+                            stack[-1] if stack else None)
+
+    def _record(self, etype, rid, phase, fields, noise, t_ns, parent):
         if etype not in EVENT_TYPES:
             raise ValueError(
                 "unregistered trace event type %r — add it to "
@@ -379,9 +509,9 @@ class Tracer:
                 "pass cross-checks the taxonomy)" % (etype,))
         with self._lock:
             rid = self._alias.get(rid, rid) if rid is not None else None
-            self._tick += 1
-            ev = TraceEvent(self._tick, etype, rid, phase,
-                            fields, noise or {})
+            self._tick = tick = self._next_tick()
+            ev = TraceEvent(tick, etype, rid, phase,
+                            fields, noise or {}, t_ns, parent)
             if self._enabled:
                 if len(self._events) < self._max_events:
                     self._events.append(ev)
@@ -393,10 +523,57 @@ class Tracer:
 
     def span(self, etype: str, rid: Optional[str] = None,
              **fields) -> _Span:
-        """Context manager recording a begin/end event pair (and a
-        ``jax.profiler.TraceAnnotation`` when a profiler session is
-        running)."""
+        """Context manager recording a begin/end event pair inside a
+        ``jax.profiler.TraceAnnotation``; ``.set(**fields)`` inside it
+        adds fields to the end.  A :data:`BOUNDARY_TYPES` span is kept
+        in the ring (:meth:`boundary_spans`) whether the tracer is
+        enabled or not."""
         return _Span(self, etype, rid, fields)
+
+    def _stack(self) -> List[int]:
+        """Begin ticks of the spans open on the calling thread."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def compile_seen(self, event: str, seconds: float, **_) -> None:
+        """The ``jax.monitoring`` duration listener: a trace, lowering,
+        back-end compilation or cache fetch that just ended on this
+        thread becomes an ``xla.compile`` span of the ring, its parent
+        the span open here.  A cache fetch lies inside the back-end
+        compilation that asked for it (a ``compile`` span that holds a
+        ``cache_fetch`` compiled nothing), so a reader takes the union
+        of these spans, not the sum of their seconds."""
+        kind = _COMPILE_KINDS.get(event)
+        if kind is None:
+            return
+        end = perf_counter_ns()
+        start = end - int(seconds * 1e9)
+        ring = self._ring
+        if kind in ("trace", "lower"):
+            # every jit met while tracing or lowering reports its own
+            # trace, by the thousand and inside this span: the outermost
+            # stands for them
+            while ring:
+                last = ring[-1]
+                if not (last[0] == "xla.compile" and last[3] >= start
+                        and last[6]["kind"] == "trace"):
+                    break
+                if ring.pop() is not last:      # another thread's append
+                    break                       # slipped in: leave it be
+        stack = self._stack()
+        ring.append(("xla.compile", None, stack[-1] if stack else None,
+                     start, end, None, {"kind": kind, "seconds": seconds}))
+
+    def boundary_spans(self, types=None) -> List[Span]:
+        """The finished boundary spans still in the ring, oldest first
+        (by END time: a child comes before the span that holds it)."""
+        tset = None if types is None else (
+            {types} if isinstance(types, str) else set(types))
+        return [Span._make(s) for s in list(self._ring)
+                if tset is None or s[0] in tset]
 
     # -- the profiler parity channel -------------------------------------
     def profiler_event(self, name: str, wall_s: float = 0.0,
@@ -407,14 +584,16 @@ class Tracer:
         its wall durations are NOISE by nature and excluded from the
         deterministic trace serialization."""
         with self._lock:
-            self._tick += 1
+            self._tick = tick = self._next_tick()
             if len(self._profiler_events) < self._max_events:
                 self._profiler_events.append(
-                    (self._tick, kind, name, float(wall_s)))
+                    (tick, kind, name, float(wall_s),
+                     perf_counter_ns()))
 
     def profiler_events(self) -> List[Tuple[int, str, str, float]]:
+        """``(tick, kind, name, wall_s)`` of every profiler-API event."""
         with self._lock:
-            return list(self._profiler_events)
+            return [e[:4] for e in self._profiler_events]
 
     def clear_profiler_events(self) -> None:
         with self._lock:
@@ -461,6 +640,7 @@ class Tracer:
                 "spans": sum(1 for e in self._events
                              if e.phase == "E"),
                 "dropped_events": self._dropped,
+                "boundary_spans": len(self._ring),
                 "profiler_events": len(self._profiler_events),
                 "ticks": self._tick,
                 "aliases": len(self._alias),
@@ -471,8 +651,9 @@ class Tracer:
                 indent: Optional[int] = None) -> str:
         """Deterministic JSON of the recorded trace: same seeds + same
         fault plan (+ a reset at the start of the run) => byte-identical
-        output.  ``include_noise=True`` adds the NOISE-labeled
-        wall-clock annotations (then equality is no longer promised)."""
+        output.  ``include_noise=True`` adds ``t_ns``, ``parent`` and
+        the NOISE-labeled annotations (then equality is no longer
+        promised)."""
         with self._lock:
             events = [e.to_dict(include_noise=include_noise)
                       for e in self._events]
@@ -516,16 +697,35 @@ def get_tracer() -> Tracer:
     return _TRACER
 
 
+def attach_jax() -> None:
+    """What this module takes from jax, taken once: ``mxtpu/__init__.py``
+    calls this right after it has imported jax.  Spans open
+    ``jax.profiler.TraceAnnotation`` from here on, and
+    :meth:`Tracer.compile_seen` of the process-wide tracer listens to
+    ``jax.monitoring``: XLA compilations, eager programs' too, become
+    ``xla.compile`` spans of the boundary ring."""
+    global _ANNOTATION
+    if _ANNOTATION is not None:
+        return
+    import jax
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(
+        _TRACER.compile_seen)
+    _ANNOTATION = jax.profiler.TraceAnnotation
+
+
 # -- chrome trace-event export (one writer for both APIs) ----------------
 
 def export_chrome_trace(file=None, include_noise: bool = True,
                         tracer: Optional[Tracer] = None) -> Optional[str]:
     """Chrome trace-event JSON (chrome://tracing / Perfetto) serving
-    BOTH the tick-clock structured trace and the legacy
-    ``mxtpu.profiler`` Counter/Marker/scope events through one writer
-    (the reference profiler's output format, on the deterministic
-    clock: 1 tick is rendered as 1 us).  ``file`` may be a path or a
-    writable file object; with neither, the JSON string is returned."""
+    BOTH the structured trace and the legacy ``mxtpu.profiler``
+    Counter/Marker/scope events through one writer (the reference
+    profiler's output format).  With ``include_noise`` the timeline is
+    wall time (``ts`` = ``t_ns`` in us, on ``time.perf_counter``'s
+    clock); without, the deterministic clock (``ts`` = tick, scopes one
+    tick long).  ``file`` may be a path or a writable file object; with
+    neither, the JSON string is returned."""
     tr = tracer if tracer is not None else get_tracer()
     tid_map: Dict[str, int] = {}
 
@@ -534,11 +734,14 @@ def export_chrome_trace(file=None, include_noise: bool = True,
             return 0
         return tid_map.setdefault(rid, len(tid_map) + 1)
 
+    def _ts(tick, t_ns):
+        return t_ns / 1e3 if include_noise else tick
+
     trace_events: List[dict] = []
     for ev in tr.events():
         ph = {"I": "i", "B": "B", "E": "E"}[ev.phase]
-        rec = {"name": ev.etype, "ph": ph, "ts": ev.tick, "pid": 0,
-               "tid": _tid(ev.rid), "cat": "mxtpu"}
+        rec = {"name": ev.etype, "ph": ph, "ts": _ts(ev.tick, ev.t_ns),
+               "pid": 0, "tid": _tid(ev.rid), "cat": "mxtpu"}
         if ph == "i":
             rec["s"] = "t"
         args = dict(ev.fields)
@@ -548,30 +751,33 @@ def export_chrome_trace(file=None, include_noise: bool = True,
             args["NOISE"] = dict(ev.noise)
         rec["args"] = args
         trace_events.append(rec)
-    for (tick, kind, name, wall_s) in tr.profiler_events():
+    with tr._lock:
+        scopes = list(tr._profiler_events)
+    for (tick, kind, name, wall_s, t_ns) in scopes:
+        # a scope is recorded when it ends: it began wall_s earlier
+        dur = wall_s * 1e6 if include_noise else 1
         trace_events.append({
-            "name": name, "ph": "X", "ts": tick,
-            "dur": max(1, int(wall_s * 1e6)),
-            "pid": 0, "tid": 0,
+            "name": name, "ph": "X", "ts": _ts(tick, t_ns) - dur,
+            "dur": dur, "pid": 0, "tid": 0,
             "cat": "profiler,NOISE-wall-duration",
             "args": {"kind": kind, "wall_s": wall_s},
         })
     # the profiler parity API's counters, as chrome counter samples
     try:
         from .. import profiler as _prof
-        now_tick = tr.ticks
+        now = _ts(tr.ticks, perf_counter_ns())
         for name, val in sorted(_prof.counter_values().items()):
             if isinstance(val, (int, float)):
                 trace_events.append({
-                    "name": name, "ph": "C", "ts": now_tick,
+                    "name": name, "ph": "C", "ts": now,
                     "pid": 0, "tid": 0, "cat": "profiler",
                     "args": {"value": val}})
     except Exception:  # noqa: BLE001 — export must not die on a
         pass           # profiler import problem
 
     doc = {"traceEvents": trace_events, "displayTimeUnit": "ms",
-           "otherData": {"clock": "mxtpu deterministic tick "
-                                  "(1 tick rendered as 1 us)"}}
+           "otherData": {"clock": "time.perf_counter (us)" if include_noise
+                         else "mxtpu deterministic tick"}}
     text = json.dumps(doc, sort_keys=True, separators=(",", ":"))
     if file is None:
         return text

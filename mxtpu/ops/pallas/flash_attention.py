@@ -198,6 +198,7 @@ def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
         out_specs=[pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, q_block, 1), lambda b, i: (b, i, 0))],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qp, kp, vp)
     return out.reshape(B, H, Tq, D)[:, :, :t_orig], lse
 
@@ -331,6 +332,7 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
         ],
         out_specs=pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        name="flash_attention_dq",
     )(qp, kp, vp, gp, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -351,6 +353,7 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
             pl.BlockSpec((1, kv_block, D), lambda b, j: (b, j, 0)),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qp, kp, vp, gp, lse, delta)
 
     dq = dq.reshape(B, H, Tq, D)[:, :, :t_orig]

@@ -559,6 +559,7 @@ def _call_local(qr, pool_k, pool_v, tables, pos, k_scales=None,
         out_shape=jax.ShapeDtypeStruct((B, KV, lanes, D), qr.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_attention_decode",
     )(*prefetch, *args)
 
 

@@ -316,6 +316,7 @@ def _call_local(qr, pool_k, pool_v, table, start, k_scales=None,
         out_shape=jax.ShapeDtypeStruct((1, KV, lanes, D), qr.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_prefill_chunk",
     )(table, start, nv, *args)
 
 

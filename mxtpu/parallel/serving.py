@@ -109,6 +109,15 @@ from .sharding import ShardingRules
 __all__ = ["ContinuousBatchingEngine", "PagedContinuousBatchingEngine",
            "Request"]
 
+
+def _host_read(value):
+    """``jax.device_get`` of a device value the host loop has to wait
+    for, as an ``engine.host_read`` boundary span: the time the engine
+    spends blocked on the device, inside whichever phase asked."""
+    with _tracer().span("engine.host_read"):
+        return jax.device_get(value)
+
+
 def _parse_spec_tree(value):
     """Normalize a tree-speculation config to ``(max_nodes, branch)``
     ints: 1 <= max_nodes <= 31 (the 32-lane int32 ancestor-bitmask cap
@@ -338,6 +347,9 @@ class ContinuousBatchingEngine:
         self._prompt_dtype = None
         self._steps = 0
         self._tokens_generated = 0
+        self._prefill_tokens = 0        # prompt tokens run through prefill
+        self._iter_decoding = 0         # slots in this iteration's decode
+        self._iter_prefilling = 0       # slots still prefilling after it
         # -- resilience state (docs/resilience.md) -----------------------
         self._max_pending = (None if max_pending is None
                              else int(max_pending))
@@ -481,6 +493,7 @@ class ContinuousBatchingEngine:
         return {
             "steps": self._steps,
             "generated_tokens": self._tokens_generated,
+            "prefill_tokens": self._prefill_tokens,
             "quarantined_requests": self._quarantined,
             "retried_requests": self._retries,
             "expired_requests": self._deadline_evictions,
@@ -778,6 +791,7 @@ class ContinuousBatchingEngine:
                 raw = jnp.pad(raw, ((0, 0), (0, Tb - Tp)))
         logits, self._pool = self._dec._slot_prefill_jitted(
             self._pool, raw, jnp.int32(slot_idx))
+        self._prefill_tokens += Tp
         last = logits[:, Tp - 1]                       # (1, V)
         keys = None
         if req.seed is not None and req.sampled:
@@ -822,8 +836,7 @@ class ContinuousBatchingEngine:
                 return int(last.toks[-1]) == slot.req.eos_id
             # eos needs a host read; only requests that opted into an
             # eos token pay the sync
-            return int(jax.device_get(
-                last[slot.row])) == slot.req.eos_id
+            return int(_host_read(last[slot.row])) == slot.req.eos_id
         return False
 
     # -- speculative decoding --------------------------------------------
@@ -985,7 +998,7 @@ class ContinuousBatchingEngine:
                 tok = nxt.reshape(-1, 1)
         if not proposals:
             return {}
-        mat = onp.asarray(jax.device_get(jnp.stack(proposals, axis=1)))
+        mat = onp.asarray(_host_read(jnp.stack(proposals, axis=1)))
         out = {}
         for i in rows:
             k = self._spec_budget(self._slots[i])
@@ -1050,7 +1063,7 @@ class ContinuousBatchingEngine:
         hist_rows = [i for i in active
                      if self._slots[i].history is not None]
         if hist_rows:
-            toks = onp.asarray(jax.device_get(self._last_tokens))
+            toks = onp.asarray(_host_read(self._last_tokens))
             for i in hist_rows:
                 self._slots[i].history.append(int(toks[i]))
         trace_on = _tracer().active
@@ -1125,8 +1138,8 @@ class ContinuousBatchingEngine:
             axis=1)[:, 0].astype(jnp.int32)
         self._update_seen_window(active, M, counts, W)
         # ONE pooled host sync: accept counts + the emitted candidates
-        counts_h = onp.asarray(jax.device_get(counts))
-        M_h = onp.asarray(jax.device_get(M))
+        counts_h, M_h = (onp.asarray(x)
+                         for x in _host_read((counts, M)))
         self._steps += 1
         self._verify_calls += 1
         self._drafted_tokens += nreal
@@ -1277,7 +1290,7 @@ class ContinuousBatchingEngine:
         # ONE pooled host sync: accept counts + the emitted path tokens
         # AND the lanes they came from (the fix-up source map)
         counts_h, pathM_h, lane_h = (
-            onp.asarray(x) for x in jax.device_get(
+            onp.asarray(x) for x in _host_read(
                 (counts, path_M, path_lane)))
         self._steps += 1
         self._verify_calls += 1
@@ -1472,16 +1485,24 @@ class ContinuousBatchingEngine:
     # -- one scheduler iteration ----------------------------------------
     def step(self):
         """One scheduler iteration (``_step_impl`` docstring has the
-        semantics).  With tracing active the iteration runs inside an
-        ``engine.iteration`` span (and, under a live ``jax.profiler``
-        session, a TraceAnnotation) — host-side only, zero compiled
-        programs either way."""
-        tr = _tracer()
-        if not tr.active:
-            return self._step_impl()
-        with tr.span("engine.iteration", tag=self._trace_tag,
-                     step=self._steps):
-            return self._step_impl()
+        semantics), inside an ``engine.iteration`` boundary span — kept
+        tracer on or off, and under a live ``jax.profiler`` session a
+        TraceAnnotation; host-side only, zero compiled programs either
+        way.  Its phases are child spans (``engine.schedule``,
+        ``engine.prefill``, ``engine.decode_step``, and
+        ``engine.host_read`` around every blocking read of a device
+        value); its end carries the iteration's counts."""
+        with _tracer().span("engine.iteration", tag=self._trace_tag,
+                            step=self._steps) as span:
+            tokens, prefill = self._tokens_generated, self._prefill_tokens
+            self._iter_decoding = self._iter_prefilling = 0
+            done = self._step_impl()
+            span.set(decoding=self._iter_decoding,
+                     prefilling=self._iter_prefilling,
+                     waiting=len(self._queue),
+                     tokens=self._tokens_generated - tokens,
+                     prefill_tokens=self._prefill_tokens - prefill)
+            return done
 
     def _step_impl(self):
         """One iteration: evict deadline-expired requests, admit queued
@@ -1495,15 +1516,36 @@ class ContinuousBatchingEngine:
         quarantines that slot only — the iteration proceeds for every
         other slot with bit-identical results."""
         finished_before = set(self._results)
-        self._evict_expired()
-        self._maybe_install_adoption()
-        if self._queue and self._staged_adoption is None:
-            self._ensure_pool(nd_array(self._queue[0].prompt))
-        # admission at the iteration boundary (Orca-style): joiners
-        # prefill now and take part in the very next pooled step —
-        # gated while a staged weight generation awaits its empty
-        # boundary (a fresh admission would pin the OLD generation
-        # and starve the install under continuous load)
+        with _tracer().span("engine.schedule"):
+            self._evict_expired()
+            self._maybe_install_adoption()
+            if self._queue and self._staged_adoption is None:
+                self._ensure_pool(nd_array(self._queue[0].prompt))
+            self._admit_queued()
+        active = [i for i, s in enumerate(self._slots) if s is not None]
+        # hot-swap invariant: every decoding slot rides the weight
+        # generation pinned at its admission (installs happen only at
+        # empty boundaries, so these can never diverge)
+        assert all(self._slots[i].param_gen == self._param_gen
+                   for i in active), "slot outlived a weight install"
+        # per-slot fault site, consulted at the iteration boundary in
+        # slot order (deterministic hit counting): a raise here models a
+        # per-request step failure and quarantines exactly that slot
+        for i in list(active):
+            try:
+                _inject("serving.step", key=self._slots[i].req.rid)
+            except Exception as exc:
+                self._quarantine(i, exc, "serving.step")
+                active.remove(i)
+        self._decode_step(active)
+        return [r for r in self._results if r not in finished_before]
+
+    def _admit_queued(self):
+        """Admission at the iteration boundary (Orca-style): joiners
+        prefill now and take part in the very next pooled step — gated
+        while a staged weight generation awaits its empty boundary (a
+        fresh admission would pin the OLD generation and starve the
+        install under continuous load)."""
         for i in range(self._num_slots):
             if not self._queue or self._staged_adoption is not None:
                 break
@@ -1522,24 +1564,16 @@ class ContinuousBatchingEngine:
                     self._quarantine_request(req, exc, "serving.admit",
                                              row=i)
 
-        active = [i for i, s in enumerate(self._slots) if s is not None]
-        # hot-swap invariant: every decoding slot rides the weight
-        # generation pinned at its admission (installs happen only at
-        # empty boundaries, so these can never diverge)
-        assert all(self._slots[i].param_gen == self._param_gen
-                   for i in active), "slot outlived a weight install"
-        # per-slot fault site, consulted at the iteration boundary in
-        # slot order (deterministic hit counting): a raise here models a
-        # per-request step failure and quarantines exactly that slot
-        for i in list(active):
-            try:
-                _inject("serving.step", key=self._slots[i].req.rid)
-            except Exception as exc:
-                self._quarantine(i, exc, "serving.step")
-                active.remove(i)
+    def _decode_step(self, active):
+        """``_decode_active`` for this iteration's decoding slots, as the
+        ``engine.decode_step`` phase; notes the iteration's counts."""
+        self._iter_decoding = len(active)
+        # an occupied slot that does not decode is still prefilling
+        self._iter_prefilling = sum(
+            s is not None for s in self._slots) - len(active)
         if active:
-            self._decode_active(active)
-        return [r for r in self._results if r not in finished_before]
+            with _tracer().span("engine.decode_step"):
+                self._decode_active(active)
 
     def _sample_pool(self, last, active, sample_next_token):
         """Pooled per-slot sampling: slots sharing a sampling config
@@ -2086,8 +2120,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             san.check_use(self._bp, bid)           # V002 gate
         content, self._pool = self._dec._swap_page_jitted(
             self._pool, self._swap_template(), bid, 0)
-        return jax.tree_util.tree_map(
-            lambda l: onp.asarray(jax.device_get(l)), content)
+        return jax.tree_util.tree_map(onp.asarray, _host_read(content))
 
     def _write_page(self, bid, content):
         """Host→device restore of one page (same program, write=1)."""
@@ -2514,7 +2547,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         self._status[req.rid] = "active"
         self._swap_attempted.discard(req.rid)   # bounded bookkeeping
         try:
-            self._advance_prefill(slot_idx)
+            # the prompt's first chunk: prefill work inside the
+            # admission's engine.schedule, so it is its child
+            with _tracer().span("engine.prefill"):
+                self._advance_prefill(slot_idx)
         except Exception:
             # the caller's quarantine path expects a FAILED admission
             # never to occupy the slot (the slot-engine invariant)
@@ -2548,6 +2584,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self._pool, raw, jnp.asarray(self._table_row(slot_idx)),
             jnp.int32(start), jnp.int32(src), jnp.int32(dst),
             total_len=(slot.Tp if moe else None))
+        self._prefill_tokens += Tact
         slot.chunk_i += 1
         if slot.chunk_i < len(slot.chunks):
             return                           # more chunks next iteration
@@ -2647,21 +2684,49 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         under the admission site.  (``step()`` wraps this in the
         ``engine.iteration`` trace span — base class.)"""
         finished_before = set(self._results)
-        self._evict_expired()
-        self._maybe_install_adoption()
+        tr = _tracer()
+        with tr.span("engine.schedule"):
+            self._evict_expired()
+            self._maybe_install_adoption()
         # chunked prefill FIRST: slots already prefilling advance one
         # chunk per iteration, interleaved with (never stalling) the
         # decode step below; slots admitted later this iteration ran
         # their first chunk inside _admit and wait for the next one
-        for i in range(self._num_slots):
-            s = self._slots[i]
-            if s is not None and s.prefilling:
-                try:
-                    self._advance_prefill(i)
-                except Exception as exc:
-                    self._quarantine(i, exc, "serving.admit")
-        if self._queue and self._staged_adoption is None:
-            self._ensure_pool(nd_array(self._queue[0].prompt))
+        prefilling = [i for i, s in enumerate(self._slots)
+                      if s is not None and s.prefilling]
+        if prefilling:
+            with tr.span("engine.prefill"):
+                for i in prefilling:
+                    try:
+                        self._advance_prefill(i)
+                    except Exception as exc:
+                        self._quarantine(i, exc, "serving.admit")
+        # (the second engine.schedule of the iteration: admissions, each
+        # running its prompt's first chunk inside _admit)
+        with tr.span("engine.schedule"):
+            if self._queue and self._staged_adoption is None:
+                self._ensure_pool(nd_array(self._queue[0].prompt))
+            self._admit_queued()
+
+        active = [i for i, s in enumerate(self._slots)
+                  if s is not None and not s.prefilling]
+        # hot-swap invariant (base _step_impl docstring): decoding
+        # slots ride their admission-pinned weight generation
+        assert all(self._slots[i].param_gen == self._param_gen
+                   for i in active), "slot outlived a weight install"
+        for i in list(active):
+            try:
+                _inject("serving.step", key=self._slots[i].req.rid)
+            except Exception as exc:
+                self._quarantine(i, exc, "serving.step")
+                active.remove(i)
+        self._decode_step(active)
+        self._service_pending_swap()
+        return [r for r in self._results if r not in finished_before]
+
+    def _admit_queued(self):
+        """The base engine's admission loop, deferring at the queue head
+        on transient page exhaustion."""
         deferred = False
         for i in range(self._num_slots):
             if not self._queue or deferred \
@@ -2684,23 +2749,6 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 except Exception as exc:
                     self._quarantine_request(req, exc, "serving.admit",
                                              row=i)
-
-        active = [i for i, s in enumerate(self._slots)
-                  if s is not None and not s.prefilling]
-        # hot-swap invariant (base _step_impl docstring): decoding
-        # slots ride their admission-pinned weight generation
-        assert all(self._slots[i].param_gen == self._param_gen
-                   for i in active), "slot outlived a weight install"
-        for i in list(active):
-            try:
-                _inject("serving.step", key=self._slots[i].req.rid)
-            except Exception as exc:
-                self._quarantine(i, exc, "serving.step")
-                active.remove(i)
-        if active:
-            self._decode_active(active)
-        self._service_pending_swap()
-        return [r for r in self._results if r not in finished_before]
 
     def _service_pending_swap(self):
         """Iteration-boundary tail of ``overlap_swaps=True``: run the

@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import autograd, ndarray as nd, optimizer as opt_mod
 from .. import random as _random
 from ..ndarray import NDArray
+from ..observability.trace import get_tracer as _tracer
 from ..ops.pallas.partition import head_sharding_scope
 from .mesh import DeviceMesh
 from .sharding import ShardingRules
@@ -452,15 +453,25 @@ class SPMDTrainer:
         """Resolve deferred shapes with one imperative forward and stage
         params/optimizer state onto the mesh (idempotent)."""
         if not self._params_sharded:
-            with autograd.pause(train_mode=False):
-                self._block(data if isinstance(data, NDArray)
-                            else nd.array(data))
-            self._stage_params()
+            with _tracer().span("trainer.stage"):
+                with autograd.pause(train_mode=False):
+                    self._block(data if isinstance(data, NDArray)
+                                else nd.array(data))
+                self._stage_params()
 
     def step(self, data, label):
         """One optimization step on a global batch. Returns the (device)
         scalar loss NDArray; no host sync — call .asnumpy() to block.
-        (Guarded trainers additionally sync the one ``ok`` scalar.)"""
+        (Guarded trainers additionally sync the one ``ok`` scalar.)
+
+        The call is one ``trainer.step`` boundary span (host dispatch,
+        not device time): ``step`` is the update count it made, ``first``
+        whether the batch signature was new (the call traced, lowered
+        and compiled or fetched), ``tokens`` the batch's elements."""
+        with _tracer().span("trainer.step") as span:
+            return self._step(data, label, span)
+
+    def _step(self, data, label, span):
         self._ensure_staged(data)
 
         data = data if isinstance(data, NDArray) else nd.array(data)
@@ -489,6 +500,7 @@ class SPMDTrainer:
                 shapes=(sig[0], sig[2]), dtypes=(sig[1], sig[3]),
                 weak=(), static=(self._guard, self._dyn_scale)),
                 hit=jitted is not None)
+        span.set(first=jitted is None, tokens=int(batch.size))
         if jitted is None:
             jitted = self._build_step(*sig)
             self._jit_cache[sig] = jitted
@@ -534,6 +546,7 @@ class SPMDTrainer:
         for p, leaf in zip(self._aux_params, new_aux):
             p.data()._rebind(leaf)
         self._opt_states = list(new_states)
+        span.set(step=self._num_update)
         return NDArray(loss)
 
     def step_program(self, data, label):
@@ -598,7 +611,13 @@ class SPMDTrainer:
         drift the counter vs the per-step drive.
 
         Returns a :class:`TrainWindow`; ``losses`` stays async (one more
-        transfer — no extra compute wait — to read)."""
+        transfer — no extra compute wait — to read).  One
+        ``trainer.step`` boundary span for the whole window (``steps`` =
+        N, ``step`` = the update count after it)."""
+        with _tracer().span("trainer.step") as span:
+            return self._step_window(data, label, count_skips, span)
+
+    def _step_window(self, data, label, count_skips, span):
         from ..resilience.counters import bump
 
         data = data if isinstance(data, NDArray) else nd.array(data)
@@ -640,6 +659,7 @@ class SPMDTrainer:
                 shapes=(sig[2], sig[4]), dtypes=(sig[3], sig[5]),
                 weak=(), static=(n, self._guard, self._dyn_scale)),
                 hit=jitted is not None)
+        span.set(first=jitted is None, tokens=int(batch.size), steps=n)
         if jitted is None:
             jitted = self._build_multi_step(n, *sig[2:])
             self._jit_cache[sig] = jitted
@@ -691,6 +711,7 @@ class SPMDTrainer:
             num_good = n
 
         self._num_update += num_good
+        span.set(step=self._num_update)
         iuc = self._optimizer._index_update_count
         for i in range(len(self._diff_params)):
             iuc[i] = self._num_update

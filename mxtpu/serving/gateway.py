@@ -482,12 +482,9 @@ class Gateway:
         supervised pool (health → step → poll per replica), ingest
         token/finish events, requeue drained tags, then run the hedge
         and deadline sweeps.  Returns the rids that went terminal this
-        pump.  With tracing active the iteration runs inside a
-        ``gateway.pump`` span."""
-        tr = _tracer()
-        if not tr.active:
-            return self._pump_impl()
-        with tr.span("gateway.pump", tick=self._tick + 1):
+        pump.  The iteration is one ``gateway.pump`` boundary span,
+        kept whether the tracer is on or off."""
+        with _tracer().span("gateway.pump", tick=self._tick + 1):
             return self._pump_impl()
 
     def _pump_impl(self) -> List[int]:

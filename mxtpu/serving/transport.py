@@ -340,6 +340,12 @@ class InProcessReplica(ReplicaTransport):
 
     def poll(self):
         _inject("replica.stream", key=self.replica_id)
+        # draining the stream reads every new token off the device: the
+        # first read waits for the step the engine just dispatched
+        with _tracer().span("engine.host_read", site="replica.poll"):
+            return self._poll()
+
+    def _poll(self):
         tokens: Dict[Any, List[int]] = {}
         finished: List[Tuple[Any, str, Optional[NDArray],
                              Optional[dict]]] = []

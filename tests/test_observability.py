@@ -683,3 +683,320 @@ def test_profiler_counters_markers_serve_through_registry():
         types = [e.etype for e in tr.events()]
         assert "profiler.counter" in types
         assert "profiler.marker" in types
+
+
+# ------------------------------- the wall clock and the boundary ring (PR 27)
+
+
+def test_t_ns_on_every_event_monotone_and_out_of_the_bytes(micro_lm, mesh,
+                                                           rules):
+    """Every event carries ``t_ns`` (perf_counter_ns, so it never runs
+    backwards in tick order); the deterministic serialization holds
+    neither it nor ``parent``, and byte identity under a fault plan
+    holds with both recorded."""
+    pa, pb = _prompts()
+
+    def run_once():
+        eng = _paged_engine(micro_lm, mesh, rules)
+        with tracing() as tr:
+            with fault_plan("serving.step@3:raise=RuntimeError(boom)"):
+                eng.submit(nd.array(pa, dtype="int32"), 3)
+                eng.submit(nd.array(pb, dtype="int32"), 3, retries=1)
+                eng.run()
+            return tr.events(), tr.to_json(), tr.to_json(include_noise=True)
+
+    evs, plain, noisy = run_once()
+    assert evs and all(e.t_ns > 0 for e in evs)
+    assert [e.t_ns for e in evs] == sorted(e.t_ns for e in evs)
+    assert "t_ns" not in plain and '"parent"' not in plain
+    recs = json.loads(noisy)["events"]
+    assert all("t_ns" in r for r in recs)
+    assert any("parent" in r for r in recs)
+    assert run_once()[1] == plain           # bytes: same seeds, same plan
+    # an instant's parent is the span open on its thread
+    begins = {e.tick for e in evs if e.phase == "B"}
+    inner = [e for e in evs if e.etype == "engine.decode"]
+    assert inner and all(e.parent in begins for e in inner)
+
+
+def test_boundary_ring_fills_with_the_tracer_off(micro_lm, mesh, rules):
+    """Boundary spans are kept with the tracer off (``events()`` stays
+    empty), parents link host_read -> decode_step -> iteration, and the
+    iteration ends carry counts that add up to ``engine.stats``."""
+    from mxtpu.observability.trace import BOUNDARY_TYPES
+
+    assert BOUNDARY_TYPES <= set(EVENT_TYPES)
+    pa, pb = _prompts()
+    tr = get_tracer()
+    tr.reset()
+    assert not tr.active
+    eng = _paged_engine(micro_lm, mesh, rules)
+    # eos ids that never match: every decoding slot pays its host read
+    eng.submit(nd.array(pa, dtype="int32"), 4, eos_id=10 ** 6)
+    eng.submit(nd.array(pb, dtype="int32"), 3, eos_id=10 ** 6)
+    eng.run()
+    assert tr.events() == []
+    ring = tr.boundary_spans()
+    by_tick = {s.tick: s for s in ring}
+    its = [s for s in ring if s.etype == "engine.iteration"]
+    assert its and all(s.parent is None and s.end_ns >= s.start_ns
+                       for s in its)
+    assert tr.boundary_spans("engine.iteration") == its
+    reads = [s for s in ring if s.etype == "engine.host_read"]
+    assert reads
+    for r in reads:     # under the phase that asked (a prompt's last
+        phase = by_tick[r.parent]   # chunk checks eos too)
+        assert phase.etype in ("engine.decode_step", "engine.prefill")
+        assert phase.start_ns <= r.start_ns and r.end_ns <= phase.end_ns
+    in_decode = [by_tick[r.parent] for r in reads
+                 if by_tick[r.parent].etype == "engine.decode_step"]
+    assert in_decode and all(by_tick[p.parent].etype == "engine.iteration"
+                             for p in in_decode)
+    kinds = {s.etype for s in ring if by_tick.get(s.parent) in its}
+    assert kinds == {"engine.schedule", "engine.prefill",
+                     "engine.decode_step"}
+    # an admission's first chunk is prefill work inside the schedule
+    assert any(s.etype == "engine.prefill"
+               and by_tick[s.parent].etype == "engine.schedule"
+               for s in ring)
+    st = eng.stats
+    assert sum(s.fields["tokens"] for s in its) == st["generated_tokens"]
+    assert sum(s.fields["decoding"] for s in its) == st["slot_iterations"]
+    assert sum(s.fields["prefill_tokens"] for s in its) \
+        == st["prefill_tokens"] == pa.shape[1] + pb.shape[1] \
+        - st["prefill_tokens_avoided"]
+    assert sum(1 for s in its if s.fields["decoding"]) == st["steps"]
+    assert its[-1].fields["waiting"] == 0
+    assert all(s.fields["tag"] == "eng" for s in its)
+    assert {"decoding", "prefilling", "waiting", "tokens",
+            "prefill_tokens", "step"} <= set(its[0].fields)
+
+
+def test_iteration_end_event_carries_the_counts(micro_lm, mesh, rules):
+    """With the tracer on the same counts ride on the end event, at the
+    same boundary, and the ring holds the same spans."""
+    pa, _ = _prompts()
+    eng = _paged_engine(micro_lm, mesh, rules)
+    with tracing() as tr:
+        eng.submit(nd.array(pa, dtype="int32"), 3)
+        eng.run()
+        ends = [e for e in tr.events(types="engine.iteration")
+                if e.phase == "E"]
+        its = tr.boundary_spans("engine.iteration")
+    assert len(ends) == len(its) > 0
+    for e, s in zip(ends, its):
+        assert e.fields == {k: v for k, v in s.fields.items()
+                            if k not in ("tag", "step")}
+        assert e.t_ns == s.end_ns
+    assert sum(e.fields["tokens"] for e in ends) \
+        == eng.stats["generated_tokens"]
+
+
+def test_gateway_pump_is_a_boundary_span(micro_lm, mesh, rules):
+    from mxtpu.serving import Gateway, replica_pool
+
+    pa, _ = _prompts()
+    tr = get_tracer()
+    tr.reset()
+    gw = Gateway(replica_pool(
+        lambda i: _paged_engine(micro_lm, mesh, rules), n=1))
+    gw.submit(nd.array(pa, dtype="int32"), 2)
+    gw.run()
+    assert tr.events() == []
+    pumps = tr.boundary_spans("gateway.pump")
+    assert len(pumps) == gw.stats["ticks"] > 0
+    ticks = {p.tick for p in pumps}
+    its = tr.boundary_spans("engine.iteration")
+    assert its and all(s.parent in ticks for s in its)
+
+
+def _toy_trainer():
+    from mxtpu import gluon
+    from mxtpu.gluon import nn
+    from mxtpu.parallel import SPMDTrainer
+
+    mx.random.seed(11)
+    net = nn.Dense(16, in_units=8)
+    net.initialize()
+    return SPMDTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+                       DeviceMesh(dp=1), optimizer_params={
+                           "learning_rate": 0.1}, guard=False)
+
+
+def _toy_batch(rows):
+    rng = np.random.RandomState(rows)
+    return (mx.nd.array(rng.rand(rows, 8).astype(np.float32)),
+            mx.nd.array(rng.randint(0, 16, (rows,)).astype(np.float32)))
+
+
+def test_trainer_spans_stage_first_and_steps():
+    """A toy ``SPMDTrainer`` on the CPU: one ``trainer.stage``, inside
+    the first ``trainer.step``; ``first`` true once per batch signature;
+    N steps give N spans; the step that compiled is the parent of its
+    ``xla.compile`` spans — all with the tracer off."""
+    tr = get_tracer()
+    tr.reset()
+    trainer = _toy_trainer()
+    for rows in (8, 8, 8, 4, 8, 4):
+        trainer.step(*_toy_batch(rows))
+    assert tr.events() == []
+    steps = tr.boundary_spans("trainer.step")
+    assert [s.fields["step"] for s in steps] == [1, 2, 3, 4, 5, 6]
+    assert [s.fields["first"] for s in steps] == [True, False, False, True,
+                                                  False, False]
+    assert [s.fields["tokens"] for s in steps] == [64, 64, 64, 32, 64, 32]
+    stage = tr.boundary_spans("trainer.stage")
+    assert len(stage) == 1 and stage[0].parent == steps[0].tick
+    assert steps[0].start_ns <= stage[0].start_ns <= stage[0].end_ns \
+        <= steps[0].end_ns
+    compiled = {}
+    for s in tr.boundary_spans("xla.compile"):
+        assert s.tick is None and s.fields["kind"] in (
+            "trace", "lower", "compile", "cache_fetch")
+        assert s.seconds == pytest.approx(s.fields["seconds"], abs=1e-6)
+        compiled.setdefault(s.parent, set()).add(s.fields["kind"])
+    # the two signatures' steps traced, lowered and compiled; no other did
+    for s in steps:
+        kinds = compiled.get(s.tick, set())
+        if s.fields["first"]:
+            assert {"trace", "lower", "compile"} <= kinds
+        else:
+            assert not kinds
+    assert compiled.get(stage[0].tick)      # the eager forward's programs
+
+
+def test_step_window_is_one_trainer_step_span():
+    tr = get_tracer()
+    trainer = _toy_trainer()
+    x, y = _toy_batch(8)
+    trainer.step(x, y)
+    tr.reset()
+    xs = mx.nd.array(np.stack([x.asnumpy()] * 3))
+    ys = mx.nd.array(np.stack([y.asnumpy()] * 3))
+    trainer.step_window(xs, ys)
+    trainer.step_window(xs, ys)
+    spans = tr.boundary_spans("trainer.step")
+    assert [(s.fields["first"], s.fields["steps"], s.fields["step"],
+             s.fields["tokens"]) for s in spans] == [
+        (True, 3, 4, 192), (False, 3, 7, 192)]
+    assert tr.boundary_spans("trainer.stage") == []
+
+
+def test_fresh_jit_is_an_xla_compile_under_the_open_span():
+    import jax
+    import jax.numpy as jnp
+
+    tr = get_tracer()
+    tr.reset()
+    with tr.span("trainer.step") as sp:
+        jax.jit(lambda v: jnp.cos(v) * 5.0 - 2.0)(jnp.ones((7, 3)))
+    jax.jit(lambda v: jnp.sin(v) * 7.0 - 3.0)(jnp.ones((7, 3)))
+    spans = tr.boundary_spans()
+    step = spans[[s.etype for s in spans].index("trainer.step")]
+    mine = [s for s in spans if s.etype == "xla.compile"
+            and s.parent == step.tick]
+    assert {s.fields["kind"] for s in mine} >= {"trace", "lower", "compile"}
+    assert all(step.start_ns <= s.start_ns and s.end_ns <= step.end_ns
+               for s in mine if s.fields["kind"] != "trace")
+    # one outside any span has no parent; none of them took a tick
+    loose = [s for s in spans if s.etype == "xla.compile"
+             and s.parent is None]
+    assert {s.fields["kind"] for s in loose} >= {"trace", "lower", "compile"}
+    assert tr.ticks == 1 and tr.events() == []
+
+
+def test_ring_is_bounded_and_non_boundary_spans_stay_off():
+    from mxtpu.observability.trace import MAX_BOUNDARY_SPANS, Tracer
+
+    tr = Tracer(enabled=False)
+    for _ in range(MAX_BOUNDARY_SPANS + 10):
+        with tr.span("engine.host_read"):
+            pass
+    ring = tr.boundary_spans()
+    assert len(ring) == MAX_BOUNDARY_SPANS
+    assert ring[-1].tick == MAX_BOUNDARY_SPANS + 10     # the oldest went
+    with tr.span("guardian.window"):        # not a boundary type: nothing
+        pass
+    assert len(tr.boundary_spans()) == MAX_BOUNDARY_SPANS
+    assert tr.ticks == MAX_BOUNDARY_SPANS + 10
+    assert tr.stats()["boundary_spans"] == MAX_BOUNDARY_SPANS
+
+
+def test_boundary_span_lands_in_any_profiler_session(tmp_path):
+    """A session started with ``jax.profiler.start_trace`` directly (the
+    benchmark's way, not ``mxtpu.profiler``) holds the boundary spans as
+    ``mxtpu.<type>`` annotations."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = get_tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("trainer.step"):
+            with tr.span("trainer.stage"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    assert paths
+    names = {e.name for plane in ProfileData.from_file(paths[0]).planes
+             for line in plane.lines for e in line.events}
+    assert {"mxtpu.trainer.step", "mxtpu.trainer.stage"} <= names
+
+
+def test_chrome_export_on_the_wall_clock_and_on_ticks(micro_lm, mesh, rules):
+    """One writer, two timelines: ``t_ns`` in microseconds with
+    ``include_noise``, ticks without (and then deterministic)."""
+    pa, _ = _prompts()
+
+    def run_once():
+        eng = _paged_engine(micro_lm, mesh, rules)
+        with tracing() as tr:
+            eng.submit(nd.array(pa, dtype="int32"), 2)
+            eng.run()
+            return (tr.events(), export_chrome_trace(),
+                    export_chrome_trace(include_noise=False))
+
+    evs, wall, ticks = run_once()
+    wall_ts = [e["ts"] for e in json.loads(wall)["traceEvents"]
+               if e["cat"] == "mxtpu"]
+    assert wall_ts == [e.t_ns / 1e3 for e in evs]
+    tick_evs = [e for e in json.loads(ticks)["traceEvents"]
+                if e["cat"] == "mxtpu"]
+    assert [e["ts"] for e in tick_evs] == [e.tick for e in evs]
+    assert "tick" in json.loads(ticks)["otherData"]["clock"]
+    assert "perf_counter" in json.loads(wall)["otherData"]["clock"]
+
+
+def test_flash_kernels_carry_their_names():
+    """The lowered text (with debug info) of a flash forward and
+    backward holds the three kernels' names."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    x = jnp.ones((1, 2, 128, 64), jnp.float32)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v).sum(),
+        argnums=(0, 1, 2))).lower(x, x, x).as_text(debug_info=True)
+    for name in ("flash_attention_fwd", "flash_attention_dq",
+                 "flash_attention_dkv"):
+        assert name in text, name
+
+
+@pytest.mark.parametrize("module, name", [
+    ("paged_attention", "paged_attention_decode"),
+    ("prefill_attention", "paged_prefill_chunk"),
+])
+def test_paged_kernels_pass_their_names(module, name):
+    import importlib
+    import inspect
+
+    src = inspect.getsource(importlib.import_module(
+        "mxtpu.ops.pallas." + module))
+    assert 'name="%s"' % name in src
