@@ -275,7 +275,7 @@ def _bench_attention():
         "platform": platform,
         "config": {"batch": B, "heads": H, "head_dim": D,
                    "dtype": "bfloat16", "causal": True,
-                   "backward": "pallas dq/dkv kernels"},
+                   "backward": "one pallas kernel (dq, dk, dv)"},
         "seq_512": results[512],
         "seq_2048": results[2048],
         "baseline_note": "no upstream analogue (reference has no "

@@ -601,9 +601,9 @@ def default_kernel_specs() -> List[KernelSpec]:
     the set ``check_kernels()`` (and ``python -m mxtpu.analysis
     kernel``) verdicts as the merge gate:
 
-    - flash_attention fwd + both backward kernels, fp32 training shape
-      and the bf16 serving-prefill shape (T=2048, D=128, 128/128
-      blocks);
+    - flash_attention fwd + the one backward kernel, fp32 training
+      shape and the bf16 serving-prefill shape (T=2048, D=128, 128/128
+      blocks forward, the backward's 512 x 512 tiles);
     - conv_bwd at the ResNet small-channel stage its VMEM gate admits
       (56x56x64, fp32);
     - paged_attention decode (W=1) and W-wide speculative verify (W=8),

@@ -6,12 +6,12 @@ algorithm: Q blocks stay resident in VMEM while K/V blocks stream through,
 softmax runs in online (max/denominator-carrying) form, so HBM traffic is
 O(T·D) instead of O(T²).
 
-Backward (round-4; SURVEY §7 hard-part 7) is the FlashAttention-2
-formulation in Pallas: the forward additionally emits the per-row
-logsumexp; dq streams K/V blocks per Q block, dk/dv streams Q/dO blocks
-per K/V block, with delta = rowsum(dO·O) precomputed in XLA.  Set
-MXTPU_FLASH_BWD=0 to fall back to the previous recompute-through-XLA
-backward.
+Backward (SURVEY §7 hard-part 7) is the FlashAttention-2 formulation in
+one Pallas kernel: the forward additionally emits the per-row
+logsumexp; per K/V block the kernel streams the head's Q/dO blocks,
+forms each tile's S, P and dS once and accumulates dk and dv for the
+block and dq for the head (a float32 VMEM scratch carried across the
+kv-block grid axis), with delta = rowsum(dO·O) precomputed in XLA.
 
 On CPU (tests) the kernels run in interpret mode; numerics match the
 dense reference implementation to ~1e-5 (fp32) / 1e-2 (bf16).
@@ -25,6 +25,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...base import register_op
 from . import counters
@@ -44,7 +45,8 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
     workload geometry — same padding and block construction as
     _flash_fwd/_flash_bwd, so the static pass verdicts exactly the
     calls that would run."""
-    from ...analysis.kernel_check import BlockOperand, KernelSpec
+    from ...analysis.kernel_check import (BlockOperand, KernelSpec,
+                                          ScratchOperand)
 
     qb = min(q_block, T)
     kb = min(kv_block, T)
@@ -54,8 +56,8 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
 
     def blk(name, kind, shape, array, dt, imap):
         # D (head_dim) and the q/kv block tiles are chosen parameters,
-        # strict on both trailing dims; the lse/delta columns carry a
-        # trailing unit dim (their array's full extent), so only their
+        # strict on both trailing dims; the forward's lse column carries
+        # a trailing unit dim (its array's full extent), so only its
         # q_block-sized sublane dim is a choice
         strict = (-2,) if shape[-1] == 1 else (-1, -2)
         return BlockOperand(name, kind, shape, array, dt, imap,
@@ -76,33 +78,29 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
         interpret=interpret)]
     if not backward:
         return specs
+    qb, kb = _bwd_tile(qb, Tq), _bwd_tile(kb, Tk)
+    nq = Tq // qb
+    kv_im = lambda b, j: (b, j, 0)     # noqa: E731 — mirrors _flash_bwd
+    # dq's block is resident over the kv-block axis — the grid's
+    # innermost, so K006 holds — beside its float32 accumulator; lse and
+    # delta lie along lanes, a row a Q block, whole for the head
     specs.append(KernelSpec(
-        "flash_attention.bwd_dq[%s,T=%d,D=%d]" % (dtype, T, D),
-        grid=(BH, Tq // qb),
-        operands=[
-            blk("q", "in", (1, qb, D), (BH, Tq, D), dtype, q_im),
-            blk("k", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
-            blk("v", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
-            blk("do", "in", (1, qb, D), (BH, Tq, D), dtype, q_im),
-            blk("lse", "in", (1, qb, 1), (BH, Tq, 1), "float32", q_im),
-            blk("delta", "in", (1, qb, 1), (BH, Tq, 1), "float32", q_im),
-            blk("dq", "out", (1, qb, D), (BH, Tq, D), dtype, q_im),
-        ],
-        interpret=interpret))
-    kv_im = lambda b, j: (b, j, 0)     # noqa: E731
-    specs.append(KernelSpec(
-        "flash_attention.bwd_dkv[%s,T=%d,D=%d]" % (dtype, T, D),
+        "flash_attention.bwd[%s,T=%d,D=%d]" % (dtype, T, D),
         grid=(BH, Tk // kb),
         operands=[
             blk("q", "in", (1, Tq, D), (BH, Tq, D), dtype, full_im),
             blk("k", "in", (1, kb, D), (BH, Tk, D), dtype, kv_im),
             blk("v", "in", (1, kb, D), (BH, Tk, D), dtype, kv_im),
             blk("do", "in", (1, Tq, D), (BH, Tq, D), dtype, full_im),
-            blk("lse", "in", (1, Tq, 1), (BH, Tq, 1), "float32", full_im),
-            blk("delta", "in", (1, Tq, 1), (BH, Tq, 1), "float32", full_im),
+            BlockOperand("lse", "in", (1, nq, qb), (BH, nq, qb), "float32",
+                         full_im),
+            BlockOperand("delta", "in", (1, nq, qb), (BH, nq, qb),
+                         "float32", full_im),
+            blk("dq", "out", (1, Tq, D), (BH, Tq, D), dtype, full_im),
             blk("dk", "out", (1, kb, D), (BH, Tk, D), dtype, kv_im),
             blk("dv", "out", (1, kb, D), (BH, Tk, D), dtype, kv_im),
         ],
+        scratch=[ScratchOperand("dq_acc", (Tq, D), "float32")],
         interpret=interpret))
     return specs
 
@@ -203,97 +201,81 @@ def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
     return out.reshape(B, H, Tq, D)[:, :, :t_orig], lse
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               scale, causal, q_block, kv_block, seq_len, q_seq_len,
-               valid_len, hi_prec):
-    """dq for one Q block: stream K/V blocks, p = exp(s - lse),
-    ds = p * (dp - delta), dq += scale * ds @ K."""
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                dk_ref, dv_ref, dq_acc, *, scale, causal, q_block, kv_block,
+                seq_len, valid_len, hi_prec):
+    """dq, dk and dv for one K/V block: stream Q/dO blocks (from the
+    diagonal on for causal) and form every tile once, transposed —
+    S^T = K Q^T, P^T = exp(S^T - lse), dS^T = P^T * (V dO^T - delta) —
+    so that dv += P^T dO and dk += dS^T Q consume it as it lies and only
+    dq += dS K contracts over its rows.  dq accumulates in ``dq_acc``
+    across the kv-block grid axis and leaves at its last step."""
     prec = jax.lax.Precision.HIGHEST if hi_prec else None
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)              # (Bq, D), UNscaled
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                              # (Bq, 1)
-    delta = delta_ref[0]
-    bq, d = q.shape
-    nkv_total = seq_len // kv_block
-    if causal:
-        nkv = jnp.minimum(((qi + 1) * q_block + kv_block - 1) // kv_block,
-                          nkv_total)
-    else:
-        nkv = nkv_total
-
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * kv_block, kv_block), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * kv_block, kv_block), :].astype(jnp.float32)
-        s = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                            precision=prec)
-        k_pos = j * kv_block + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, kv_block), 1)
-        if valid_len != seq_len:
-            s = jnp.where(k_pos < valid_len, s, _NEG_INF)
-        if causal:
-            q_pos = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, kv_block), 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)                      # masked entries -> ~0
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32,
-                     precision=prec)
-        ds = p * (dp - delta)
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32,
-                            precision=prec)
-
-    dq0 = jnp.zeros((bq, d), jnp.float32)
-    dq = jax.lax.fori_loop(0, nkv, body, dq0)
-    dq_ref[0] = (scale * dq).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, *, scale, causal, q_block, kv_block, seq_len,
-                q_seq_len, valid_len, hi_prec):
-    """dk/dv for one K/V block: stream Q/dO blocks (from the diagonal on
-    for causal), dv += p^T @ dO, dk += scale * ds^T @ Q."""
-    prec = jax.lax.Precision.HIGHEST if hi_prec else None
+    dot = functools.partial(jax.lax.dot_general, precision=prec,
+                            preferred_element_type=jnp.float32)
+    a_bt = (((1,), (1,)), ((), ()))               # a @ b.T
+    a_b = (((1,), (0,)), ((), ()))                # a @ b
+    at_b = (((0,), (0,)), ((), ()))               # a.T @ b
     kj = pl.program_id(1)
+
+    @pl.when(kj == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
     k = k_ref[0].astype(jnp.float32)              # (Bkv, D)
     v = v_ref[0].astype(jnp.float32)
     bkv, d = k.shape
-    # Q-side padded length, NOT the K-side seq_len: with q_block !=
-    # kv_block the two paddings differ and Tk//q_block would read past
-    # the end of the q/do/lse blocks
-    nq_total = q_seq_len // q_block
+    # the Q side's own blocks, a row of lse each: with q_block !=
+    # kv_block it is padded to another length than the K side's seq_len
+    nq_total = lse_ref.shape[1]
     i0 = (kj * kv_block) // q_block if causal else 0
 
-    k_pos_col = kj * kv_block + jax.lax.broadcasted_iota(
-        jnp.int32, (q_block, bkv), 1)
+    k_pos = kj * kv_block + jax.lax.broadcasted_iota(
+        jnp.int32, (bkv, q_block), 0)
 
     def body(i, carry):
         dk, dv = carry
-        qb = q_ref[0, pl.ds(i * q_block, q_block), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * q_block, q_block), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * q_block, q_block), :]    # (Bq, 1)
-        delta = delta_ref[0, pl.ds(i * q_block, q_block), :]
-        s = scale * jnp.dot(qb, k.T, preferred_element_type=jnp.float32,
-                            precision=prec)       # (Bq, Bkv)
+        rows = pl.ds(i * q_block, q_block)
+        qb = q_ref[0, rows, :].astype(jnp.float32)          # UNscaled
+        do = do_ref[0, rows, :].astype(jnp.float32)
+        lse = lse_ref[0, pl.ds(i, 1), :]                    # (1, Bq)
+        delta = delta_ref[0, pl.ds(i, 1), :]
+        st = scale * dot(k, qb, a_bt)                       # (Bkv, Bq)
         if valid_len != seq_len:
-            s = jnp.where(k_pos_col < valid_len, s, _NEG_INF)
+            st = jnp.where(k_pos < valid_len, st, _NEG_INF)
         if causal:
             q_pos = i * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, (q_block, bkv), 0)
-            s = jnp.where(q_pos >= k_pos_col, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dv = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32,
-                          precision=prec)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32,
-                     precision=prec)
-        ds = p * (dp - delta)
-        dk = dk + jnp.dot(ds.T, qb, preferred_element_type=jnp.float32,
-                          precision=prec)
+                jnp.int32, (bkv, q_block), 1)
+            st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+        pt = jnp.exp(st - lse)                    # masked entries -> ~0
+        dv = dv + dot(pt, do, a_b)
+        dst = pt * (dot(v, do, a_bt) - delta)
+        dk = dk + dot(dst, qb, a_b)
+        dq_acc[rows, :] += dot(dst, k, at_b)
         return dk, dv
 
     z = jnp.zeros((bkv, d), jnp.float32)
     dk, dv = jax.lax.fori_loop(i0, nq_total, body, (z, z))
     dk_ref[0] = (scale * dk).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(kj == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = (scale * dq_acc[...]).astype(dq_ref.dtype)
+
+
+#: the backward's tiles grow to this many rows: at 512 x 512 the one
+#: kernel took 4.05 ms a call on a v5e where 128 x 128 took 5.46 (384
+#: heads x 512 x 64 float32; PERF.md, PR 28)
+BWD_TILE = 512
+
+
+def _bwd_tile(block, padded):
+    """The widest run of whole forward blocks that is at most BWD_TILE
+    rows (one block where a block is wider) and tiles ``padded``."""
+    n = padded // block
+    return block * max(m for m in range(1, n + 1)
+                       if n % m == 0 and (m == 1 or block * m <= BWD_TILE))
 
 
 def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
@@ -305,55 +287,50 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
     gp, _ = _pad_to(g, 2, q_block)          # zero-padded dO: no gradient
     op, _ = _pad_to(o, 2, q_block)
     Tq, Tk = qp.shape[2], kp.shape[2]
+    # same padded lengths as the forward (lse has Tq rows), wider tiles
+    q_block = _bwd_tile(q_block, Tq)
+    kv_block = _bwd_tile(kv_block, Tk)
     BH = B * H
     qp = qp.reshape(BH, Tq, D)
     kp = kp.reshape(BH, Tk, D)
     vp = vp.reshape(BH, Tk, D)
     gp = gp.reshape(BH, Tq, D)
     op = op.reshape(BH, Tq, D)
-    # lse comes padded from the forward already (BH, Tq_padded, 1)
+    # the per-row vectors lie along lanes, one row a Q block.  lse comes
+    # padded from the forward already, as a (BH, Tq, 1) column
+    nq = Tq // q_block
+    lse = lse.reshape(BH, nq, q_block)
     delta = jnp.sum(gp.astype(jnp.float32) * op.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # (BH, Tq, 1)
+                    axis=-1).reshape(BH, nq, q_block)
 
-    common = dict(scale=scale, causal=causal, q_block=q_block,
-                  kv_block=kv_block, seq_len=Tk, q_seq_len=Tq,
-                  valid_len=T, hi_prec=q.dtype == jnp.float32)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **common),
-        out_shape=jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
-        grid=(BH, Tq // q_block),
-        in_specs=[
-            pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, q_block, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, q_block, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
-        interpret=interpret,
-        name="flash_attention_dq",
-    )(qp, kp, vp, gp, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **common),
-        out_shape=[jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
+    kernel = functools.partial(
+        _bwd_kernel, scale=scale, causal=causal, q_block=q_block,
+        kv_block=kv_block, seq_len=Tk, valid_len=T,
+        hi_prec=q.dtype == jnp.float32)
+    head = lambda b, j: (b, 0, 0)           # noqa: E731 — resident over j
+    kv = lambda b, j: (b, j, 0)             # noqa: E731
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
+                   jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, Tk, D), v.dtype)],
         grid=(BH, Tk // kv_block),
         in_specs=[
-            pl.BlockSpec((1, Tq, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, kv_block, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, kv_block, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, Tq, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, Tq, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, Tq, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, Tq, D), head),
+            pl.BlockSpec((1, kv_block, D), kv),
+            pl.BlockSpec((1, kv_block, D), kv),
+            pl.BlockSpec((1, Tq, D), head),
+            pl.BlockSpec((1, nq, q_block), head),
+            pl.BlockSpec((1, nq, q_block), head),
         ],
         out_specs=[
-            pl.BlockSpec((1, kv_block, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, kv_block, D), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, Tq, D), head),
+            pl.BlockSpec((1, kv_block, D), kv),
+            pl.BlockSpec((1, kv_block, D), kv),
         ],
+        scratch_shapes=[pltpu.VMEM((Tq, D), jnp.float32)],
         interpret=interpret,
-        name="flash_attention_dkv",
+        name="flash_attention_bwd",
     )(qp, kp, vp, gp, lse, delta)
 
     dq = dq.reshape(B, H, Tq, D)[:, :, :t_orig]
@@ -363,7 +340,7 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
 
 
 def _dense_attention(q, k, v, scale, causal):
-    """XLA reference path (also the recompute backward's forward)."""
+    """XLA reference path (shapes too small to tile; the tests' oracle)."""
     prec = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
     qf = q.astype(jnp.float32)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, k.astype(jnp.float32),
@@ -379,7 +356,7 @@ def _dense_attention(q, k, v, scale, causal):
 
 
 @functools.lru_cache(maxsize=32)
-def _make_flash(scale, causal, q_block, kv_block, interpret, pallas_bwd):
+def _make_flash(scale, causal, q_block, kv_block, interpret):
     @jax.custom_vjp
     def fa(q, k, v):
         out, _ = _flash_fwd(q, k, v, scale, causal, q_block, kv_block,
@@ -393,15 +370,8 @@ def _make_flash(scale, causal, q_block, kv_block, interpret, pallas_bwd):
 
     def fa_bwd(res, g):
         q, k, v, o, lse = res
-        if pallas_bwd:
-            return _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block,
-                              kv_block, interpret)
-        # legacy fallback (MXTPU_FLASH_BWD=0): recompute through the XLA
-        # formulation; XLA fuses this into blocked passes
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _dense_attention(q_, k_, v_, scale, causal),
-            q, k, v)
-        return vjp(g)
+        return _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block,
+                          kv_block, interpret)
 
     fa.defvjp(fa_fwd, fa_bwd)
     return fa
@@ -416,8 +386,6 @@ def flash_attention(q, k, v, causal=False, scale=None, q_block=128,
     Inside a ``head_sharding_scope`` (ops/pallas/partition.py) the call
     is shard_mapped over the scope's batch and heads axes.
     """
-    from ...base import env_bool
-
     B, H, T, D = q.shape
     scale = float(scale if scale is not None else 1.0 / math.sqrt(D))
     if T < 16 or D % 8 != 0:
@@ -425,10 +393,8 @@ def flash_attention(q, k, v, causal=False, scale=None, q_block=128,
     q_block = min(q_block, T)
     kv_block = min(kv_block, T)
     interpret = jax.default_backend() == "cpu"
-    pallas_bwd = env_bool("MXTPU_FLASH_BWD", True)
     counters.bump(KERNEL_NAME)
-    fa = _make_flash(scale, causal, q_block, kv_block, interpret,
-                     pallas_bwd)
+    fa = _make_flash(scale, causal, q_block, kv_block, interpret)
     # inside a sharded training step or tp>1 decoder program GSPMD
     # cannot partition the kernel: split it over batch and heads
     return shard_attention(fa, B, H)(q, k, v)

@@ -48,8 +48,9 @@ def test_shipped_kernels_pass_clean_at_tpu_geometries():
     specs = default_kernel_specs()
     names = " ".join(s.name for s in specs)
     assert "flash_attention.fwd" in names
-    assert "flash_attention.bwd_dq" in names
-    assert "flash_attention.bwd_dkv" in names
+    assert "flash_attention.bwd[" in names
+    assert "flash_attention.bwd_dq" not in names
+    assert "flash_attention.bwd_dkv" not in names
     assert "conv_bwd" in names
     assert "paged_attention[int8,W=8" in names
     assert "paged_attention[float32,W=1" in names
@@ -388,6 +389,48 @@ def test_vmem_estimate_prices_the_real_call(monkeypatch, cache_dtype):
         prefetch=spec.prefetch)
     assert kernel_vmem_estimate(rebuilt)["total_bytes"] == \
         kernel_vmem_estimate(spec)["total_bytes"]
+
+
+@pytest.mark.parametrize("T,blocks", [
+    (256, dict(q_block=128, kv_block=128)),     # widened to one tile
+    (150, dict(q_block=64, kv_block=128)),      # Tq = 192, Tk = 256
+    (640, dict(q_block=128, kv_block=128)),     # five tiles of 128
+], ids=["T256", "T150_q64_k128", "T640"])
+def test_flash_specs_describe_the_real_calls(monkeypatch, T, blocks):
+    """flash_attention.kernel_specs == the two pallas_calls a forward
+    and backward issue: names, grids, block shapes, dq's scratch."""
+    import importlib
+
+    import jax
+
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    calls = []
+    real = fa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        calls.append(kw)
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    fa._make_flash.cache_clear()
+    B, H, D = 1, 2, 16
+    x = jnp.ones((B, H, T, D), jnp.float32)
+    jax.grad(lambda q, k, v: fa.flash_attention(q, k, v, **blocks).sum(),
+             argnums=(0, 1, 2))(x, x, x)
+    specs = fa.kernel_specs(B, H, T, D, interpret=True, **blocks)
+    assert [c["name"] for c in calls] == \
+        ["flash_attention_fwd", "flash_attention_bwd"]
+    assert [s.name.split("[")[0] for s in specs] == \
+        ["flash_attention.fwd", "flash_attention.bwd"]
+    for call, spec in zip(calls, specs):
+        assert tuple(call["grid"]) == spec.grid
+        for kind, key in (("in", "in_specs"), ("out", "out_specs")):
+            assert [tuple(b.block_shape) for b in call[key]] == \
+                [op.block_shape for op in spec.operands
+                 if op.kind == kind], (spec.name, kind)
+        assert [(tuple(sc.shape), str(jnp.dtype(sc.dtype)))
+                for sc in call.get("scratch_shapes", ())] == \
+            [(sc.shape, sc.dtype) for sc in spec.scratch]
 
 
 def test_m007_details_decompose_the_total():

@@ -24,7 +24,7 @@ import pytest
 
 import mxtpu as mx
 from mxtpu import nd
-from mxtpu.analysis import check_compiles, compile_budget
+from mxtpu.analysis import check_compiles, compile_budget, get_ledger
 from mxtpu.analysis.memory_estimate import (kv_cache_residency,
                                             paged_kv_cache_residency)
 from mxtpu.contrib.quantization import (QuantizedDense, pack_int4,
@@ -366,6 +366,9 @@ def test_int8_slot_engine_holds_compile_budget():
     float workload's program count (2 prefill buckets + 1 pooled step)
     — quantization changes the programs' BODIES, never their FAMILY
     structure; C001 stays clean."""
+    # the C001 verdict is about THIS workload's compiles, not what other
+    # files' tests left in the process-wide ledger of this worker
+    get_ledger().reset()
     mx.random.seed(77)
     tiny = TransformerLM(50, units=32, hidden_size=64, num_layers=1,
                          num_heads=2, num_kv_heads=2)
@@ -391,6 +394,7 @@ def test_int8_slot_engine_holds_compile_budget():
 def test_int8_paged_engine_holds_compile_budget():
     """The paged twin: chunked shared-prefix int8 workload stays at 2
     chunk-bucket prefills + 1 paged step, C001-clean."""
+    get_ledger().reset()
     mx.random.seed(77)
     tiny = TransformerLM(50, units=32, hidden_size=64, num_layers=1,
                          num_heads=2, num_kv_heads=2)
