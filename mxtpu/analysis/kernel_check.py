@@ -92,6 +92,9 @@ _PASS = "kernel_check"
 
 #: default per-grid-step budget: the ~16 MiB VMEM per TensorCore
 DEFAULT_VMEM_BUDGET = 16 * (1 << 20)
+#: what a call may ask for at most (``KernelSpec.vmem_limit``): a v5e
+#: TensorCore's physical VMEM
+PHYSICAL_VMEM = 128 * (1 << 20)
 
 
 class BlockOperand:
@@ -184,18 +187,24 @@ class KernelSpec:
     per-shard geometry was derived from (kv heads for the paged
     kernels).  The spec's grid/operands then describe ONE shard, so
     K003 prices the per-device VMEM; a shard count that does not divide
-    the global extent is a K009 ERROR."""
+    the global extent is a K009 ERROR.
+
+    ``vmem_limit`` is what the call itself asks of the compiler
+    (``CompilerParams(vmem_limit_bytes=...)``): K003 then holds the
+    estimate to that, under the chip's physical VMEM."""
 
     __slots__ = ("name", "grid", "operands", "scratch", "prefetch",
-                 "interpret", "mesh_axis")
+                 "interpret", "mesh_axis", "vmem_limit")
 
     def __init__(self, name: str, grid: Sequence[int],
                  operands: Sequence[BlockOperand],
                  scratch: Sequence[ScratchOperand] = (),
                  prefetch: Sequence[ScalarPrefetch] = (),
                  interpret: bool = False,
-                 mesh_axis: Optional[Tuple] = None):
+                 mesh_axis: Optional[Tuple] = None,
+                 vmem_limit: Optional[int] = None):
         self.name = name
+        self.vmem_limit = None if vmem_limit is None else int(vmem_limit)
         self.grid = tuple(int(g) for g in grid)
         self.operands = list(operands)
         self.scratch = list(scratch)
@@ -502,7 +511,7 @@ def check_kernels(specs: Optional[Sequence[KernelSpec]] = None,
     """
     if specs is None:
         specs = default_kernel_specs()
-    budget = parse_bytes(vmem_budget)
+    base_budget = parse_bytes(vmem_budget)
     report = Report()
     for spec in specs:
         deferred: List[Tuple[str, str, str]] = []
@@ -535,6 +544,8 @@ def check_kernels(specs: Optional[Sequence[KernelSpec]] = None,
                     "%s.%s" % (spec.name, opname), msg))
 
         # K003 / M007 — VMEM budget + pricing
+        budget = base_budget if spec.vmem_limit is None else min(
+            max(base_budget, spec.vmem_limit), PHYSICAL_VMEM)
         est = kernel_vmem_estimate(spec, buffering=buffering)
         report.add(Diagnostic(
             _PASS, "M007", Severity.INFO, spec.name,
@@ -603,7 +614,12 @@ def default_kernel_specs() -> List[KernelSpec]:
 
     - flash_attention fwd + the one backward kernel, fp32 training
       shape and the bf16 serving-prefill shape (T=2048, D=128, 128/128
-      blocks forward, the backward's 512 x 512 tiles);
+      blocks forward, the backward's 512 x 512 tiles), and latent
+      attention's (T=8192, keys 192, values 128, fp32: whole heads in
+      VMEM, asked for through ``vmem_limit``);
+    - KDA's kernels (the chunks' operands forward and backward, the
+      state pass forward writing states and backward) at 8,192 positions
+      and at a toy length, heads and chunks of 128 x 64;
     - conv_bwd at the ResNet small-channel stage its VMEM gate admits
       (56x56x64, fp32);
     - paged_attention decode (W=1) and W-wide speculative verify (W=8),
@@ -623,6 +639,7 @@ def default_kernel_specs() -> List[KernelSpec]:
     import importlib
 
     from ..ops.pallas import conv_bwd, paged_attention
+    kda = importlib.import_module("mxtpu.ops.pallas.kda")
 
     # the package re-exports the flash_attention FUNCTION under the
     # module's name; import the module itself for its spec builder
@@ -635,6 +652,13 @@ def default_kernel_specs() -> List[KernelSpec]:
     for dtype in ("float32", "bfloat16"):
         specs.extend(flash_attention.kernel_specs(
             B=4, H=8, T=2048, D=128, dtype=dtype))
+    # latent attention of the Kimi-Linear cell: keys 192, values 128,
+    # 8,192 positions; and KDA's kernels there (4 heads a call) and at
+    # the toy length of the CPU tests
+    specs.extend(flash_attention.kernel_specs(
+        B=1, H=32, T=8192, D=192, Dv=128, dtype="float32"))
+    for T in (8192, 96):
+        specs.extend(kda.kernel_specs(B=1, H=4, T=T, K=128))
     specs.append(conv_bwd.kernel_spec(N=8, H=56, W=56, Ci=64, Co=64,
                                       dtype="float32"))
     for cache_dtype, block_size in (("float32", 16), ("int8", 32)):

@@ -35,7 +35,38 @@ from ..ndarray import NDArray
 from .parameter import (Parameter, ParameterDict, DeferredInitializationError,
                         Constant)
 
-__all__ = ["Block", "HybridBlock", "SymbolBlock"]
+__all__ = ["Block", "HybridBlock", "SymbolBlock", "remat_scope"]
+
+
+# -- recomputation per unit --------------------------------------------------
+#
+# A block whose class (or instance) sets ``remat_unit = True`` — a decoder
+# or encoder layer, or half of one — is a unit of recomputation: called
+# inside ``remat_scope()`` (``SPMDTrainer(remat=True)`` opens it around
+# the traced forward) its forward is wrapped in ``jax.checkpoint`` over
+# the unit's own parameters and inputs, so the backward pass keeps the
+# unit's input and forms everything inside it again.  Units inside a unit
+# are not wrapped a second time.
+
+_REMAT = threading.local()
+
+
+class remat_scope:
+    """Inside it, every ``remat_unit`` block recomputes its forward in
+    the backward pass.  Only meaningful while a program is traced (under
+    ``jax.grad``); eager calls just run."""
+
+    def __init__(self, active=True):
+        self._active = bool(active)
+
+    def __enter__(self):
+        self._was = getattr(_REMAT, "active", False)
+        _REMAT.active = self._active
+        return self
+
+    def __exit__(self, *exc):
+        _REMAT.active = self._was
+        return False
 
 
 class _BlockScope:
@@ -326,13 +357,63 @@ class Block:
         for _, param in self.params.items():
             param.cast(dtype)
 
+    #: a unit of recomputation under ``remat_scope`` (see the top of
+    #: this file); layers of the model zoo set it
+    remat_unit = False
+
     def __call__(self, *args):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
-        out = self.forward(*args)
+        if self.remat_unit and getattr(_REMAT, "active", False):
+            out = self._forward_rematerialized(*args)
+        else:
+            out = self.forward(*args)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out
+
+    def _forward_rematerialized(self, *args):
+        """``forward`` under ``jax.checkpoint``, as a function of this
+        unit's parameters and its array arguments.  What the forward
+        rebinds of non-trained parameters (running statistics, counters)
+        leaves the checkpoint as an output and is rebound outside it."""
+        import jax
+
+        params = [p for _, p in sorted(self.collect_params().items())]
+        holders = [p.data() for p in params]
+        is_array = [isinstance(a, NDArray) for a in args]
+        structure = []
+
+        def fn(leaves, arrays):
+            saved = [h._data for h in holders]
+            for h, leaf in zip(holders, leaves):
+                h._data = leaf
+            arrays = iter(arrays)
+            try:
+                with remat_scope(False):
+                    out = self.forward(*[
+                        NDArray(next(arrays)) if nd else a
+                        for a, nd in zip(args, is_array)])
+                after = tuple(h._data for h in holders)
+            finally:
+                for h, data in zip(holders, saved):
+                    h._data = data
+            flat, tree = jax.tree_util.tree_flatten(
+                out, is_leaf=lambda x: isinstance(x, NDArray))
+            structure.append((tree, [isinstance(x, NDArray) for x in flat]))
+            return tuple(x._data if isinstance(x, NDArray) else x
+                         for x in flat), after
+
+        flat, after = jax.checkpoint(fn)(
+            tuple(h._data for h in holders),
+            tuple(a._data for a, nd in zip(args, is_array) if nd))
+        for p, h, new in zip(params, holders, after):
+            if p.grad_req == "null":
+                h._data = new
+        tree, was_array = structure[-1]
+        return jax.tree_util.tree_unflatten(
+            tree, [NDArray(x) if nd else x
+                   for x, nd in zip(flat, was_array)])
 
     def forward(self, *args):
         raise NotImplementedError

@@ -17,6 +17,8 @@ from .transformer import (MultiHeadAttention, TransformerEncoderLayer,
                           bert_sharding_rules)
 from . import moe
 from .moe import SwitchMoE, MoEDecoderLayer, moe_sharding_rules
+from . import kimi_linear
+from .kimi_linear import KimiLinearLM, kimi_linear_from_config
 from . import sampler
 from .sampler import (BeamSearchSampler, NGramDrafter, SequenceSampler,
                       beam_search)

@@ -138,6 +138,8 @@ class MoEDecoderLayer(HybridBlock):
     """LlamaDecoderLayer with the SwiGLU FFN swapped for SwitchMoE
     (pre-RMSNorm residual structure preserved)."""
 
+    remat_unit = True       # SPMDTrainer(remat=True): gluon/block.py
+
     def __init__(self, units, hidden_size, num_heads, num_kv_heads,
                  num_experts, capacity_factor=1.25, mesh=None,
                  return_aux=False, **kwargs):

@@ -736,6 +736,8 @@ class TransformerEncoderLayer(HybridBlock):
     """Pre-LN encoder block (BERT uses post-LN originally; pre-LN is the
     numerically stable modern default — `post_ln=True` restores parity)."""
 
+    remat_unit = True       # SPMDTrainer(remat=True): gluon/block.py
+
     def __init__(self, units, hidden_size, num_heads, dropout=0.1,
                  activation="gelu", post_ln=True, mesh=None, **kwargs):
         super().__init__(**kwargs)
@@ -841,6 +843,8 @@ def bert_base(**kwargs):
 
 class LlamaDecoderLayer(HybridBlock):
     """Pre-RMSNorm decoder block: GQA attention with rotary + SwiGLU FFN."""
+
+    remat_unit = True       # SPMDTrainer(remat=True): gluon/block.py
 
     def __init__(self, units, hidden_size, num_heads, num_kv_heads,
                  mesh=None, **kwargs):

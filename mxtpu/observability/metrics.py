@@ -36,6 +36,12 @@ source              pulls
                     ``lifecycle.pages_tracked``,
                     ``lifecycle.violations_ever`` — see
                     ``analysis/lifecycle_check.py``)
+``moe``             load of every live expert layer that holds a share
+                    of the experts (``moe.<layer>.held.<i>``,
+                    ``.elsewhere``, ``.held_sum.<i>``,
+                    ``.elsewhere_sum`` — models/kimi_linear.py
+                    ``expert_loads``; reads a few numbers a layer
+                    from the device)
 ==================  ====================================================
 
 Live objects (engines, gateways, supervisors, routers) register with
@@ -238,6 +244,18 @@ def _src_kernel_invocations() -> dict:
     return counters.counts()
 
 
+def _src_moe() -> dict:
+    """Load of every live expert layer that holds a share of the experts
+    (``moe.<layer>.held`` — pairs each held expert received in the
+    newest forward pass —, ``.elsewhere``, and their running sums
+    ``.held_sum`` / ``.elsewhere_sum``): kept on the device by the layer,
+    read here (models/kimi_linear.py ``expert_loads``)."""
+    from ..models.kimi_linear import expert_loads
+    return {layer: {name: dict(enumerate(v)) if isinstance(v, list) else v
+                    for name, v in load.items()}
+            for layer, load in expert_loads().items()}
+
+
 def default_registry() -> MetricsRegistry:
     """A fresh registry pre-loaded with the built-in process-wide
     sources (module docstring table)."""
@@ -250,6 +268,7 @@ def default_registry() -> MetricsRegistry:
     reg.register_source("flight", _src_flight)
     reg.register_source("kernel_invocations", _src_kernel_invocations)
     reg.register_source("lifecycle", _src_lifecycle)
+    reg.register_source("moe", _src_moe)
     return reg
 
 

@@ -1,8 +1,17 @@
 """Mixture-of-Experts ops (SURVEY §2.3 row 59 — EP/MoE, absent in the
-reference; built TPU-first: static-capacity routing with one-hot
-dispatch/combine einsums, the GShard/Switch-Transformer formulation that
-GSPMD turns into expert all-to-alls when the expert dimension is sharded
-over the mesh "ep" axis).
+reference).
+
+Two expert layers live here.  ``switch_moe`` is the static-capacity one:
+softmax router, one-hot dispatch/combine einsums of shape (S, E, C)
+(the GShard/Switch-Transformer formulation that GSPMD turns into expert
+all-to-alls when the expert dimension is sharded over the mesh "ep"
+axis), tokens over capacity dropped.  It is what the serving layers use
+today (``SwitchMoE.decode_forward`` / ``prefill_forward``,
+``MoEDecoderLayer`` in the engines).  For training use
+``moe_expert_share``: no capacity, no dropped token, no (S, E, C) tensor
+— a sigmoid top-k router over all experts, the pairs that fall to the
+experts this share holds sorted by expert and run through grouped
+products.
 
 Routing: top-1 (Switch, default) or top-k (GShard top-2) — the discrete
 choice gets gradients through the selected gate probabilities
@@ -13,6 +22,7 @@ training at scale.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -124,3 +134,124 @@ def switch_moe(x, router_w, w1, w2, capacity_factor=1.25,
         z = jax.scipy.special.logsumexp(logits, axis=-1)
         aux = aux + z_loss_weight * jnp.mean(jnp.square(z))
     return y.reshape(orig_shape).astype(x.dtype), aux.astype(jnp.float32)
+
+
+#: sorted (token, expert) pairs that go through the grouped products at a
+#: time.  The held pairs are taken in as many tiles as they fill, so time
+#: and memory follow the load this share really has; a share that every
+#: token chose for every slot just takes more tiles.
+PAIRS_PER_TILE = 4096
+
+
+def _tile(xf, pair_weight, w_gate, w_up, w_down, order, bounds, i, k, rows,
+          hi):
+    """What tile ``i`` of the sorted held pairs adds to the result:
+    (S, d).  ``order`` lists pair ids (token * k + slot) sorted by held
+    expert, held pairs first; ``bounds`` (held + 1,) are the experts'
+    boundaries in it."""
+    S = xf.shape[0]
+    start = i * rows
+    pair = jax.lax.dynamic_slice_in_dim(order, start, rows)
+    token = pair // k
+    here = ((start + jnp.arange(rows)) < bounds[-1])[:, None]
+    sizes = jnp.diff(jnp.clip(bounds - start, 0, rows))
+    grouped = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                                precision=hi)
+    x = jnp.where(here, xf[token], 0)                        # (rows, d)
+    h = jax.nn.silu(grouped(x, w_gate)) * grouped(x, w_up)
+    out = jnp.where(here, grouped(h, w_down), 0)
+    out = out * pair_weight[pair][:, None].astype(out.dtype)
+    return jax.ops.segment_sum(out, token, num_segments=S)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _held_experts(xf, pair_weight, w_gate, w_up, w_down, order, bounds, k,
+                  rows, hi):
+    """Sum over the held pairs of weight * expert(x): a loop over as many
+    tiles of the sorted pairs as are held (a trip count known only on
+    the device), forward and backward, each tile formed again in the
+    backward pass."""
+    tiles = -(-bounds[-1] // rows)
+
+    def add(i, y):
+        return y + _tile(xf, pair_weight, w_gate, w_up, w_down, order,
+                         bounds, i, k, rows, hi)
+
+    return jax.lax.fori_loop(0, tiles, add, jnp.zeros_like(xf))
+
+
+def _held_experts_fwd(xf, pair_weight, w_gate, w_up, w_down, order, bounds,
+                      k, rows, hi):
+    y = _held_experts(xf, pair_weight, w_gate, w_up, w_down, order, bounds,
+                      k, rows, hi)
+    return y, (xf, pair_weight, w_gate, w_up, w_down, order, bounds)
+
+
+def _held_experts_bwd(k, rows, hi, kept, dy):
+    *inputs, order, bounds = kept
+    tiles = -(-bounds[-1] // rows)
+
+    def add(i, grads):
+        _, back = jax.vjp(lambda *a: _tile(*a, order, bounds, i, k, rows,
+                                           hi), *inputs)
+        return tuple(g + d for g, d in zip(grads, back(dy)))
+
+    grads = jax.lax.fori_loop(0, tiles, add,
+                              tuple(jnp.zeros_like(a) for a in inputs))
+    return grads + (None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+@register_op("moe_expert_share", num_outputs=2)
+def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
+                     held_first=0, top_k=8, renormalize=True,
+                     scale=1.0):
+    """The routed part of an expert layer that holds a share of the
+    experts (expert parallelism: one rank's part of the result).
+
+    x (.., d); router_w (E, d) over ALL ``E`` experts; select_bias (E,),
+    added to the scores for the choice only (no gradient reaches it);
+    w_gate, w_up (held, d, f) and w_down (held, f, d): the gated experts
+    ``held_first .. held_first + held - 1`` this share holds.
+
+    Every token chooses its ``top_k`` experts among all ``E`` by
+    ``sigmoid(x router_w^T) + select_bias`` and weighs them by the
+    sigmoid scores themselves, renormalised over all the chosen (held
+    here or not) and scaled by ``scale``.  The (token, expert) pairs that
+    fall to held experts are sorted by expert and go, ``PAIRS_PER_TILE``
+    at a time, through three grouped products (``lax.ragged_dot``),
+    ``w_down(silu(w_gate x) * w_up x)``; there is no capacity and no
+    pair is dropped.  Pairs that fall to experts held elsewhere add
+    nothing here: on one chip there is no exchange.
+
+    Returns (y, load): y like x, and load (held + 1,) int32 — the pairs
+    each held expert received, then the pairs that fell elsewhere.
+    """
+    shape, d = x.shape, x.shape[-1]
+    held, k = w_gate.shape[0], int(top_k)
+    hi = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    xf = x.reshape(-1, d)
+    scores = jax.nn.sigmoid(jnp.dot(
+        xf.astype(jnp.float32), router_w.astype(jnp.float32).T,
+        precision=jax.lax.Precision.HIGHEST))                 # (S, E)
+    _, chosen = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
+    weight = jnp.take_along_axis(scores, chosen, axis=-1)     # (S, k)
+    if renormalize:
+        weight = weight / jnp.sum(weight, -1, keepdims=True)
+    weight = weight * scale
+
+    local = (chosen - held_first).reshape(-1)                 # (S * k,)
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)          # elsewhere: sorted last
+    load = jnp.sum(jax.nn.one_hot(group, held + 1, dtype=jnp.int32), 0)
+    rows = min(PAIRS_PER_TILE, group.size)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, (-order.size) % rows))
+    bounds = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                              jnp.cumsum(load[:held])])
+    y = _held_experts(xf, jnp.where(mine, weight.reshape(-1), 0), w_gate,
+                      w_up, w_down, order, bounds, k, rows, hi)
+    return y.reshape(shape).astype(x.dtype), load

@@ -6,3 +6,4 @@ from . import counters
 from .flash_attention import flash_attention
 from .paged_attention import paged_decode_attention
 from .prefill_attention import paged_prefill_attention
+from .kda import kda, kda_mixer

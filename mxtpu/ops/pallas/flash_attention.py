@@ -39,15 +39,17 @@ KERNEL_NAME = "flash_attention"
 
 
 def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
-                 backward=True, interpret=False):
+                 backward=True, interpret=False, Dv=None):
     """KernelSpec descriptors (mxtpu.analysis.kernel_check) for the
     pallas_calls one flash_attention forward/backward issues at this
     workload geometry — same padding and block construction as
     _flash_fwd/_flash_bwd, so the static pass verdicts exactly the
-    calls that would run."""
+    calls that would run.  ``Dv`` is the values' width where it is not
+    the keys' (``D``)."""
     from ...analysis.kernel_check import (BlockOperand, KernelSpec,
                                           ScratchOperand)
 
+    Dv = D if Dv is None else Dv
     qb = min(q_block, T)
     kb = min(kv_block, T)
     Tq = math.ceil(T / qb) * qb
@@ -55,27 +57,31 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
     BH = B * H
 
     def blk(name, kind, shape, array, dt, imap):
-        # D (head_dim) and the q/kv block tiles are chosen parameters,
-        # strict on both trailing dims; the forward's lse column carries
-        # a trailing unit dim (its array's full extent), so only its
-        # q_block-sized sublane dim is a choice
-        strict = (-2,) if shape[-1] == 1 else (-1, -2)
+        # the q/kv block tiles are chosen parameters, strict on the
+        # sublane dim.  The head width is the arrays' whole last axis:
+        # VMEM pads it to the lanes, and the chip's compiler takes 64
+        # (BERT's cell) and 192 (latent attention) as it takes 128
+        # (tests/test_chip_compile.py); the forward's lse column carries
+        # a trailing unit dim likewise
         return BlockOperand(name, kind, shape, array, dt, imap,
-                            strict_dims=strict)
+                            strict_dims=(-2,))
 
     q_im = lambda b, i: (b, i, 0)      # noqa: E731 — mirrors _flash_fwd
     full_im = lambda b, i: (b, 0, 0)   # noqa: E731
+    tag = "[%s,T=%d,D=%d]" % (dtype, T, D) if Dv == D else \
+        "[%s,T=%d,D=%d,Dv=%d]" % (dtype, T, D, Dv)
     specs = [KernelSpec(
-        "flash_attention.fwd[%s,T=%d,D=%d]" % (dtype, T, D),
+        "flash_attention.fwd" + tag,
         grid=(BH, Tq // qb),
         operands=[
             blk("q", "in", (1, qb, D), (BH, Tq, D), dtype, q_im),
             blk("k", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
-            blk("v", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
-            blk("o", "out", (1, qb, D), (BH, Tq, D), dtype, q_im),
+            blk("v", "in", (1, Tk, Dv), (BH, Tk, Dv), dtype, full_im),
+            blk("o", "out", (1, qb, Dv), (BH, Tq, Dv), dtype, q_im),
             blk("lse", "out", (1, qb, 1), (BH, Tq, 1), "float32", q_im),
         ],
-        interpret=interpret)]
+        interpret=interpret,
+        vmem_limit=_vmem_limit(_fwd_vmem(qb, Tk, D, Dv, dtype)))]
     if not backward:
         return specs
     qb, kb = _bwd_tile(qb, Tq), _bwd_tile(kb, Tk)
@@ -85,24 +91,66 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
     # innermost, so K006 holds — beside its float32 accumulator; lse and
     # delta lie along lanes, a row a Q block, whole for the head
     specs.append(KernelSpec(
-        "flash_attention.bwd[%s,T=%d,D=%d]" % (dtype, T, D),
+        "flash_attention.bwd" + tag,
         grid=(BH, Tk // kb),
         operands=[
             blk("q", "in", (1, Tq, D), (BH, Tq, D), dtype, full_im),
             blk("k", "in", (1, kb, D), (BH, Tk, D), dtype, kv_im),
-            blk("v", "in", (1, kb, D), (BH, Tk, D), dtype, kv_im),
-            blk("do", "in", (1, Tq, D), (BH, Tq, D), dtype, full_im),
+            blk("v", "in", (1, kb, Dv), (BH, Tk, Dv), dtype, kv_im),
+            blk("do", "in", (1, Tq, Dv), (BH, Tq, Dv), dtype, full_im),
             BlockOperand("lse", "in", (1, nq, qb), (BH, nq, qb), "float32",
                          full_im),
             BlockOperand("delta", "in", (1, nq, qb), (BH, nq, qb),
                          "float32", full_im),
             blk("dq", "out", (1, Tq, D), (BH, Tq, D), dtype, full_im),
             blk("dk", "out", (1, kb, D), (BH, Tk, D), dtype, kv_im),
-            blk("dv", "out", (1, kb, D), (BH, Tk, D), dtype, kv_im),
+            blk("dv", "out", (1, kb, Dv), (BH, Tk, Dv), dtype, kv_im),
         ],
         scratch=[ScratchOperand("dq_acc", (Tq, D), "float32")],
-        interpret=interpret))
+        interpret=interpret,
+        vmem_limit=_vmem_limit(_bwd_vmem(Tq, kb, D, Dv, dtype))))
     return specs
+
+
+# The kernels keep one head's K and V (forward) or Q, dO and dQ
+# (backward) whole in VMEM.  Up to the compiler's own limit per kernel
+# nothing is asked for; a longer head (8,192 x 192 float32 is 8 MiB an
+# operand, as VMEM pads its lanes to 128) asks for what its blocks take,
+# twice over for the pipeline's second buffer, and some room.
+
+_SCOPED_VMEM = 16 * (1 << 20)
+
+
+def _padded(rows, cols, dtype):
+    return rows * (-(-cols // 128) * 128) * jnp.dtype(dtype).itemsize
+
+
+def _fwd_vmem(q_block, Tk, D, Dv, dtype):
+    return 2 * (_padded(Tk, D, dtype) + _padded(Tk, Dv, dtype)
+                + _padded(q_block, D, dtype) + _padded(q_block, Dv, dtype)
+                + _padded(q_block, 1, "float32"))
+
+
+def _bwd_vmem(Tq, kv_block, D, Dv, dtype):
+    return (2 * (2 * _padded(Tq, D, dtype) + _padded(Tq, Dv, dtype)
+                 + 2 * _padded(kv_block, D, dtype)
+                 + 2 * _padded(kv_block, Dv, dtype)
+                 + 2 * _padded(Tq // 8, 128, "float32"))
+            + _padded(Tq, D, "float32"))
+
+
+def _vmem_limit(needed):
+    """None while the blocks fit the compiler's own limit with half of it
+    to spare for the tiles the kernel forms; else what to ask for."""
+    if needed <= _SCOPED_VMEM // 2:
+        return None
+    return int(needed * 1.25) + _SCOPED_VMEM
+
+
+def _compiler_params(needed):
+    limit = _vmem_limit(needed)
+    return None if limit is None else pltpu.CompilerParams(
+        vmem_limit_bytes=limit)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
@@ -149,7 +197,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 
     m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
+    acc0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, nkv, body, (m0, l0, acc0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     # logsumexp residual for the Pallas backward (fp32; the softmax is
@@ -169,6 +217,7 @@ def _pad_to(x, axis, multiple):
 
 def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     qp, t_orig = _pad_to(q, 2, q_block)
     kp, _ = _pad_to(k, 2, kv_block)
     vp, _ = _pad_to(v, 2, kv_block)
@@ -176,7 +225,7 @@ def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
     Tk = kp.shape[2]
     qp = qp.reshape(B * H, Tq, D)
     kp = kp.reshape(B * H, Tk, D)
-    vp = vp.reshape(B * H, Tk, D)
+    vp = vp.reshape(B * H, Tk, Dv)
 
     grid = (B * H, Tq // q_block)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -185,20 +234,22 @@ def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
                                hi_prec=q.dtype == jnp.float32)
     out, lse = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B * H, Tq, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B * H, Tq, 1), jnp.float32)],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Tk, Dv), lambda b, i: (b, 0, 0)),
         ],
-        out_specs=[pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
+        out_specs=[pl.BlockSpec((1, q_block, Dv), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, q_block, 1), lambda b, i: (b, i, 0))],
+        compiler_params=_compiler_params(
+            _fwd_vmem(q_block, Tk, D, Dv, q.dtype)),
         interpret=interpret,
         name="flash_attention_fwd",
     )(qp, kp, vp)
-    return out.reshape(B, H, Tq, D)[:, :, :t_orig], lse
+    return out.reshape(B, H, Tq, Dv)[:, :, :t_orig], lse
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -254,8 +305,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_acc[rows, :] += dot(dst, k, at_b)
         return dk, dv
 
-    z = jnp.zeros((bkv, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(i0, nq_total, body, (z, z))
+    dk, dv = jax.lax.fori_loop(
+        i0, nq_total, body, (jnp.zeros((bkv, d), jnp.float32),
+                             jnp.zeros(v.shape, jnp.float32)))
     dk_ref[0] = (scale * dk).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -281,6 +333,7 @@ def _bwd_tile(block, padded):
 def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
                interpret):
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     qp, t_orig = _pad_to(q, 2, q_block)
     kp, _ = _pad_to(k, 2, kv_block)
     vp, _ = _pad_to(v, 2, kv_block)
@@ -293,9 +346,9 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
     BH = B * H
     qp = qp.reshape(BH, Tq, D)
     kp = kp.reshape(BH, Tk, D)
-    vp = vp.reshape(BH, Tk, D)
-    gp = gp.reshape(BH, Tq, D)
-    op = op.reshape(BH, Tq, D)
+    vp = vp.reshape(BH, Tk, Dv)
+    gp = gp.reshape(BH, Tq, Dv)
+    op = op.reshape(BH, Tq, Dv)
     # the per-row vectors lie along lanes, one row a Q block.  lse comes
     # padded from the forward already, as a (BH, Tq, 1) column
     nq = Tq // q_block
@@ -313,29 +366,31 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
         kernel,
         out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Tk, D), v.dtype)],
+                   jax.ShapeDtypeStruct((BH, Tk, Dv), v.dtype)],
         grid=(BH, Tk // kv_block),
         in_specs=[
             pl.BlockSpec((1, Tq, D), head),
             pl.BlockSpec((1, kv_block, D), kv),
-            pl.BlockSpec((1, kv_block, D), kv),
-            pl.BlockSpec((1, Tq, D), head),
+            pl.BlockSpec((1, kv_block, Dv), kv),
+            pl.BlockSpec((1, Tq, Dv), head),
             pl.BlockSpec((1, nq, q_block), head),
             pl.BlockSpec((1, nq, q_block), head),
         ],
         out_specs=[
             pl.BlockSpec((1, Tq, D), head),
             pl.BlockSpec((1, kv_block, D), kv),
-            pl.BlockSpec((1, kv_block, D), kv),
+            pl.BlockSpec((1, kv_block, Dv), kv),
         ],
         scratch_shapes=[pltpu.VMEM((Tq, D), jnp.float32)],
+        compiler_params=_compiler_params(
+            _bwd_vmem(Tq, kv_block, D, Dv, q.dtype)),
         interpret=interpret,
         name="flash_attention_bwd",
     )(qp, kp, vp, gp, lse, delta)
 
     dq = dq.reshape(B, H, Tq, D)[:, :, :t_orig]
     dk = dk.reshape(B, H, Tk, D)[:, :, :t_orig]
-    dv = dv.reshape(B, H, Tk, D)[:, :, :t_orig]
+    dv = dv.reshape(B, H, Tk, Dv)[:, :, :t_orig]
     return dq, dk, dv
 
 
@@ -379,7 +434,8 @@ def _make_flash(scale, causal, q_block, kv_block, interpret):
 
 def flash_attention(q, k, v, causal=False, scale=None, q_block=128,
                     kv_block=128):
-    """Streaming-softmax attention over (B, H, T, D).
+    """Streaming-softmax attention over (B, H, T, D); ``v`` may have a
+    width of its own, (B, H, T, Dv), which is then the output's.
 
     Pallas kernel on TPU; interpret-mode on CPU (slow — tests only).
     Falls back to the dense XLA path when shapes are too small to tile.
@@ -388,7 +444,7 @@ def flash_attention(q, k, v, causal=False, scale=None, q_block=128,
     """
     B, H, T, D = q.shape
     scale = float(scale if scale is not None else 1.0 / math.sqrt(D))
-    if T < 16 or D % 8 != 0:
+    if T < 16 or D % 8 != 0 or v.shape[-1] % 8 != 0:
         return _dense_attention(q, k, v, scale, causal)
     q_block = min(q_block, T)
     kv_block = min(kv_block, T)

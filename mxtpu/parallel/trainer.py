@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import autograd, ndarray as nd, optimizer as opt_mod
 from .. import random as _random
+from ..gluon.block import remat_scope
 from ..ndarray import NDArray
 from ..observability.trace import get_tracer as _tracer
 from ..ops.pallas.partition import head_sharding_scope
@@ -66,8 +67,11 @@ class SPMDTrainer:
     batch_spec / label_spec : PartitionSpec for the data arrays (default
         shard batch dim over "dp"; add "sp" on the sequence dim for
         sequence parallelism).
-    remat : rematerialize the forward in backward (jax.checkpoint) to trade
-        FLOPs for HBM.
+    remat : recompute each unit of the model in the backward pass
+        (``jax.checkpoint`` around every block that sets ``remat_unit``:
+        the model zoo's encoder and decoder layers, halves of a layer in
+        ``KimiLinearLM``), keeping the unit's input and nothing inside
+        it: FLOPs for HBM.  A model without such a block is unchanged.
     donate : donate old param/state buffers (in-place update on device).
     clip_gradient_norm : optional global-norm gradient clip fused into
         the compiled step (parity: gluon.utils.clip_global_norm); the
@@ -187,6 +191,7 @@ class SPMDTrainer:
         optimizer = self._optimizer
         clip_norm = self._clip_norm
         mesh = self._mesh
+        remat = self._remat
         batch_axes = self._batch_spec[0] if len(self._batch_spec) else ()
         batch_axes = ((batch_axes,) if isinstance(batch_axes, str)
                       else tuple(batch_axes or ()))
@@ -210,7 +215,8 @@ class SPMDTrainer:
                 # batch axes and the heads axes (GSPMD cannot partition
                 # a Mosaic kernel — ops/pallas/partition.py)
                 with autograd.pause(train_mode=True), \
-                        head_sharding_scope(mesh, heads_axes, batch_axes):
+                        head_sharding_scope(mesh, heads_axes, batch_axes), \
+                        remat_scope(remat):
                     out = block(NDArray(batch))
                     # multi-output blocks: by default the loss sees the
                     # FIRST output; a loss with accepts_full_output=True
@@ -227,9 +233,6 @@ class SPMDTrainer:
                 for holder, data in saved:
                     holder._data = data
             return loss_scalar, new_aux
-
-        if self._remat:
-            forward = jax.checkpoint(forward, static_argnums=())
 
         guard = self._guard
         dyn_scale = self._dyn_scale
@@ -450,13 +453,19 @@ class SPMDTrainer:
 
     # -- public API ------------------------------------------------------
     def _ensure_staged(self, data):
-        """Resolve deferred shapes with one imperative forward and stage
-        params/optimizer state onto the mesh (idempotent)."""
+        """Stage params/optimizer state onto the mesh (idempotent).
+        Where a parameter's shape is still deferred, one imperative
+        forward resolves it first; a model whose shapes are all known is
+        not run eagerly (op by op, every op a program of its own: 133 s
+        of a cold start at 8,192 tokens through five Kimi-Linear layers,
+        PERF.md PR 29)."""
         if not self._params_sharded:
             with _tracer().span("trainer.stage"):
-                with autograd.pause(train_mode=False):
-                    self._block(data if isinstance(data, NDArray)
-                                else nd.array(data))
+                if any(p._deferred_init for p in
+                       self._block.collect_params().values()):
+                    with autograd.pause(train_mode=False):
+                        self._block(data if isinstance(data, NDArray)
+                                    else nd.array(data))
                 self._stage_params()
 
     def step(self, data, label):

@@ -7,8 +7,10 @@ the CPU cannot: before PR 22 every kernel here passed its parity suite
 and four of them were refused by Mosaic.
 
 Geometries are the real ones — BERT-base training (batch 32, 12 heads
-of 64, seq 128 in bfloat16 and the benchmark cell's seq 512 in float32)
-and Llama-3-8B serving (32 Q / 8 KV heads of 128).
+of 64, seq 128 in bfloat16 and the benchmark cell's seq 512 in float32),
+Kimi-Linear's cell (32 heads at 8,192 positions: latent attention with
+keys of 192 and values of 128, KDA's state kernels at 128) and
+Llama-3-8B serving (32 Q / 8 KV heads of 128).
 Nothing runs, so results are covered by the interpret-mode suites
 (test_flash_attention / test_paged_attention_pallas /
 test_prefill_attention_pallas / test_sharded_paged_kernel).
@@ -31,6 +33,7 @@ from mxtpu.ops.pallas.partition import head_sharding_scope
 from mxtpu.parallel.mesh import make_mesh
 
 fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+kda = importlib.import_module("mxtpu.ops.pallas.kda")
 pa = importlib.import_module("mxtpu.ops.pallas.paged_attention")
 pf = importlib.import_module("mxtpu.ops.pallas.prefill_attention")
 
@@ -105,6 +108,41 @@ def test_flash_attention(on_chip, geometry, dtype, causal, backward):
     assert ("flash_attention_bwd" in text) == backward
     assert "flash_attention_dq" not in text
     assert "flash_attention_dkv" not in text
+
+
+def test_flash_attention_with_narrower_values_at_8k(on_chip):
+    """Latent attention of the Kimi-Linear cell: one row of 32 heads,
+    8,192 positions, keys of 192 and values of 128, float32, causal.
+    The heads' K and V (forward) and Q, dO and dQ (backward) stay whole
+    in VMEM, which the calls ask the compiler for."""
+    q = _shape((1, 32, 8192, 192), F32, on_chip)
+    v = _shape((1, 32, 8192, 128), F32, on_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile().as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+
+
+@pytest.mark.parametrize("T", [8192, 96], ids=["cell", "toy"])
+def test_kda_kernels(on_chip, T, heads=4, K=128):
+    """KDA's four kernels, forward and backward, at one call of the cell
+    (4 heads at a time x 128 chunks of 64 x 128): the chunks' operands
+    (the backward is the forward's ``jax.vjp`` inside a kernel) and the
+    pass that carries the state."""
+    rows = _shape((1, T, heads, K), F32, on_chip)
+    beta = _shape((1, T, heads), F32, on_chip)
+    text = jax.jit(jax.grad(
+        lambda *a: kda._recurrence(*a, chunk=kda.CHUNK).sum(),
+        argnums=(0, 1, 2, 3, 4))).lower(
+        rows, rows, rows, rows, beta).compile().as_text()
+    for name in (kda.CHUNK_FWD_NAME, kda.CHUNK_BWD_NAME, kda.FWD_NAME,
+                 kda.FWD_STATES_NAME, kda.BWD_NAME):
+        assert name in text
+    for cached in (kda._make_state_pass, kda._make_chunk_operands):
+        cached.cache_clear()
 
 
 def _decode_shapes(cache_dtype, W, tree, place):

@@ -243,3 +243,53 @@ def test_flash_shard_mapped_under_a_sharding_scope(qkv, dp, tp):
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-4)
+
+
+# ---- values narrower than keys (latent attention: keys 192, values 128)
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("T,D,Dv,causal,blocks", [
+    (256, 192, 128, True, {}),                          # the new cell's heads
+    (150, 192, 128, True, dict(q_block=64, kv_block=128)),
+    (200, 48, 16, False, {}),
+    (256, 64, 64, False, {}),                           # BERT's: one width
+], ids=["causal_D192_Dv128_T256", "causal_D192_Dv128_T150_q64_k128",
+        "D48_Dv16_T200", "D64_Dv64_T256"])
+def test_flash_with_a_value_width_of_its_own(direction, T, D, Dv, causal,
+                                             blocks):
+    rng = np.random.RandomState(T + D)
+    q, k = (jnp.asarray(rng.randn(1, 2, T, D).astype("float32"))
+            for _ in range(2))
+    v, ct = (jnp.asarray(rng.randn(1, 2, T, Dv).astype("float32"))
+             for _ in range(2))
+    scale = 1.0 / np.sqrt(D)
+    if direction == "forward":
+        out = flash_attention(q, k, v, causal=causal, **blocks)
+        assert out.shape == (1, 2, T, Dv)
+        np.testing.assert_allclose(
+            np.asarray(out),
+            np.asarray(_dense_attention(q, k, v, scale, causal)),
+            rtol=1e-4, atol=1e-5)
+        return
+    _, flash = jax.vjp(lambda a, b, c: flash_attention(
+        a, b, c, causal=causal, **blocks), q, k, v)
+    _, dense = jax.vjp(lambda a, b, c: _dense_attention(
+        a, b, c, scale, causal), q, k, v)
+    for g, w, name in zip(flash(ct), dense(ct), ("dq", "dk", "dv")):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+def test_a_long_head_asks_for_its_vmem_and_a_short_one_for_nothing():
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    # BERT's call: the compiler's own limit, as before
+    assert fa._compiler_params(fa._fwd_vmem(128, 512, 64, 64,
+                                            "float32")) is None
+    assert fa._compiler_params(fa._bwd_vmem(512, 512, 64, 64,
+                                            "float32")) is None
+    # 8,192 keys of 192: K and V whole in VMEM are 12 MiB, twice buffered
+    asked = fa._compiler_params(fa._fwd_vmem(128, 8192, 192, 128, "float32"))
+    assert 24 * 2 ** 20 < asked.vmem_limit_bytes < 128 * 2 ** 20
+    back = fa._compiler_params(fa._bwd_vmem(8192, 512, 192, 128, "float32"))
+    assert asked.vmem_limit_bytes < back.vmem_limit_bytes < 128 * 2 ** 20

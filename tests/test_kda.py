@@ -1,0 +1,169 @@
+"""Kimi Delta Attention: the chunked op (its four Pallas kernels in
+interpret mode) against the recurrence it stands for, computed here one
+position at a time."""
+
+import importlib
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import mxtpu as mx
+from mxtpu.analysis import check_kernels
+
+kda = importlib.import_module("mxtpu.ops.pallas.kda")
+HI = jax.lax.Precision.HIGHEST
+
+
+def recurrence(q, k, v, g, beta):
+    """S_t = (I - b k k^T) Diag(exp g) S_{t-1} + b k v^T; o_t = S_t^T q_t."""
+    B, T, H, K = q.shape
+
+    def step(S, xs):
+        q_t, k_t, v_t, g_t, b_t = xs
+        S = jnp.exp(g_t)[..., None] * S
+        kS = jnp.einsum("bhk,bhkv->bhv", k_t, S, precision=HI)
+        S = S + (b_t[..., None] * k_t)[..., None] * (v_t - kS)[..., None, :]
+        return S, jnp.einsum("bhk,bhkv->bhv", q_t, S, precision=HI)
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    _, o = jax.lax.scan(step, jnp.zeros((B, H, K, v.shape[-1])), xs)
+    return jnp.moveaxis(o, 0, 1)
+
+
+def inputs(T, decay, B=2, H=5, K=16, V=8, seed=0):
+    """``decay`` = (least, most) of -g per position and channel."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (B, T, H, K))) * K ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, T, H, K)))
+    v = jax.random.normal(ks[2], (B, T, H, V))
+    g = -jnp.exp(jax.random.uniform(ks[3], (B, T, H, K),
+                                    minval=np.log(decay[0]),
+                                    maxval=np.log(decay[1])))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H)))
+    return (q, k, v, g, beta), jax.random.normal(ks[5], (B, T, H, V))
+
+
+CASES = [
+    # T, chunk, (least, most) decay a step
+    (128, 64, (1e-3, 1e-1)),    # two whole chunks, the model's decays
+    (100, 32, (1e-3, 3.0)),     # no multiple of the chunk
+    (64, 16, (1.0, 12.0)),      # decay near 0: a = exp(-12) a step
+    (48, 16, (1e-6, 1e-5)),     # decay near 1
+    (40, 64, (1e-2, 1.0)),      # shorter than one chunk
+]
+IDS = ["T128_c64", "T100_c32_ragged", "T64_c16_decay_near_0",
+       "T48_c16_decay_near_1", "T40_c64_short"]
+
+
+@pytest.mark.parametrize("T,chunk,decay", CASES, ids=IDS)
+def test_forward_matches_the_recurrence(T, chunk, decay):
+    args, _ = inputs(T, decay)
+    np.testing.assert_allclose(
+        np.asarray(kda.kda(*args, chunk=chunk)),
+        np.asarray(recurrence(*args)), rtol=2e-5, atol=2e-6)
+
+
+@pytest.fixture(scope="module", params=list(zip(CASES, IDS)),
+                ids=lambda p: p[1])
+def gradients(request):
+    (T, chunk, decay), _ = request.param
+    args, ct = inputs(T, decay, seed=1)
+    got = jax.grad(lambda *a: jnp.sum(kda.kda(*a, chunk=chunk) * ct),
+                   argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(lambda *a: jnp.sum(recurrence(*a) * ct),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+    return dict(zip(("q", "k", "v", "g", "beta"), zip(got, want)))
+
+
+@pytest.mark.parametrize("name", ["q", "k", "v", "g", "beta"])
+def test_every_inputs_gradient_matches_the_recurrence(gradients, name):
+    got, want = gradients[name]
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=2e-6 * max(scale, 1.0))
+
+
+def test_heads_go_through_in_groups_and_give_the_same(monkeypatch):
+    args, _ = inputs(64, (1e-3, 1e-1), H=6)
+    monkeypatch.setattr(kda, "HEADS_AT_A_TIME", 6)
+    whole = kda.kda(*args, chunk=32)
+    monkeypatch.setattr(kda, "HEADS_AT_A_TIME", 4)      # 6 heads: 2 x 3
+    assert kda.heads_per_call(6) == 3
+    np.testing.assert_allclose(np.asarray(kda.kda(*args, chunk=32)),
+                               np.asarray(whole), rtol=1e-5, atol=1e-7)
+
+
+def test_chunk_must_be_a_power_of_two():
+    args, _ = inputs(32, (1e-3, 1e-1))
+    with pytest.raises(ValueError, match="power of two"):
+        kda.kda(*args, chunk=48)
+
+
+def test_registered_op_and_counters():
+    from mxtpu.ops.pallas import counters
+
+    (q, k, v, g, beta), _ = inputs(32, (1e-3, 1e-1))
+    before = counters.count(kda.FWD_NAME)
+    out = mx.nd.kda(*(mx.nd.array(np.asarray(a)) for a in (q, k, v, g, beta)),
+                    chunk=16)
+    assert out.shape == v.shape
+    assert counters.count(kda.FWD_NAME) > before
+    from mxtpu.observability.metrics import default_registry
+    snap = default_registry().snapshot()
+    assert snap["kernel_invocations." + kda.FWD_NAME] >= 1
+
+
+def test_bfloat16_model_gets_bfloat16_back():
+    args, _ = inputs(32, (1e-3, 1e-1))
+    out = kda.kda(*(a.astype(jnp.bfloat16) for a in args), chunk=16)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, dtype="float32"),
+                               np.asarray(recurrence(*args)), atol=3e-2)
+
+
+@pytest.mark.parametrize("T", [8192, 96], ids=["cell", "toy"])
+def test_kernel_specs_pass_the_static_check(T):
+    specs = kda.kernel_specs(B=1, H=8, T=T, K=128)
+    assert [s.name.split("[")[0] for s in specs] == \
+        [kda.CHUNK_FWD_NAME, kda.FWD_STATES_NAME, kda.BWD_NAME,
+         kda.CHUNK_BWD_NAME]
+    report = check_kernels(specs)
+    assert not report.errors, [str(d) for d in report.errors]
+
+
+def test_kernel_specs_describe_the_real_calls(monkeypatch):
+    """kernel_specs == the four pallas_calls a backward pass issues after
+    it has run the forward again: the chunks' operands, the state pass
+    that writes the chunks' states, its backward, the operands'
+    backward."""
+    calls = []
+    real = kda.pl.pallas_call
+
+    def spy(kernel, **kw):
+        calls.append(kw)
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(kda.pl, "pallas_call", spy)
+    for cached in (kda._make_state_pass, kda._make_chunk_operands):
+        cached.cache_clear()
+    args, _ = inputs(96, (1e-3, 1e-1), B=1, H=2, K=16, V=16)
+    jax.grad(lambda *a: kda.kda(*a, chunk=32).sum())(*args)
+    for cached in (kda._make_state_pass, kda._make_chunk_operands):
+        cached.cache_clear()
+    forward = [kda.CHUNK_FWD_NAME, kda.FWD_NAME]
+    assert [c["name"] for c in calls] == forward + [
+        kda.CHUNK_FWD_NAME, kda.FWD_NAME, kda.FWD_STATES_NAME, kda.BWD_NAME,
+        kda.CHUNK_BWD_NAME]
+    specs = kda.kernel_specs(B=1, H=2, T=96, K=16, chunk=32, interpret=True)
+    issued = [calls[2]] + calls[4:]
+    for call, spec in zip(issued, specs):
+        assert tuple(call["grid"]) == spec.grid
+        for kind, key in (("in", "in_specs"), ("out", "out_specs")):
+            assert [tuple(b.block_shape) for b in call[key]] == \
+                [op.block_shape for op in spec.operands
+                 if op.kind == kind], (spec.name, kind)
+        assert [tuple(sc.shape) for sc in call.get("scratch_shapes", ())] \
+            == [sc.shape for sc in spec.scratch]

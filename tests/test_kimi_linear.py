@@ -1,0 +1,219 @@
+"""KimiLinearLM through SPMDTrainer.step against its plain reference
+(chipbench/references/kimi_linear.py: the recurrence step by step, dense
+attention, a loop over the held experts): loss, first gradient and three
+Adam steps, float32, with both kinds of layer, the leading dense layer
+and expert layers that hold a share.  And recomputation per unit."""
+
+import importlib
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import mxtpu as mx
+from mxtpu import gluon
+from mxtpu.analysis.memory_estimate import estimate_jit_memory
+from mxtpu.models import kimi_linear, transformer
+from mxtpu.parallel import SPMDTrainer, make_mesh
+
+from chipbench import harness, models, models_lm
+
+ref = importlib.import_module("chipbench.references.kimi_linear")
+CFG = harness.load_json(harness.HERE, "tests", "configs",
+                        "kimi-linear-tiny.json")
+STEPS, LR, B, T = 3, 1e-3, 1, 40      # T: no multiple of KDA's chunk
+
+
+def test_the_toy_configuration_has_every_kind_of_layer():
+    assert ref.layer_kinds(CFG) == [("kda", "dense"), ("kda", "moe"),
+                                    ("kda", "moe"), ("mla", "moe"),
+                                    ("kda", "moe")]
+    assert CFG["num_experts"] < CFG["num_experts_total"]    # a share
+
+
+@pytest.fixture(scope="module")
+def both():
+    """The program's and the reference's readings of the same steps."""
+    rng = np.random.default_rng(0)
+    tokens, labels = (rng.integers(0, CFG["vocab_size"], (B, T),
+                                   dtype=np.int32) for _ in range(2))
+    weights, bias = ref.init_weights(CFG, 5), ref.selection_bias(CFG)
+    train = dict(dtype="float32", optimizer="adam", learning_rate=LR,
+                 remat=True)
+    trainer, named = models_lm.kimi_linear_trainer(
+        CFG, train, weights, bias, jax.devices()[:1])
+    w = {k: jnp.asarray(v) for k, v in weights.items()}
+    state = tuple(jax.tree_util.tree_map(jnp.zeros_like, w)
+                  for _ in range(2))
+    out = {"loss": [], "ref_loss": []}
+    for n in range(STEPS):
+        out["loss"].append(float(trainer.step(
+            mx.nd.array(tokens, dtype="int32"),
+            mx.nd.array(labels, dtype="int32"))._data))
+        total, grads = jax.value_and_grad(
+            lambda w_: ref.loss_sum(CFG, w_, tokens, labels))(w)
+        grads = jax.tree_util.tree_map(lambda g: g / tokens.size, grads)
+        if n == 0:
+            _, mean = models.trainer_state(trainer, named)
+            out["grad"] = {k: np.asarray(v) / (1 - ref.BETA1)
+                           for k, v in mean.items()}
+            out["ref_grad"] = {k: np.asarray(v) for k, v in grads.items()}
+        out["ref_loss"].append(float(total) / tokens.size)
+        w, state = ref.adam_step(w, grads, state, LR, n + 1)
+    params, _ = models.trainer_state(trainer, named)
+    out["params"] = {k: np.asarray(v) for k, v in params.items()}
+    out["ref_params"] = {k: np.asarray(v) for k, v in w.items()}
+    out["start"] = weights
+    return out
+
+
+@pytest.mark.parametrize("step", range(STEPS))
+def test_loss_of_every_step_matches_the_reference(both, step):
+    assert both["loss"][step] == pytest.approx(both["ref_loss"][step],
+                                               rel=2e-6)
+
+
+KINDS = ["embed", "lm_head", "norm", "layer0.q_conv", "layer0.A_log",
+         "layer0.dt_bias", "layer0.f_up", "layer0.g_up_bias", "layer0.beta",
+         "layer0.o_norm", "layer0.down", "layer1.router",
+         "layer1.experts_gate", "layer1.experts_down", "layer1.shared_up",
+         "layer3.q", "layer3.dkv", "layer3.kv_norm", "layer3.ukv",
+         "layer3.out", "layer4.k", "layer4.experts_up"]
+
+
+@pytest.mark.parametrize("leaf", KINDS)
+def test_first_gradient_matches_the_reference(both, leaf):
+    got, want = both["grad"][leaf], both["ref_grad"][leaf]
+    np.testing.assert_allclose(got, want, rtol=2e-4,
+                               atol=2e-5 * np.abs(want).max())
+
+
+def test_every_leaf_has_a_gradient_and_the_reference_names_them_all(both):
+    assert set(both["grad"]) == set(both["ref_grad"]) == set(both["start"])
+    still = [k for k, g in both["ref_grad"].items() if not g.any()]
+    assert still == []
+
+
+def test_three_adam_steps_land_where_the_references_do(both):
+    for name, want in both["ref_params"].items():
+        moved = np.abs(want - both["start"][name]).max()
+        np.testing.assert_allclose(both["params"][name], want, rtol=0,
+                                   atol=0.02 * moved + 1e-7, err_msg=name)
+
+
+# ------------------------------------------------- recomputation per unit
+
+class _LMLoss(gluon.loss.Loss):
+    def __init__(self):
+        super().__init__(1.0, 0)
+        self._ce = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def hybrid_forward(self, F, out, labels):
+        return self._ce(out.reshape((-1, out.shape[-1])),
+                        labels.reshape((-1,)))
+
+
+def _llama():
+    return transformer.TransformerLM(64, units=32, hidden_size=64,
+                                     num_layers=3, num_heads=2)
+
+
+def _kimi():
+    return kimi_linear.KimiLinearLM(
+        64, 32, [("kda", "dense"), ("mla", "moe")], num_heads=2,
+        kda_heads=2, kda_head_dim=16, kda_gate_rank=8, kv_rank=16,
+        nope_dim=16, shared_dim=8, v_dim=16, hidden_size=64,
+        expert_hidden_size=24, num_experts_total=8, top_k=2, held=(2, 4),
+        routed_scale=2.0)
+
+
+def _bert():
+    class MLM(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.bert = transformer.BERTModel(
+                    vocab_size=64, units=32, hidden_size=64, num_layers=3,
+                    num_heads=2, max_length=16, dropout=0.0)
+
+        def hybrid_forward(self, F, tokens):
+            return self.bert(tokens)[2]
+    return MLM()
+
+
+@pytest.fixture(scope="module", params=[_llama, _kimi, _bert],
+                ids=["llama", "kimi_linear", "bert"])
+def with_and_without(request):
+    tokens = mx.nd.array(np.random.RandomState(0).randint(0, 64, (2, 16)),
+                         dtype="int32")
+    out = {}
+    for remat in (False, True):
+        mx.random.seed(0)
+        net = request.param()
+        net.initialize(mx.init.Xavier())
+        trainer = SPMDTrainer(net, _LMLoss(), "sgd",
+                              make_mesh(dp=1, devices=jax.devices()[:1]),
+                              optimizer_params={"learning_rate": 0.1},
+                              remat=remat)
+        jitted, args = trainer.step_program(tokens, tokens)
+        peak = estimate_jit_memory(jitted, *args).activation_peak_bytes
+        text = str(jax.make_jaxpr(jitted)(*args))
+        loss = float(trainer.step(tokens, tokens)._data)
+        out[remat] = dict(
+            peak=peak, loss=loss, checkpoints=text.count("remat2"),
+            model=request.param.__name__,
+            params=[np.asarray(p.data()._data)
+                    for p in trainer._diff_params])
+    return out
+
+
+def test_recomputation_gives_the_same_step_bit_for_bit(with_and_without):
+    plain, remat = with_and_without[False], with_and_without[True]
+    assert remat["loss"] == plain["loss"]
+    for a, b in zip(plain["params"], remat["params"]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_recomputation_lowers_the_estimated_peak(with_and_without):
+    plain, remat = with_and_without[False], with_and_without[True]
+    if plain["model"] == "_kimi":
+        # at toy widths the peak lies inside the KDA op, which forms its
+        # chunks again in the backward pass with or without the trainer
+        assert remat["peak"] <= plain["peak"]
+    else:
+        assert remat["peak"] < plain["peak"]
+
+
+def test_recomputation_is_per_unit_not_around_the_whole_forward(
+        with_and_without):
+    plain, remat = with_and_without[False], with_and_without[True]
+    if plain["model"] != "_kimi":       # (the KDA op has one of its own)
+        assert plain["checkpoints"] == 0
+    assert remat["checkpoints"] - plain["checkpoints"] >= 3   # a unit each
+
+
+def test_remat_scope_outside_a_trace_just_runs():
+    from mxtpu.gluon.block import remat_scope
+
+    net = _kimi()
+    net.initialize(mx.init.Xavier())
+    x = mx.nd.array(np.arange(32).reshape(2, 16) % 64, dtype="int32")
+    plain = net(x).asnumpy()
+    with remat_scope():
+        np.testing.assert_array_equal(net(x).asnumpy(), plain)
+    loads = kimi_linear.expert_loads()
+    assert any(sum(v["held"]) + v["elsewhere"] == 2 * 16 * 2
+               for v in loads.values())
+
+
+def test_from_config_takes_the_layers_it_has_from_the_published_lists():
+    net = kimi_linear.kimi_linear_from_config(
+        dict(CFG, num_hidden_layers=4), held=(4, 4), num_experts_total=16,
+        kda_gate_rank=8)
+    assert net.layer_kinds == [("kda", "dense"), ("kda", "moe"),
+                               ("kda", "moe"), ("mla", "moe")]
+    with pytest.raises(ValueError, match="neither"):
+        kimi_linear.kimi_linear_from_config(dict(
+            CFG, linear_attn_config=dict(CFG["linear_attn_config"],
+                                         kda_layers=[1, 2])))

@@ -1,0 +1,140 @@
+"""The dropless expert layer that holds a share of the experts
+(``moe_expert_share`` / ``ExpertShare``): the shares' routed parts, with
+the shared expert counted once, add up to the whole layer as the plain
+reference (chipbench/references/kimi_linear.py) computes it uncut, and
+no pair is dropped however skewed the router."""
+
+import importlib
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import mxtpu as mx
+from mxtpu.ops import moe
+
+ref = importlib.import_module("chipbench.references.kimi_linear")
+
+D, F, E, K, SCALE = 32, 24, 16, 4, 2.446
+CFG = dict(num_experts_per_token=K, moe_renormalize=True,
+           routed_scaling_factor=SCALE, num_experts_total=E)
+
+
+def weights(skew=0.0, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
+    n = lambda k, *s: 0.2 * jax.random.normal(k, s)
+    # skew: the selection bias makes every token choose expert 3
+    bias = (0.01 * jax.random.normal(ks[1], (E,))).at[3].add(skew)
+    return dict(router=n(ks[0], E, D), bias=bias,
+                gate=n(ks[2], E, D, F), up=n(ks[3], E, D, F),
+                down=n(ks[4], E, F, D), shared_gate=n(ks[5], F, D),
+                shared_up=n(ks[6], F, D), shared_down=n(ks[7], D, F),
+                x=jax.random.normal(ks[8], (2, 40, D)))
+
+
+def reference_layer(w, x, first, held):
+    """The reference's expert layer holding experts first..first+held-1."""
+    cfg = dict(CFG, held_experts_first=first, num_experts=held)
+    sl = slice(first, first + held)
+    named = {"router": w["router"], "experts_gate": w["gate"][sl],
+             "experts_up": w["up"][sl], "experts_down": w["down"][sl],
+             "shared_gate": w["shared_gate"], "shared_up": w["shared_up"],
+             "shared_down": w["shared_down"]}
+    return ref._expert_layer(cfg, named, "", x, w["bias"], "highest")
+
+
+def share(w, x, first, held):
+    sl = slice(first, first + held)
+    return moe.moe_expert_share(
+        x, w["router"], w["bias"], w["gate"][sl], w["up"][sl], w["down"][sl],
+        held_first=first, top_k=K, scale=SCALE)
+
+
+@pytest.mark.parametrize("tile", [4096, 48], ids=["one_tile", "many_tiles"])
+@pytest.mark.parametrize("skew", [0.0, 4.0], ids=["even", "skewed"])
+@pytest.mark.parametrize("shares", [1, 4], ids=["whole", "four_shares"])
+def test_the_shares_add_up_to_the_uncut_layer(monkeypatch, shares, skew,
+                                              tile):
+    monkeypatch.setattr(moe, "PAIRS_PER_TILE", tile)
+    w = weights(skew)
+    x, held = w["x"], E // shares
+    total = ref._swiglu("highest", x, w["shared_gate"], w["shared_up"],
+                        w["shared_down"])                   # counted once
+    pairs = 0
+    for first in range(0, E, held):
+        y, load = share(w, x, first, held)
+        total = total + y
+        load = np.asarray(load)
+        # no pair is dropped: what is not held here fell elsewhere
+        assert load.sum() == x.shape[0] * x.shape[1] * K
+        pairs += load[:-1].sum()
+    assert pairs == x.shape[0] * x.shape[1] * K
+    np.testing.assert_allclose(
+        np.asarray(total), np.asarray(reference_layer(w, x, 0, E)),
+        rtol=1e-5, atol=2e-6)
+    if skew:
+        _, load = share(w, x, 0, held)
+        assert load[3] == x.shape[0] * x.shape[1]          # every token
+
+
+@pytest.mark.parametrize("tile", [4096, 48], ids=["one_tile", "many_tiles"])
+def test_gradients_of_a_share_match_the_reference(monkeypatch, tile):
+    monkeypatch.setattr(moe, "PAIRS_PER_TILE", tile)
+    w = weights(skew=1.0, seed=1)
+    first, held = 4, 4
+
+    def routed(fn):
+        def loss(x, gate, router):
+            ww = dict(w, gate=w["gate"].at[first:first + held].set(gate),
+                      router=router)
+            return jnp.sum(jnp.square(fn(ww, x)))
+        return jax.grad(loss, argnums=(0, 1, 2))(
+            w["x"], w["gate"][first:first + held], w["router"])
+
+    got = routed(lambda ww, x: share(ww, x, first, held)[0])
+    want = routed(lambda ww, x: reference_layer(ww, x, first, held)
+                  - ref._swiglu("highest", x, ww["shared_gate"],
+                                ww["shared_up"], ww["shared_down"]))
+    for g, r, name in zip(got, want, ("x", "experts_gate", "router")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(r))),
+                                   err_msg=name)
+
+
+def test_the_selection_bias_chooses_and_takes_no_gradient():
+    w = weights()
+    bias = w["bias"].at[5].add(10.0)            # everyone now chooses 5
+    _, load = moe.moe_expert_share(
+        w["x"], w["router"], bias, w["gate"][4:8], w["up"][4:8],
+        w["down"][4:8], held_first=4, top_k=K, scale=SCALE)
+    assert load[1] == w["x"].shape[0] * w["x"].shape[1]
+    grad = jax.grad(lambda b: jnp.sum(moe.moe_expert_share(
+        w["x"], w["router"], b, w["gate"][4:8], w["up"][4:8],
+        w["down"][4:8], held_first=4, top_k=K, scale=SCALE)[0]))(bias)
+    assert not np.asarray(grad).any()
+
+
+def test_expert_share_block_counts_its_load_and_rejects_a_bad_share():
+    from mxtpu.models.kimi_linear import ExpertShare, expert_loads
+
+    layer = ExpertShare(D, F, E, K, held=(4, 4), routed_scale=SCALE,
+                        prefix="probe_moe_")
+    layer.initialize(mx.init.Xavier())
+    x = mx.nd.array(np.asarray(weights()["x"]))
+    y = layer(x)
+    assert y.shape == x.shape
+    load = expert_loads()["probe_moe"]
+    assert sum(load["held"]) + load["elsewhere"] == 2 * 40 * K
+    layer(x)
+    again = expert_loads()["probe_moe"]
+    assert again["held"] == load["held"]                # the newest pass
+    assert again["held_sum"] == [2.0 * n for n in load["held"]]
+    from mxtpu.observability.metrics import default_registry
+    snap = default_registry().snapshot()
+    assert snap["moe.probe_moe.elsewhere"] == load["elsewhere"]
+    assert not [p for p in layer.collect_params().values()
+                if p.name.endswith(("load", "load_sum", "select_bias"))
+                and p.grad_req != "null"]
+    with pytest.raises(ValueError, match="not within"):
+        ExpertShare(D, F, E, K, held=(14, 4))

@@ -412,6 +412,16 @@ SKIP = {
                 "grad over the lattice is O(T*V) slow",
     "flash_attention": "covered by tests/test_flash_attention.py "
                        "(fwd parity + gradients)",
+    "kda": "chunked recurrence with Pallas state kernels; covered by "
+           "tests/test_kda.py (forward and every input's gradient "
+           "against the step-by-step recurrence)",
+    "kda_mixer": "the fused KDA mixer core around that recurrence; "
+                 "covered by tests/test_kimi_linear.py (model against "
+                 "its plain reference: loss, gradients, Adam steps)",
+    "moe_expert_share": "discrete top-k routing: numeric gradients cross "
+                        "routing decision boundaries by construction; "
+                        "value, gradients and the shares' sum covered by "
+                        "tests/test_moe_share.py",
     "paged_decode_attention": "ragged Pallas kernel; covered by "
                               "tests/test_paged_attention_pallas.py "
                               "(XLA-path parity matrix incl. int8)",
