@@ -14,7 +14,8 @@ first compile.  This pass answers it statically:
 - **Jittable callables** (:func:`estimate_jit_memory`): the same
   liveness scan over the ``jax.make_jaxpr`` equation list (call-like
   sub-jaxprs — pjit, remat, custom_vjp — contribute their inner peak
-  while executing), which covers CachedOp-style compiled programs,
+  while executing, and free an argument nothing reads after them at
+  its last reader inside), which covers CachedOp-style compiled programs,
   decode steps with KV caches, and trainer steps.
 - **KV caches** (:func:`kv_cache_residency`): persistent cache bytes for
   a block's ``init_cache`` under a cache PartitionSpec, abstractly
@@ -331,16 +332,19 @@ _LAYOUT_PRIMS = {"transpose", "reshape", "squeeze", "expand_dims",
                  "rev", "bitcast_convert_type", "copy"}
 
 
-def _jaxpr_liveness_peak(jaxpr) -> int:
+def _jaxpr_liveness_peak(jaxpr, dying=()) -> int:
     """Peak live intermediate bytes over a jaxpr's equation schedule
     (outvars live to the end; invars/constvars excluded — the caller
     accounts them as resident).  Layout ops (transpose/reshape/...)
     alias their input: they add no bytes, and extend the aliased
-    value's liveness instead."""
+    value's liveness instead.  ``dying`` are the invars that the caller
+    holds for nothing after this call: they are counted here, live from
+    the start to their last consumer, as XLA frees them once the call is
+    inlined."""
     from jax.extend.core import Literal
 
     eqns = jaxpr.eqns
-    defined = set()
+    defined = set(dying)
     for eqn in eqns:
         for v in eqn.outvars:
             defined.add(v)
@@ -375,14 +379,25 @@ def _jaxpr_liveness_peak(jaxpr) -> int:
             if c in defined:
                 last_use[c] = len(eqns)
 
-    live: Dict[Any, int] = {}
-    running = 0
-    peak = 0
+    live: Dict[Any, int] = {v: _aval_nbytes(v.aval) for v in dying
+                            if v in last_use}
+    running = sum(live.values())
+    peak = running
     for n, eqn in enumerate(eqns):
         inner = (_inner_jaxpr(eqn)
                  if eqn.primitive.name in _CALL_PRIMITIVES else None)
         transient = 0
         if inner is not None:
+            # a value whose last consumer is this call is the call's to
+            # free: it leaves the count here and joins the inner one
+            passed = [canon(v) for v in eqn.invars
+                      if not isinstance(v, Literal)]
+            handed = [(canon(v), w)
+                      for v, w in zip(eqn.invars, inner.invars)
+                      if not isinstance(v, Literal) and canon(v) in live
+                      and last_use[canon(v)] == n
+                      and passed.count(canon(v)) == 1
+                      ] if len(inner.invars) == len(eqn.invars) else []
             # the inner peak excludes the inner invars (resident at the
             # outer level) but INCLUDES the inner outputs (live to the
             # inner end); the outer level counts this eqn's outvars
@@ -390,7 +405,9 @@ def _jaxpr_liveness_peak(jaxpr) -> int:
             out_bytes = sum(_aval_nbytes(getattr(v, "aval", None))
                             for v in inner.outvars
                             if not isinstance(v, Literal))
-            transient = max(0, _jaxpr_liveness_peak(inner) - out_bytes)
+            transient = max(0, _jaxpr_liveness_peak(
+                inner, [w for _, w in handed]) - out_bytes) - sum(
+                live[c] for c, _ in handed)
         elif eqn.primitive.name == "scan":
             body = _inner_jaxpr(eqn)
             if body is not None:

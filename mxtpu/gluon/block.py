@@ -32,6 +32,7 @@ from .. import autograd, ndarray
 from ..base import MXTPUError
 from ..context import Context, current_context
 from ..ndarray import NDArray
+from ..ops import remat as _kept
 from .parameter import (Parameter, ParameterDict, DeferredInitializationError,
                         Constant)
 
@@ -45,8 +46,13 @@ __all__ = ["Block", "HybridBlock", "SymbolBlock", "remat_scope"]
 # inside ``remat_scope()`` (``SPMDTrainer(remat=True)`` opens it around
 # the traced forward) its forward is wrapped in ``jax.checkpoint`` over
 # the unit's own parameters and inputs, so the backward pass keeps the
-# unit's input and forms everything inside it again.  Units inside a unit
-# are not wrapped a second time.
+# unit's input, and what the unit's ops marked, and forms everything
+# else inside it again.  An op marks a value with ``ops.remat.keep`` where
+# forming it a second time is dear and holding it cheap: flash
+# attention's output and logsumexp, the KDA mixer's output — as much
+# again as the unit's input, and one forward of the kernel less.  A unit
+# whose ops mark nothing keeps its input alone.  Units inside a unit are
+# not wrapped a second time.
 
 _REMAT = threading.local()
 
@@ -54,7 +60,9 @@ _REMAT = threading.local()
 class remat_scope:
     """Inside it, every ``remat_unit`` block recomputes its forward in
     the backward pass.  Only meaningful while a program is traced (under
-    ``jax.grad``); eager calls just run."""
+    ``jax.grad``); eager calls just run.  Opening it starts the count of
+    what the units keep (``remat.kept_outputs``, ``remat.kept_bytes``)
+    anew."""
 
     def __init__(self, active=True):
         self._active = bool(active)
@@ -62,6 +70,8 @@ class remat_scope:
     def __enter__(self):
         self._was = getattr(_REMAT, "active", False)
         _REMAT.active = self._active
+        if self._active and not self._was:
+            _kept.reset()
         return self
 
     def __exit__(self, *exc):
@@ -404,7 +414,7 @@ class Block:
             return tuple(x._data if isinstance(x, NDArray) else x
                          for x in flat), after
 
-        flat, after = jax.checkpoint(fn)(
+        flat, after = jax.checkpoint(fn, policy=_kept.policy)(
             tuple(h._data for h in holders),
             tuple(a._data for a, nd in zip(args, is_array) if nd))
         for p, h, new in zip(params, holders, after):

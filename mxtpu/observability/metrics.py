@@ -31,6 +31,10 @@ source              pulls
 ``kernel_invocations``  :func:`mxtpu.ops.pallas.counters.counts` —
                     trace-time Pallas kernel invocation counters
                     (``kernel_invocations.<kernel_name>``)
+``remat``           :func:`mxtpu.ops.remat.counts` — what the units
+                    of recomputation kept beside their inputs in the
+                    newest program traced under ``remat_scope``
+                    (``remat.kept_outputs``, ``remat.kept_bytes``)
 ``lifecycle``       page-sanitizer shadow-accounting stats from the
                     serving-lifecycle pass (``lifecycle.armed``,
                     ``lifecycle.pages_tracked``,
@@ -244,6 +248,16 @@ def _src_kernel_invocations() -> dict:
     return counters.counts()
 
 
+def _src_remat() -> dict:
+    """What the units of recomputation kept beside their inputs in the
+    newest program traced under ``remat_scope``: ``remat.kept_outputs``
+    values an op marked and ``remat.kept_bytes`` their bytes, as the
+    units' checkpoint policy ruled while the backward pass was traced
+    (ops/remat.py)."""
+    from ..ops import remat
+    return remat.counts()
+
+
 def _src_moe() -> dict:
     """Load of every live expert layer that holds a share of the experts
     (``moe.<layer>.held`` — pairs each held expert received in the
@@ -267,6 +281,7 @@ def default_registry() -> MetricsRegistry:
     reg.register_source("tracer", _src_tracer)
     reg.register_source("flight", _src_flight)
     reg.register_source("kernel_invocations", _src_kernel_invocations)
+    reg.register_source("remat", _src_remat)
     reg.register_source("lifecycle", _src_lifecycle)
     reg.register_source("moe", _src_moe)
     return reg

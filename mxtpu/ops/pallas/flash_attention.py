@@ -28,6 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...base import register_op
+from ..remat import keep
 from . import counters
 from .partition import shard_attention
 
@@ -421,6 +422,10 @@ def _make_flash(scale, causal, q_block, kv_block, interpret):
     def fa_fwd(q, k, v):
         out, lse = _flash_fwd(q, k, v, scale, causal, q_block, kv_block,
                               interpret)
+        # a unit of recomputation keeps these two and forms q, k, v again
+        # from its projections: without the marks the forward kernel
+        # would run a second time for them (ops/remat.py)
+        out, lse = keep(out), keep(lse)
         return out, (q, k, v, out, lse)
 
     def fa_bwd(res, g):

@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...base import register_op
+from ..remat import keep
 from . import counters
 
 __all__ = ["kda", "kda_mixer", "kernel_specs"]
@@ -413,12 +414,15 @@ def kda(q, k, v, g, beta, chunk=CHUNK):
     a length that is no multiple of it is padded with positions that
     neither decay nor write.  The backward pass keeps the five inputs
     and nothing else: the chunks' operands and states are formed again,
-    ``HEADS_AT_A_TIME`` heads at a time."""
+    ``HEADS_AT_A_TIME`` heads at a time.  The output is marked for the
+    unit of recomputation that holds the call (ops/remat.py): a unit
+    that kept its input alone would run this forward a second time for
+    nothing but the output."""
     _check_chunk(chunk)
     seqs = tuple(a.astype(jnp.float32) for a in (q, k, v, g, beta))
     o = _by_groups_of_heads(
         lambda xs, _: _recurrence(*xs, chunk=chunk), seqs)
-    return o.astype(v.dtype)
+    return keep(o.astype(v.dtype))
 
 
 def _short_conv(x, filt):
@@ -448,7 +452,11 @@ def kda_mixer(q, k, v, f, gate, beta, q_conv, k_conv, v_conv, a_log,
 
     One fused op and not five because of what the backward pass keeps:
     the five projections and this op's output — a third of what the
-    pieces keep one by one (a dozen more arrays of B x T x H x K)."""
+    pieces keep one by one (a dozen more arrays of B x T x H x K).  Under
+    recomputation per unit the projections are formed again and the
+    output, marked here outside the groups' own checkpoint, is kept
+    (ops/remat.py): B x T x H x K buys back a whole forward of the
+    mixer."""
     _check_chunk(chunk)
     H, K = q.shape[2:]
     f32 = lambda a: a.astype(jnp.float32)                   # noqa: E731
@@ -472,7 +480,7 @@ def kda_mixer(q, k, v, f, gate, beta, q_conv, k_conv, v_conv, a_log,
         (f32(q_conv).reshape(H, K, W), f32(k_conv).reshape(H, K, W),
          f32(v_conv).reshape(H, K, W), f32(a_log),
          f32(dt_bias).reshape(H, K)))
-    return o.astype(v.dtype)
+    return keep(o.astype(v.dtype))
 
 
 @register_op("kda", aliases=("_contrib_kda",))

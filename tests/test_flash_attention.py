@@ -293,3 +293,81 @@ def test_a_long_head_asks_for_its_vmem_and_a_short_one_for_nothing():
     assert 24 * 2 ** 20 < asked.vmem_limit_bytes < 128 * 2 ** 20
     back = fa._compiler_params(fa._bwd_vmem(8192, 512, 192, 128, "float32"))
     assert asked.vmem_limit_bytes < back.vmem_limit_bytes < 128 * 2 ** 20
+
+
+# ---------------------------- under a unit of recomputation (ops/remat)
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_a_unit_keeps_out_and_lse_and_runs_the_forward_once(qkv, causal):
+    """A checkpoint with the units' policy keeps what ``fa_fwd`` marked:
+    the forward kernel is not in the backward pass a second time, q, k
+    and v are formed again, and no gradient moves by a bit."""
+    import collections
+    import re
+    from mxtpu.ops import remat
+
+    q, k, v = qkv
+    B, H, T, D = q.shape
+    w = jnp.array(np.random.RandomState(1).randn(D, 4).astype("float32"))
+
+    def unit(q, k, v, w):
+        q, k, v = (jnp.tanh(a) for a in (q, k, v))  # a projection's stead
+        out = flash_attention(q, k, v, causal=causal, q_block=64,
+                              kv_block=64)
+        return jnp.tanh(out @ w).sum()
+
+    def under(policy):
+        grad = jax.grad(jax.checkpoint(unit, policy=policy),
+                        argnums=(0, 1, 2, 3))
+        remat.reset()
+        text = str(jax.make_jaxpr(grad)(q, k, v, w))
+        return (collections.Counter(re.findall(r"name=(flash_\w+)", text)),
+                text.count("tanh"), remat.counts(), grad(q, k, v, w))
+
+    kernels, tanhs, counts, grads = under(remat.policy)
+    alone, tanhs_alone, nothing, want = under(None)
+    assert kernels == {"flash_attention_fwd": 1, "flash_attention_bwd": 1}
+    assert alone == {"flash_attention_fwd": 2, "flash_attention_bwd": 1}
+    assert tanhs == tanhs_alone             # q, k, v are formed again
+    assert counts == {"kept_outputs": 2,
+                      "kept_bytes": B * H * T * D * 4 + B * H * T * 4}
+    assert nothing == {"kept_outputs": 0, "kept_bytes": 0}
+    for a, b in zip(grads, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_outside_a_checkpoint_the_marks_leave_the_program_alone(qkv):
+    grad = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, q_block=64, kv_block=64).sum(),
+        argnums=(0, 1, 2)))
+    assert "mxtpu_kept" not in grad.lower(*qkv).compile().as_text()
+
+
+def test_a_sharded_unit_keeps_its_shards_of_out_and_lse():
+    """Under a sharded trainer the kernel sits in a ``shard_map``: the
+    units' policy reaches the marks through it, and counts a shard."""
+    import re
+    from mxtpu import gluon
+    from mxtpu.models import transformer
+    from mxtpu.observability.metrics import get_registry
+    from mxtpu.parallel import SPMDTrainer, make_mesh
+
+    B, H, T, layers = 4, 2, 16, 2
+    mx.random.seed(0)
+    net = transformer.TransformerLM(64, units=32, hidden_size=64,
+                                    num_layers=layers, num_heads=H)
+    net.initialize(mx.init.Xavier())
+    trainer = SPMDTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(axis=-1),
+                          "sgd", make_mesh(dp=2, devices=jax.devices()[:2]),
+                          optimizer_params={"learning_rate": 0.1},
+                          remat=True)
+    tokens = mx.nd.array(np.random.RandomState(0).randint(0, 64, (B, T)),
+                         dtype="int32")
+    jitted, args = trainer.step_program(tokens, tokens)
+    text = str(jax.make_jaxpr(jitted)(*args))
+    assert "shard_map" in text
+    assert len(re.findall(r"name=flash_attention_fwd", text)) == layers
+    snap = get_registry().snapshot()
+    shard = B // 2 * H * T * (32 // H + 1) * 4     # out and lse, float32
+    assert (snap["remat.kept_outputs"], snap["remat.kept_bytes"]) == (
+        2 * layers, layers * shard)

@@ -167,3 +167,61 @@ def test_kernel_specs_describe_the_real_calls(monkeypatch):
                  if op.kind == kind], (spec.name, kind)
         assert [tuple(sc.shape) for sc in call.get("scratch_shapes", ())] \
             == [sc.shape for sc in spec.scratch]
+
+
+# ---------------------------- under a unit of recomputation (ops/remat)
+
+def _under_a_unit(op, args, w, policy):
+    """Gradient of ``sum(tanh(op(x) @ w))`` through a checkpoint as a
+    unit of recomputation has it, with the unit's policy or none: the
+    jaxpr's kernels by name, what the policy kept, the gradients."""
+    import collections
+    import re
+    from mxtpu.ops import remat
+
+    def unit(args, w):
+        scaled = tuple(a * 1.0 for a in args)       # a projection's stead
+        return jnp.tanh(jnp.einsum("bthv,vu->bthu", op(*scaled), w)).sum()
+
+    grad = jax.grad(jax.checkpoint(unit, policy=policy), argnums=(0, 1))
+    remat.reset()
+    names = re.findall(r"name=(kda_\w+)", str(jax.make_jaxpr(grad)(args, w)))
+    return collections.Counter(names), remat.counts(), grad(args, w)
+
+
+@pytest.mark.parametrize("op", ["kda", "kda_mixer"])
+def test_a_unit_keeps_the_output_and_runs_the_forward_once_less(op):
+    from mxtpu.ops import remat
+
+    (q, k, v, g, beta), _ = inputs(48, (1e-3, 1e-1), H=6, V=16)
+    B, T, H, K = q.shape                            # 6 heads: 2 groups of 3
+    if op == "kda":
+        fn, args = (lambda *a: kda.kda(*a, chunk=16)), (q, k, v, g, beta)
+    else:
+        conv = jnp.full((H * K, 4), 0.25)
+        fn = lambda q, k, v, f, gate, beta: kda.kda_mixer(   # noqa: E731
+            q, k, v, f, gate, beta, conv, conv, conv, jnp.zeros(H),
+            jnp.zeros(H * K), jnp.ones(K), chunk=16)
+        args = (q, k, v, g, q + k, beta)
+    w = jax.random.normal(jax.random.PRNGKey(7), (16, 4))
+    kept, counts, grads = _under_a_unit(fn, args, w, remat.policy)
+    alone, nothing, want = _under_a_unit(fn, args, w, None)
+    assert (kept[kda.CHUNK_FWD_NAME], alone[kda.CHUNK_FWD_NAME]) == (2, 3)
+    # the groups' own recomputation reads the state pass's output only in
+    # the mixer (its norm and gate); the bare op's backward does not
+    states = (2, 3) if op == "kda_mixer" else (1, 2)
+    assert (kept[kda.FWD_NAME], alone[kda.FWD_NAME]) == states
+    for name in (kda.CHUNK_BWD_NAME, kda.BWD_NAME, kda.FWD_STATES_NAME):
+        assert (kept[name], alone[name]) == (1, 1)
+    assert counts == {"kept_outputs": 1, "kept_bytes": B * T * H * 16 * 4}
+    assert nothing == {"kept_outputs": 0, "kept_bytes": 0}
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_outside_a_checkpoint_the_mark_leaves_the_program_alone():
+    (q, k, v, g, beta), _ = inputs(32, (1e-3, 1e-1))
+    text = jax.jit(lambda *a: kda.kda(*a, chunk=16)).lower(
+        q, k, v, g, beta).compile().as_text()
+    assert "mxtpu_kept" not in text

@@ -15,6 +15,8 @@ import mxtpu as mx
 from mxtpu import gluon
 from mxtpu.analysis.memory_estimate import estimate_jit_memory
 from mxtpu.models import kimi_linear, transformer
+from mxtpu.observability.metrics import get_registry
+from mxtpu.ops import remat as kept
 from mxtpu.parallel import SPMDTrainer, make_mesh
 
 from chipbench import harness, models, models_lm
@@ -32,17 +34,24 @@ def test_the_toy_configuration_has_every_kind_of_layer():
     assert CFG["num_experts"] < CFG["num_experts_total"]    # a share
 
 
-@pytest.fixture(scope="module")
-def both():
-    """The program's and the reference's readings of the same steps."""
+def _toy_trainer():
+    """(trainer, {name: Parameter}, starting weights, tokens, labels) of
+    the toy configuration: Adam, recomputation per unit."""
     rng = np.random.default_rng(0)
     tokens, labels = (rng.integers(0, CFG["vocab_size"], (B, T),
                                    dtype=np.int32) for _ in range(2))
-    weights, bias = ref.init_weights(CFG, 5), ref.selection_bias(CFG)
+    weights = ref.init_weights(CFG, 5)
     train = dict(dtype="float32", optimizer="adam", learning_rate=LR,
                  remat=True)
     trainer, named = models_lm.kimi_linear_trainer(
-        CFG, train, weights, bias, jax.devices()[:1])
+        CFG, train, weights, ref.selection_bias(CFG), jax.devices()[:1])
+    return trainer, named, weights, tokens, labels
+
+
+@pytest.fixture(scope="module")
+def both():
+    """The program's and the reference's readings of the same steps."""
+    trainer, named, weights, tokens, labels = _toy_trainer()
     w = {k: jnp.asarray(v) for k, v in weights.items()}
     state = tuple(jax.tree_util.tree_map(jnp.zeros_like, w)
                   for _ in range(2))
@@ -217,3 +226,182 @@ def test_from_config_takes_the_layers_it_has_from_the_published_lists():
         kimi_linear.kimi_linear_from_config(dict(
             CFG, linear_attn_config=dict(CFG["linear_attn_config"],
                                          kda_layers=[1, 2])))
+
+
+# --------------------------------- what a unit keeps beside its input
+#
+# ops/remat.py: the KDA mixer's output and flash attention's output and
+# logsumexp are marked, and the units' checkpoint keeps them; so the
+# backward pass runs those kernels' forward twice (KDA: the forward and
+# the groups' own recomputation) or once (flash), where a unit that kept
+# its input alone — ``jax.checkpoint(fn, policy=None)``, the program
+# before the marks — ran them once more.
+
+KERNEL = r"name=(kda_\w+|flash_attention_\w+)"
+
+
+def _toy_steps(policy):
+    """Three Adam steps of the toy configuration with ``policy`` as the
+    units': the step program's kernels by name, what its units kept,
+    losses, weights and Adam means."""
+    import collections
+    import re
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kept, "policy", policy)
+        trainer, named, _, tokens, labels = _toy_trainer()
+        tokens, labels = (mx.nd.array(a, dtype="int32")
+                          for a in (tokens, labels))
+        jitted, args = trainer.step_program(tokens, labels)
+        text = str(jax.make_jaxpr(jitted)(*args))
+        snap = get_registry().snapshot()
+        losses = [float(trainer.step(tokens, labels)._data)
+                  for _ in range(STEPS)]
+    params, mean = models.trainer_state(trainer, named)
+    return dict(kernels=collections.Counter(re.findall(KERNEL, text)),
+                kept=(snap["remat.kept_outputs"], snap["remat.kept_bytes"]),
+                losses=[x.hex() for x in losses],
+                params={k: np.asarray(v) for k, v in params.items()},
+                mean={k: np.asarray(v) for k, v in mean.items()})
+
+
+@pytest.fixture(scope="module")
+def toy_steps():
+    """With the units' policy, and with none: the program before the
+    marks.  (``with_and_without`` has recomputation against none.)"""
+    return {"kept": _toy_steps(kept.policy), "input_alone": _toy_steps(None)}
+
+
+@pytest.mark.parametrize("unit, names, kept_runs, alone_runs", [
+    ("kda", ("kda_chunk_fwd", "kda_state_fwd"), 2, 3),
+    ("mla", ("flash_attention_fwd",), 1, 2)])
+def test_the_backward_pass_runs_a_marked_kernels_forward_once_less(
+        toy_steps, unit, names, kept_runs, alone_runs):
+    layers = sum(mixer == unit for mixer, _ in ref.layer_kinds(CFG))
+    for name in names:
+        assert toy_steps["kept"]["kernels"][name] == layers * kept_runs
+        assert toy_steps["input_alone"]["kernels"][name] == \
+            layers * alone_runs
+    for name in ("kda_chunk_bwd", "kda_state_bwd", "kda_state_fwd_states",
+                 "flash_attention_bwd"):
+        assert toy_steps["kept"]["kernels"][name] == \
+            toy_steps["input_alone"]["kernels"][name]
+
+
+@pytest.mark.parametrize("what", ["losses", "mean", "params"])
+def test_keeping_the_marked_values_changes_no_bit(toy_steps, what):
+    ours, theirs = toy_steps["kept"][what], toy_steps["input_alone"][what]
+    if what == "losses":
+        assert ours == theirs
+    else:
+        assert sorted(ours) == sorted(theirs)
+        for name in ours:
+            np.testing.assert_array_equal(ours[name], theirs[name],
+                                          err_msg=name)
+
+
+def test_the_counters_read_what_the_shapes_say(toy_steps):
+    kda, heads = CFG["linear_attn_config"], CFG["num_attention_heads"]
+    kinds = [mixer for mixer, _ in ref.layer_kinds(CFG)]
+    mixer_out = B * T * kda["num_heads"] * kda["head_dim"] * 4
+    flash_out = B * heads * T * CFG["v_head_dim"] * 4
+    flash_lse = B * heads * T * 4
+    assert toy_steps["kept"]["kept"] == (
+        kinds.count("kda") + 2 * kinds.count("mla"),
+        kinds.count("kda") * mixer_out
+        + kinds.count("mla") * (flash_out + flash_lse))
+    assert toy_steps["input_alone"]["kept"] == (0, 0)
+
+
+def test_the_count_starts_anew_with_every_scope():
+    from jax._src.ad_checkpoint import name_p
+    from mxtpu.gluon.block import remat_scope
+
+    value = jax.ShapeDtypeStruct((3, 5), jnp.float32)
+    one = {"kept_outputs": 1, "kept_bytes": 60}
+    with remat_scope():
+        assert kept.policy(name_p, value, name=kept.KEPT)
+        assert not kept.policy(name_p, value, name="another")
+        assert not kept.policy(jax.lax.mul_p, value, value)
+        assert kept.counts() == one
+        with remat_scope(False):            # a unit's own forward
+            assert kept.counts() == one
+        with remat_scope():                 # no new program
+            assert kept.counts() == one
+    assert get_registry().snapshot()["remat.kept_bytes"] == 60
+    with remat_scope():
+        assert kept.counts() == {"kept_outputs": 0, "kept_bytes": 0}
+
+
+def test_tracing_the_step_again_reads_the_same_counts():
+    """As a jaxpr and lowered, each time traced anew: the policy rules
+    once on a marked equation in each trace, and each trace starts its
+    count with its scope.  A trace that jit finds in its cache runs
+    nothing and leaves the counts as they are."""
+    trainer, _, _, tokens, labels = _toy_trainer()
+    tokens, labels = (mx.nd.array(a, dtype="int32")
+                      for a in (tokens, labels))
+    jitted, args = trainer.step_program(tokens, labels)
+    step = jitted.__wrapped__
+    read = []
+    for trace in (jax.make_jaxpr(jitted),
+                  jax.jit(lambda *a: step(*a)).lower,
+                  jax.make_jaxpr(lambda *a: step(*a))):
+        trace(*args)
+        read.append(kept.counts())
+    assert read[0]["kept_outputs"] > 0
+    assert read[1] == read[0] and read[2] == read[0]
+    kept.reset()
+    jitted.lower(*args)                     # traced above
+    assert kept.counts() == {"kept_outputs": 0, "kept_bytes": 0}
+
+
+def test_each_thread_counts_its_own_programs():
+    import threading
+    from jax._src.ad_checkpoint import name_p
+    from mxtpu.gluon.block import remat_scope
+
+    value = jax.ShapeDtypeStruct((3, 5), jnp.float32)
+    read = {}
+
+    def trace(who, times, go, wait):
+        with remat_scope():
+            for _ in range(times):
+                kept.policy(name_p, value, name=kept.KEPT)
+            go.set()
+            wait.wait(10)                   # both scopes open at once
+            read[who] = kept.counts()
+
+    first, second = threading.Event(), threading.Event()
+    threads = [threading.Thread(target=trace, args=("a", 1, first, second)),
+               threading.Thread(target=trace, args=("b", 2, second, first))]
+    kept.reset()
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert read == {"a": {"kept_outputs": 1, "kept_bytes": 60},
+                    "b": {"kept_outputs": 2, "kept_bytes": 120}}
+    assert kept.counts() == {"kept_outputs": 0, "kept_bytes": 0}
+
+
+def test_a_unit_whose_ops_mark_nothing_lowers_to_the_program_it_had():
+    """Eight positions: attention takes its dense path, which marks
+    nothing."""
+    tokens = mx.nd.array(np.random.RandomState(0).randint(0, 64, (2, 8)),
+                         dtype="int32")
+    texts = {}
+    for policy in (kept.policy, None):
+        mx.random.seed(0)
+        net = _llama()
+        net.initialize(mx.init.Xavier())
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(kept, "policy", policy)
+            trainer = SPMDTrainer(
+                net, _LMLoss(), "sgd",
+                make_mesh(dp=1, devices=jax.devices()[:1]),
+                optimizer_params={"learning_rate": 0.1}, remat=True)
+            texts[policy] = trainer.lower_step(tokens, tokens).as_text()
+        assert get_registry().snapshot()["remat.kept_outputs"] == 0
+    assert texts[kept.policy].count("func.func") > 3     # units, wrapped
+    assert texts[kept.policy] == texts[None]

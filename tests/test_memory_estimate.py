@@ -335,6 +335,32 @@ def test_nested_jit_intermediates_are_counted():
     assert nested.activation_peak_bytes == flat.activation_peak_bytes
 
 
+@pytest.mark.parametrize("read_after", [False, True])
+def test_a_value_whose_last_reader_is_a_call_is_freed_inside_it(read_after):
+    """XLA inlines the call and frees ``a`` after the sine; a value the
+    caller reads again stays for the whole call.  (A unit of
+    recomputation's backward pass is such a call, and what the unit kept
+    is such a value: tests/test_kimi_linear.py.)"""
+    def inner(a):
+        b = jnp.sin(a)
+        return jnp.exp(b) * b               # b, the exponential, the product
+
+    def flat(x):
+        a = x + 1.0
+        return inner(a) + a if read_after else inner(a)
+
+    def nested(x):
+        a = x + 1.0
+        return jax.jit(inner)(a) + a if read_after else jax.jit(inner)(a)
+
+    x = jax.ShapeDtypeStruct((256, 256), jnp.float32)
+    size = 256 * 256 * 4
+    assert estimate_jit_memory(flat, x).activation_peak_bytes == \
+        (4 if read_after else 3) * size
+    assert estimate_jit_memory(nested, x).activation_peak_bytes == \
+        (4 if read_after else 3) * size
+
+
 # -- callable path of the registered pass ------------------------------
 
 def test_check_memory_callable_with_budget():
