@@ -11,7 +11,7 @@ calls, published widths, random weights from SEED:
 
 - trainer: BERT-base (12 x 768, batch 32 x seq 128, MLM head, bf16,
   adam) and ResNet-50 (bf16, batch 128, sgd) through ``SPMDTrainer``,
-  as bench.py builds them — finite, falling loss on a fixed batch, the
+  — finite, falling loss on a fixed batch, the
   Pallas flash-attention kernel in the compiled BERT step;
 - server: Llama-3-8B at every published width (units 4096, hidden 14336,
   32 Q / 8 KV heads of 128, vocab 128256), depth cut from 32 to 8 layers
@@ -125,8 +125,8 @@ def device_phase(chips):
 # ----------------------------------------------------------------- trainer
 
 def bert_for_mlm(seq):
-    """BERT-base with the MLM head as the training output, and its loss
-    — bench.py:_bench_bert's construction."""
+    """BERT-base with the MLM head as the training output, and its
+    loss."""
     from mxtpu import gluon
     from mxtpu.gluon import HybridBlock
     from mxtpu.models import transformer
@@ -184,8 +184,8 @@ def resnet_trainer(mesh, batch=128):
     net = vision.resnet50_v1()
     net.initialize()
     net.cast("bfloat16")
-    # bench.py's model, dtype, batch and optimizer; its lr of 0.1 suits a
-    # throughput reading, not a falling loss on one fixed random batch
+    # an lr of 0.1 suits a throughput reading, not a falling loss on one
+    # fixed random batch
     trainer = SPMDTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(),
                           "sgd", mesh,
                           optimizer_params={"learning_rate": 0.01,

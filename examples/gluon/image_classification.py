@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Image classification with the Gluon vision model zoo (parity:
 example/image-classification/ + example/gluon/image_classification.py —
-BASELINE config 2's training loop at example scale).
+GluonCV's ResNet-50 ImageNet training loop at example scale).
 
 Trains any model-zoo architecture on CIFAR-10 when present under
 --data-root, else on a synthetic 10-class image set, with hybridize,

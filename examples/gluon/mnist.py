@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """LeNet/MLP on MNIST, imperative Gluon (parity: example/gluon/mnist/
-mnist.py — BASELINE config 1, Milestone A).
+mnist.py: LeNet on MNIST, the reference's first Gluon example).
 
 Runs against real MNIST files when present under --data-root; otherwise
 generates a deterministic synthetic digit-like dataset so the example is

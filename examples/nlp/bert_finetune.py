@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """BERT fine-tuning for sentence classification (parity target: the
 GluonNLP finetune_classifier.py flow the reference powers with its
-contrib fused-MHA ops — BASELINE config 3's model family at example
-scale).
+contrib fused-MHA ops — GluonNLP's BERT-base, at example scale).
 
 A classifier head goes on BERT's pooled output; the whole thing trains
 through SPMDTrainer as one compiled step (fwd+bwd+AdamW) over a dp mesh.

@@ -2,7 +2,7 @@
 analysis for Pallas kernels.
 
 The serving/training stack's Pallas kernels (``mxtpu.ops.pallas``:
-flash_attention, conv_bwd, paged_attention) compile against TPU lowering
+flash_attention, kda, paged_attention) compile against TPU lowering
 constraints — lane-aligned last dims, dtype-dependent sublane tiling,
 the ~16 MiB VMEM ceiling per grid step — that until this pass lived only
 in docstrings, and whose violation surfaces as an opaque Mosaic lowering
@@ -620,8 +620,6 @@ def default_kernel_specs() -> List[KernelSpec]:
     - KDA's kernels (the chunks' operands forward and backward, the
       state pass forward writing states and backward) at 8,192 positions
       and at a toy length, heads and chunks of 128 x 64;
-    - conv_bwd at the ResNet small-channel stage its VMEM gate admits
-      (56x56x64, fp32);
     - paged_attention decode (W=1) and W-wide speculative verify (W=8),
       fp32 cache at block_size 16 and int8 cache at block_size 32 (the
       int8 sublane floor), GQA rep 4, D=128, ragged model tables — plus
@@ -638,7 +636,7 @@ def default_kernel_specs() -> List[KernelSpec]:
     """
     import importlib
 
-    from ..ops.pallas import conv_bwd, paged_attention
+    from ..ops.pallas import paged_attention
     kda = importlib.import_module("mxtpu.ops.pallas.kda")
 
     # the package re-exports the flash_attention FUNCTION under the
@@ -659,8 +657,6 @@ def default_kernel_specs() -> List[KernelSpec]:
         B=1, H=32, T=8192, D=192, Dv=128, dtype="float32"))
     for T in (8192, 96):
         specs.extend(kda.kernel_specs(B=1, H=4, T=T, K=128))
-    specs.append(conv_bwd.kernel_spec(N=8, H=56, W=56, Ci=64, Co=64,
-                                      dtype="float32"))
     for cache_dtype, block_size in (("float32", 16), ("int8", 32)):
         for W in (1, 8):
             specs.append(paged_attention.kernel_spec(

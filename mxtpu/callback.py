@@ -1,6 +1,6 @@
 """Training callbacks (parity: python/mxnet/callback.py).
 
-Speedometer is where the reference's BASELINE throughput numbers come from
+Speedometer is where the reference's published throughput numbers come from
 (SURVEY §5 observability) — the samples/sec logging is kept line-compatible.
 """
 

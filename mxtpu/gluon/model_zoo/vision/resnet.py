@@ -1,7 +1,8 @@
 """ResNet v1/v2 (parity: gluon/model_zoo/vision/resnet.py — ResNetV1,
 ResNetV2, BasicBlockV1/V2, BottleneckV1/V2, resnet18_v1 … resnet152_v2).
 
-North-star benchmark model (BASELINE config 2/4). TPU notes: NCHW layout
+GluonCV's ResNet-50 is the model zoo's reference workload (no cell of
+the benchmark trains it yet: ROADMAP W6). TPU notes: NCHW layout
 is kept for API parity — XLA:TPU re-lays out convolutions internally; use
 net.cast('bfloat16') for MXU-friendly mixed precision.
 """

@@ -3,8 +3,8 @@
 The reference's transformer story is GluonNLP BERT riding the fused
 interleaved-MHA kernels in src/operator/contrib/transformer.cc (SURVEY §2.1
 operator library row); its decoder-era models don't exist in MXNet 1.x.
-Here both live in-tree: BERT-style encoders (north-star config 3) and a
-Llama-style decoder (stretch config 5) designed for SPMD execution —
+Here both live in-tree: BERT-style encoders (GluonNLP's BERT-base) and a
+Llama-style decoder, designed for SPMD execution —
 sharding rules for tensor parallel, ring attention for sequence parallel,
 bf16-first compute.
 """

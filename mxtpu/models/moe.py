@@ -163,104 +163,41 @@ class MoEDecoderLayer(HybridBlock):
             return x + y, aux
         return x + self.moe(self.ffn_norm(x))
 
-    def step(self, x, cache_k, cache_v, pos):
-        """One-token KV-cache decode (mirrors LlamaDecoderLayer.step;
-        the routed FFN runs capacity-unbounded — see decode_forward)."""
-        h, cache_k, cache_v = self.attn.step(self.attn_norm(x),
-                                             cache_k, cache_v, pos)
-        x = x + h
-        return x + self.moe.decode_forward(self.ffn_norm(x)), \
-            cache_k, cache_v
+    def cached_forward(self, form, x, cache, *address, total_len=None,
+                       **kw):
+        """LlamaDecoderLayer.cached_forward with the routed FFN told
+        what the form is doing.  A prefill form (PREFILL_FORMS) budgets
+        the TRAINING capacity from the FULL prompt length
+        (prefill_forward): bounded dispatch memory at prompt scale.
+        Every other form runs capacity-unbounded (decode_forward), so
+        inactive pool slots — which still flow through a pooled step
+        with garbage activations — can never evict a live slot's token
+        from an expert.  That unbounded capacity NUMBER is a function
+        of the batch (S = B*W tokens), so a W-token verify window is
+        not guaranteed to route bit-identically to W sequential steps:
+        the serving engines opt MoE blocks OUT of speculation, linear
+        and tree windows alike; the verify forms stay reachable for
+        parity experiments and future capacity-pinned routing."""
+        from .transformer import PREFILL_FORMS
 
-    def step_slots(self, x, cache_k, cache_v, pos):
-        """Per-slot-position decode step (continuous batching): ``pos``
-        is a (B,) vector.  The routed FFN runs capacity-unbounded, so
-        inactive pool slots — which still flow through the step with
-        garbage activations — can never evict a live slot's token from
-        an expert."""
-        h, cache_k, cache_v = self.attn.step_slots(self.attn_norm(x),
-                                                   cache_k, cache_v,
-                                                   pos)
+        h, *cache = getattr(self.attn, form)(self.attn_norm(x), *cache,
+                                             *address, **kw)
         x = x + h
-        return x + self.moe.decode_forward(self.ffn_norm(x)), \
-            cache_k, cache_v
-
-    def verify_slots(self, x, cache_k, cache_v, pos, valid_len,
-                     tree=None):
-        """Speculative verification window (W candidate tokens per row;
-        see Attention.verify_slots).  The routed FFN runs
-        capacity-unbounded like step_slots — BUT the unbounded capacity
-        NUMBER is a function of the window batch (S = B*W tokens), so a
-        W-token window is not guaranteed to route bit-identically to W
-        sequential one-token steps.  The serving engines therefore opt
-        MoE blocks OUT of speculation automatically — linear AND tree
-        windows alike (the same caveat class as prefix sharing /
-        prefill bucketing); this method exists for parity experiments
-        and future capacity-pinned routing."""
-        h, cache_k, cache_v = self.attn.verify_slots(
-            self.attn_norm(x), cache_k, cache_v, pos, valid_len,
-            tree=tree)
-        x = x + h
-        return x + self.moe.decode_forward(self.ffn_norm(x)), \
-            cache_k, cache_v
-
-    def verify_pages(self, x, pool_k, pool_v, tables, pos, valid_len,
-                     tree=None):
-        """Block-paged speculative verification window (see
-        verify_slots for the MoE routing caveat — the serving engines
-        opt MoE blocks out of speculation, tree windows included)."""
-        h, pool_k, pool_v = self.attn.verify_pages(
-            self.attn_norm(x), pool_k, pool_v, tables, pos, valid_len,
-            tree=tree)
-        x = x + h
-        return x + self.moe.decode_forward(self.ffn_norm(x)), \
-            pool_k, pool_v
-
-    def prefill(self, x, cache_k, cache_v, start_pos=0, total_len=None):
-        """Chunked prompt ingestion (see Attention.prefill).  The routed
-        FFN uses the TRAINING capacity budgeted from the FULL prompt
-        length (prefill_forward): bounded dispatch memory at prompt
-        scale; only the one-token step() runs capacity-unbounded.
-        ``total_len`` defaults to start_pos + T — exact for single-chunk
-        prefill and for the FINAL chunk of a multi-chunk ingestion;
-        earlier chunks should pass the known full prompt length."""
-        h, cache_k, cache_v = self.attn.prefill(self.attn_norm(x),
-                                                cache_k, cache_v,
-                                                start_pos)
-        x = x + h
-        total = total_len if total_len is not None \
-            else start_pos + x.shape[1]
-        return x + self.moe.prefill_forward(self.ffn_norm(x),
-                                            total_len=total), \
-            cache_k, cache_v
-
-    def step_pages(self, x, pool_k, pool_v, tables, pos):
-        """Block-paged per-slot decode step (see step_slots: the routed
-        FFN runs capacity-unbounded so dead pool lanes cannot evict a
-        live slot's token from an expert)."""
-        h, pool_k, pool_v = self.attn.step_pages(self.attn_norm(x),
-                                                 pool_k, pool_v,
-                                                 tables, pos)
-        x = x + h
-        return x + self.moe.decode_forward(self.ffn_norm(x)), \
-            pool_k, pool_v
-
-    def prefill_pages(self, x, pool_k, pool_v, table, start_pos=0,
-                      total_len=None):
-        """Block-paged prompt-chunk ingestion with the TRAINING
-        capacity budgeted from the FULL prompt length — the same
-        ``total_len`` contract (and multi-chunk routing caveat,
-        docs/inference.md) as prefill().  ``total_len`` must be a
-        static int here: expert capacity is a SHAPE."""
-        h, pool_k, pool_v = self.attn.prefill_pages(self.attn_norm(x),
-                                                    pool_k, pool_v,
-                                                    table, start_pos)
-        x = x + h
-        total = total_len if total_len is not None \
-            else x.shape[1]  # start_pos may be traced; single-chunk only
-        return x + self.moe.prefill_forward(self.ffn_norm(x),
-                                            total_len=total), \
-            pool_k, pool_v
+        h = self.ffn_norm(x)
+        if form not in PREFILL_FORMS:
+            return x + self.moe.decode_forward(h), tuple(cache)
+        if total_len is None:
+            # this chunk taken as the prompt's LAST: exact for
+            # single-chunk prefill and for the final chunk; earlier
+            # chunks should pass the known full prompt length.
+            # ``prefill`` has its start as a Python int; under
+            # ``prefill_pages`` start_pos may be traced (one program
+            # serves every chunk offset) and capacity is a SHAPE, so its
+            # default holds for single-chunk ingestion only.
+            start = address[0] if form == "prefill" and address else 0
+            total_len = start + x.shape[1]
+        return x + self.moe.prefill_forward(h, total_len=total_len), \
+            tuple(cache)
 
 
 def moe_sharding_rules(base=None):

@@ -868,94 +868,39 @@ class LlamaDecoderLayer(HybridBlock):
         h = self.down_proj(F.swish(self.gate_proj(h)) * self.up_proj(h))
         return x + h
 
-    def step(self, x, cache_k, cache_v, pos):
-        """One-token decode through this layer (same math as
-        hybrid_forward with T=1 + cached attention)."""
-        h, cache_k, cache_v = self.attn.step(self.attn_norm(x),
-                                             cache_k, cache_v, pos)
+    def cached_forward(self, form, x, cache, *address, total_len=None,
+                       **kw):
+        """This layer over a cache — hybrid_forward's residual structure
+        with the sequence mixer reading and writing ``cache`` (its own
+        leaves) through ``form``, one of CACHE_FORMS, at ``address``
+        (what that form takes after the leaves).  Returns (x, new
+        leaves).  The SwiGLU FFN is per-token: no form changes it, and
+        ``total_len`` (routed layers' capacity, see MoEDecoderLayer) is
+        accepted and ignored so a model threads it uniformly."""
+        h, *cache = getattr(self.attn, form)(self.attn_norm(x), *cache,
+                                             *address, **kw)
         x = x + h
         h = self.ffn_norm(x)
         h = self.down_proj(nd.swish(self.gate_proj(h)) * self.up_proj(h))
-        return x + h, cache_k, cache_v
+        return x + h, tuple(cache)
 
-    def step_slots(self, x, cache_k, cache_v, pos):
-        """One-token decode with per-row positions (continuous
-        batching); pos is a (B,) vector — see Attention.step_slots."""
-        h, cache_k, cache_v = self.attn.step_slots(self.attn_norm(x),
-                                                   cache_k, cache_v,
-                                                   pos)
-        x = x + h
-        h = self.ffn_norm(x)
-        h = self.down_proj(nd.swish(self.gate_proj(h)) * self.up_proj(h))
-        return x + h, cache_k, cache_v
 
-    def prefill(self, x, cache_k, cache_v, start_pos=0, total_len=None):
-        """Chunked prompt ingestion through this layer (T tokens in one
-        pass; see Attention.prefill).  ``total_len`` (the full prompt
-        length) only matters for routed-FFN capacity — dense layers
-        accept and ignore it so TransformerLM.prefill can thread it
-        uniformly."""
-        h, cache_k, cache_v = self.attn.prefill(self.attn_norm(x),
-                                                cache_k, cache_v,
-                                                start_pos)
-        x = x + h
-        h = self.ffn_norm(x)
-        h = self.down_proj(nd.swish(self.gate_proj(h)) * self.up_proj(h))
-        return x + h, cache_k, cache_v
-
-    def verify_slots(self, x, cache_k, cache_v, pos, valid_len,
-                     tree=None):
-        """Speculative verification window through this layer (W
-        candidate tokens per row at per-row positions; see
-        Attention.verify_slots — ``tree`` is the draft-tree form).  The
-        FFN is per-token, so the window batch changes nothing."""
-        h, cache_k, cache_v = self.attn.verify_slots(
-            self.attn_norm(x), cache_k, cache_v, pos, valid_len,
-            tree=tree)
-        x = x + h
-        h = self.ffn_norm(x)
-        h = self.down_proj(nd.swish(self.gate_proj(h)) * self.up_proj(h))
-        return x + h, cache_k, cache_v
-
-    def verify_pages(self, x, pool_k, pool_v, tables, pos, valid_len,
-                     tree=None):
-        """Speculative verification window through the block-paged pool
-        (see Attention.verify_pages)."""
-        h, pool_k, pool_v = self.attn.verify_pages(
-            self.attn_norm(x), pool_k, pool_v, tables, pos, valid_len,
-            tree=tree)
-        x = x + h
-        h = self.ffn_norm(x)
-        h = self.down_proj(nd.swish(self.gate_proj(h)) * self.up_proj(h))
-        return x + h, pool_k, pool_v
-
-    def step_pages(self, x, pool_k, pool_v, tables, pos):
-        """One-token decode through the block-paged pool (continuous
-        batching); see Attention.step_pages."""
-        h, pool_k, pool_v = self.attn.step_pages(self.attn_norm(x),
-                                                 pool_k, pool_v,
-                                                 tables, pos)
-        x = x + h
-        h = self.ffn_norm(x)
-        h = self.down_proj(nd.swish(self.gate_proj(h)) * self.up_proj(h))
-        return x + h, pool_k, pool_v
-
-    def prefill_pages(self, x, pool_k, pool_v, table, start_pos=0,
-                      total_len=None):
-        """One prompt chunk through the block-paged pool; ``total_len``
-        accepted and ignored by dense layers (routed-FFN capacity only)
-        so TransformerLM.prefill_pages can thread it uniformly."""
-        h, pool_k, pool_v = self.attn.prefill_pages(self.attn_norm(x),
-                                                    pool_k, pool_v,
-                                                    table, start_pos)
-        x = x + h
-        h = self.ffn_norm(x)
-        h = self.down_proj(nd.swish(self.gate_proj(h)) * self.up_proj(h))
-        return x + h, pool_k, pool_v
+#: The cache forms: each is one way of ADDRESSING a sequence mixer's
+#: cache, and the mixer (MultiHeadAttention) is the only class that
+#: implements them — ``form(x, *cache_leaves, *address, **kw) -> (out,
+#: *new_leaves)``.  Layers, TransformerLM and ShardedDecoder carry the
+#: form's NAME and its address through (``cached_forward``).  The table
+#: of forms and addresses, and what a new mixer with a cache provides:
+#: docs/inference.md "What a sequence mixer provides".
+CACHE_FORMS = ("step", "step_slots", "verify_slots", "prefill",
+               "step_pages", "verify_pages", "prefill_pages")
+#: The forms that ingest prompt tokens: a routed channel mixer budgets
+#: training capacity there and runs capacity-unbounded in the others.
+PREFILL_FORMS = ("prefill", "prefill_pages")
 
 
 class TransformerLM(HybridBlock):
-    """Causal decoder LM (Llama architecture; stretch config 5).
+    """Causal decoder LM (Llama architecture).
 
     Logits head ties to the embedding when tie_weights (memory win on TPU).
     """
@@ -1025,73 +970,30 @@ class TransformerLM(HybridBlock):
             return nd.dot(x, w, transpose_b=True)
         return self.lm_head(x)
 
+    def cached_forward(self, form, token_ids, caches, *address, **kw):
+        """Every cache form is this one pass: embed → each layer over
+        its own cache leaves → logits.  ``form`` is one of CACHE_FORMS
+        and ``address`` what it addresses the cache with — the table
+        there; each form's contract is the mixer's docstring (e.g.
+        MultiHeadAttention.verify_slots: window logits bit-identical to
+        sequential steps, ``tree=`` the draft-tree form).  token_ids
+        (B, T) → (logits (B, T, V), new_caches).  Caches are
+        FUNCTIONAL: the passed-in list is not mutated — always thread
+        the returned new_caches into the next call (this is what lets
+        ShardedDecoder trace a form with dynamic positions and
+        tables).  ``total_len=`` (prefill forms): the FULL prompt
+        length, for routed layers' expert capacity."""
+        x = self.embed(token_ids)
+        new_caches = []
+        for layer, cache in zip(self.layers, caches):
+            x, cache = layer.cached_forward(form, x, cache, *address, **kw)
+            new_caches.append(cache)
+        return self._logits(x), new_caches
+
     def step(self, token_ids, caches, pos):
         """Decode ONE token per sequence: token_ids (B, 1) → (logits
-        (B, 1, V), new_caches).  Caches are FUNCTIONAL: the passed-in
-        list is not mutated — always thread the returned new_caches into
-        the next step (this is what lets ShardedDecoder trace the step
-        with a dynamic position)."""
-        x = self.embed(token_ids)
-        new_caches = []
-        for layer, (ck, cv) in zip(self.layers, caches):
-            x, ck, cv = layer.step(x, ck, cv, pos)
-            new_caches.append((ck, cv))
-        return self._logits(x), new_caches
-
-    def step_slots(self, token_ids, caches, pos):
-        """Decode ONE token per cache SLOT, each at its own position:
-        token_ids (B, 1), pos (B,) int vector → (logits (B, 1, V),
-        new_caches).  The continuous-batching step: row b writes at
-        pos[b] and attends only its own [0, pos[b]] prefix.  Same
-        functional-cache contract as step()."""
-        x = self.embed(token_ids)
-        new_caches = []
-        for layer, (ck, cv) in zip(self.layers, caches):
-            x, ck, cv = layer.step_slots(x, ck, cv, pos)
-            new_caches.append((ck, cv))
-        return self._logits(x), new_caches
-
-    def verify_slots(self, token_ids, caches, pos, valid_len, tree=None):
-        """Score a speculative window of W candidate tokens per slot in
-        ONE forward: token_ids (B, W) — row b holds its last sampled
-        token followed by up to W-1 drafted tokens, starting at cache
-        position ``pos[b]`` — → (logits (B, W, V), new_caches).  The
-        logits at window index w are bit-identical to what W sequential
-        step_slots() calls would produce at that position, which is
-        what lets the serving engine verify k drafts against ONE cache
-        read and keep per-stream output bit-exact (speculative
-        decoding).  ``valid_len`` (B,) masks each row's real window
-        extent; lanes past it (padding, inactive slots at 0) write
-        nothing.  Same functional-cache contract as step_slots().
-
-        ``tree=(perm, depth)`` scores a draft TREE instead of a chain
-        (TreeDrafter windows; see Attention.verify_slots): the logits
-        at lane w are then bit-identical to the sequential steps along
-        lane w's root-to-w ancestor path."""
-        x = self.embed(token_ids)
-        new_caches = []
-        for layer, (ck, cv) in zip(self.layers, caches):
-            x, ck, cv = layer.verify_slots(x, ck, cv, pos, valid_len,
-                                           tree=tree)
-            new_caches.append((ck, cv))
-        return self._logits(x), new_caches
-
-    def verify_pages(self, token_ids, pools, tables, pos, valid_len,
-                     tree=None):
-        """Speculative-window scoring through the block-paged pool:
-        verify_slots() with the cache traffic routed through ``tables``
-        (B, M) — see Attention.verify_pages.  Rollback on rejection is a
-        host position fix-up only: every page a window can touch was
-        allocated at admission and stays with the slot.  ``tree=(perm,
-        depth, anc)`` is the draft-tree form (anc feeds the Pallas
-        kernel's ancestor bitmask)."""
-        x = self.embed(token_ids)
-        new_pools = []
-        for layer, (pk, pv) in zip(self.layers, pools):
-            x, pk, pv = layer.verify_pages(x, pk, pv, tables, pos,
-                                           valid_len, tree=tree)
-            new_pools.append((pk, pv))
-        return self._logits(x), new_pools
+        (B, 1, V), new_caches)."""
+        return self.cached_forward("step", token_ids, caches, pos)
 
     def permute_cache_span(self, caches, pos, src_lane):
         """Post-acceptance tree fix-up over every layer's static cache:
@@ -1128,13 +1030,8 @@ class TransformerLM(HybridBlock):
         (MoE) layers ``total_len`` declares the FULL prompt length so
         expert capacity budgets from the whole prompt even when this
         call ingests only a chunk (defaults to start_pos + T)."""
-        x = self.embed(token_ids)
-        new_caches = []
-        for layer, (ck, cv) in zip(self.layers, caches):
-            x, ck, cv = layer.prefill(x, ck, cv, start_pos,
-                                      total_len=total_len)
-            new_caches.append((ck, cv))
-        return self._logits(x), new_caches
+        return self.cached_forward("prefill", token_ids, caches, start_pos,
+                                   total_len=total_len)
 
     def write_cache_slot(self, caches, slot_caches, slot, pos=0):
         """Copy one sequence's per-layer (k, v) caches (batch 1, length
@@ -1152,37 +1049,6 @@ class TransformerLM(HybridBlock):
         """Per-layer (k, v) page pools — see Attention.init_block_pool."""
         return [layer.attn.init_block_pool(num_blocks, block_size, dtype)
                 for layer in self.layers]
-
-    def step_pages(self, token_ids, pools, tables, pos):
-        """Decode ONE token per slot through the block-paged pool:
-        token_ids (B, 1), ``tables`` (B, M) int32 block tables, ``pos``
-        (B,) → (logits (B, 1, V), new_pools).  Row b writes at logical
-        position pos[b] through its table and attends only its own
-        gathered [0, pos[b]] prefix.  Same functional-cache contract as
-        step_slots()."""
-        x = self.embed(token_ids)
-        new_pools = []
-        for layer, (pk, pv) in zip(self.layers, pools):
-            x, pk, pv = layer.step_pages(x, pk, pv, tables, pos)
-            new_pools.append((pk, pv))
-        return self._logits(x), new_pools
-
-    def prefill_pages(self, token_ids, pools, table, start_pos=0,
-                      total_len=None):
-        """Ingest ONE prompt chunk (1, T) at logical positions
-        [start_pos, start_pos+T) through the block-paged pool: the
-        chunk's K/V scatter through ``table`` (M,) and its queries
-        attend the gathered extent — shared prefix pages, earlier
-        chunks, itself.  ``total_len`` declares the FULL prompt length
-        for routed (MoE) expert-capacity budgeting, exactly as
-        prefill() does."""
-        x = self.embed(token_ids)
-        new_pools = []
-        for layer, (pk, pv) in zip(self.layers, pools):
-            x, pk, pv = layer.prefill_pages(x, pk, pv, table, start_pos,
-                                            total_len=total_len)
-            new_pools.append((pk, pv))
-        return self._logits(x), new_pools
 
     def copy_block(self, pools, src, dst):
         """Copy page ``src`` onto page ``dst`` in every layer's pool —
@@ -1281,7 +1147,7 @@ def llama_tiny(vocab_size=256, mesh=None, **kwargs):
 
 def llama_3_8b(vocab_size=128256, mesh=None, width_factor=1.0,
                depth_factor=1.0, **kwargs):
-    """Llama-3-8B geometry (stretch config 5).
+    """Llama-3-8B geometry (meta-llama/Meta-Llama-3-8B config.json).
 
     width_factor/depth_factor scale the architecture down while keeping
     its shape invariants (4:1 GQA ratio, SwiGLU hidden ratio, rotary,

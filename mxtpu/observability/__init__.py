@@ -33,8 +33,8 @@ deterministic bytes):
   resilience counters, guardian counters, CompileLedger per-site
   program counts, bulk-cache stats) flattened into a single snapshot
   with ``snapshot()``/``delta()`` and Prometheus-text + JSON
-  exposition; ``tools/diagnose.py`` and ``bench.py`` collect through
-  it.
+  exposition; ``tools/diagnose.py`` and the benchmark's runners
+  collect through it.
 
 Coverage is checked statically: the ``obs_check`` analysis pass (O001,
 ``python -m mxtpu.analysis obs``) asserts every declared fault site

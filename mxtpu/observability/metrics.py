@@ -3,7 +3,7 @@
 Telemetry used to be scattered over five uncoordinated surfaces —
 engine ``stats``, ``resilience.counters()``, the CompileLedger,
 gateway/router/supervisor stats, guardian counters — that
-``tools/diagnose.py`` and ``bench.py`` each hand-stitched.  The
+``tools/diagnose.py`` hand-stitched.  The
 :class:`MetricsRegistry` is the one collection point: named SOURCES
 (callables returning nested dicts) are pulled LAZILY at
 :meth:`~MetricsRegistry.snapshot` time and flattened into a single

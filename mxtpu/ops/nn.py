@@ -10,10 +10,10 @@ general_dilated` and `jnp.dot` ARE the tuned kernels.
 Layout: the MXNet API default NCHW is preserved at the op boundary, but 2-D
 convolutions run NHWC INTERNALLY (transpose in/out; XLA's algebraic
 simplifier cancels the transpose pairs between consecutive convs).
-Measured on a real v5e (tools/profile_resnet.py, ResNet-50 fwd+bwd+SGD,
-batch 128 bf16): NCHW end-to-end 13.2% MFU, NHWC-internal 16.9% — the
-round-2 docstring's claim that XLA re-lays out NCHW for free was wrong on
-TPU.  The remaining gap to peak is HBM bandwidth, not layout: the profiler
+Measured on a real v5e in round 2 (a raw-JAX ResNet-50 fwd+bwd+SGD
+profile, batch 128 bf16; no ledger cell guards it — ROADMAP W6): NCHW
+end-to-end 13.2% MFU, NHWC-internal 16.9% — the round-2 docstring's
+claim that XLA re-lays out NCHW for free was wrong on TPU.  The remaining gap to peak is HBM bandwidth, not layout: the profiler
 trace shows conv fusions at ~754 GB/s (~92% of v5e's 819 GB/s) with conv
 weight-gradients alone moving 14 GB/step — ResNet-50's arithmetic
 intensity (~140 flops/byte fwd+bwd) sits below the v5e ridge point
@@ -47,19 +47,6 @@ def fully_connected(x, weight, bias=None, num_hidden=0, no_bias=False,
     return y
 
 
-def _pallas_conv_bwd_active(ndim, kernel, stride, dilate, pad, num_group,
-                            x, weight):
-    """Flag-gated fused Pallas conv backward (see pallas/conv_bwd.py);
-    OFF by default pending on-chip measurement."""
-    try:
-        from .pallas import conv_bwd
-    except Exception:  # pallas unavailable on this jax
-        return False
-    return conv_bwd.enabled() and conv_bwd.eligible(
-        ndim, kernel, stride, dilate, pad, num_group,
-        in_shape=tuple(x.shape), num_filter=int(weight.shape[0]))
-
-
 def _conv_dn(ndim, layout):
     if ndim == 1:
         return ("NCW", "OIW", "NCW")
@@ -89,20 +76,15 @@ def convolution(x, weight, bias=None, kernel=(), stride=(), dilate=(),
     if ndim == 2 and layout == "NCHW":
         x_nhwc = jnp.transpose(x, (0, 2, 3, 1))
         w_hwio = jnp.transpose(weight, (2, 3, 1, 0))  # OIHW -> HWIO
-        if _pallas_conv_bwd_active(ndim, kernel, stride, dilate, pad,
-                                   num_group, x, weight):  # trace-ok: shape/env decision
-            from .pallas import conv_bwd
-            y = conv_bwd.conv3x3_s1(x_nhwc, w_hwio)
-        else:
-            y = lax.conv_general_dilated(
-                x_nhwc, w_hwio,
-                window_strides=stride,
-                padding=[(p, p) for p in pad],
-                rhs_dilation=dilate,
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                feature_group_count=num_group,
-                precision=matmul_precision(x, weight),
-            )
+        y = lax.conv_general_dilated(
+            x_nhwc, w_hwio,
+            window_strides=stride,
+            padding=[(p, p) for p in pad],
+            rhs_dilation=dilate,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            feature_group_count=num_group,
+            precision=matmul_precision(x, weight),
+        )
         if bias is not None and not no_bias:
             y = y + bias
         return jnp.transpose(y, (0, 3, 1, 2))
@@ -365,7 +347,7 @@ def batch_norm(x, gamma, beta, moving_mean, moving_var, eps=1e-5,
         # Two-pass batch stats: the fp32 casts fuse into the reduces
         # (convert_reduce_fusion on TPU) so the activation is never
         # materialized in fp32 — measured vs the round-2 whole-activation
-        # fp32 cast on a real v5e (tools/profile_resnet.py).  The centered
+        # fp32 cast on a real v5e (round 2, no ledger cell).  The centered
         # second pass avoids E[x^2]-E[x]^2 catastrophic cancellation for
         # large-mean channels.
         xf = x.astype(jnp.float32)

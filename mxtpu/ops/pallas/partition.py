@@ -9,7 +9,7 @@ builder opens a scope around its traced body and the kernels read it at
 trace time.
 
 The paged-attention / paged-prefill pallas_calls are traced deep inside
-``TransformerLM.step_pages``-family bodies, but the information needed
+the mixer's ``step_pages``-family cache forms, but the information needed
 to partition them — the device mesh and which mesh axes shard the
 KV-heads axis of the paged cache (``cache_spec[1]``, ``"tp"`` by
 default) — lives on the ``ShardedDecoder`` that builds the jitted

@@ -1,7 +1,7 @@
 """Chunked-prefill (flash-prefill) attention kernel in Pallas (TPU).
 
 The paged engines prefill prompts in pow2 chunks
-(``TransformerLM.prefill_pages``): the chunk's K/V rows are written into
+(the ``prefill_pages`` cache form): the chunk's K/V rows are written into
 the block pool, then the XLA path GATHERS every table entry back out —
 a full-K/V materialization whose residency the K003 pricer measured at
 ~2 MiB per (slot, kv-head) row at T=2048.  This kernel walks the slot's

@@ -20,8 +20,9 @@ are drop-in interchangeable and testable against each other.
 
 from __future__ import annotations
 
+import functools
 import re
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +116,137 @@ def resolve_cache_dtype(cache_dtype):
     if cache_dtype is not None:
         return cache_dtype
     return os.environ.get("MXTPU_CACHE_DTYPE", "float32")
+
+
+# -- the program kinds ------------------------------------------------------
+# Every compiled serving program is one row of PROGRAMS: what it traces
+# (``body(block, caches, *extras) -> (logits, new_caches)``) and what of
+# its call enters the jit-cache key.  ShardedDecoder._run is the one
+# path from a kind's name to its program; the engines call it by name.
+
+def _form_body(form, n_tree=0):
+    """Body of a kind that IS one cache form of the block (models.
+    transformer.CACHE_FORMS): the inputs are the tokens, then the
+    form's address, every one traced — ONE compiled program serves
+    every position, table content and window.  The last ``n_tree``
+    inputs travel as the form's ``tree=`` (a draft TREE in window-lane
+    order, lane 0 = root: each lane's root-to-self ancestor chain perm/
+    depth, and for the paged kernel the (B, W) int32 strict-ancestor
+    bitmask anc it reads via scalar prefetch).  A degenerate chain
+    (perm[b, w, i] = min(i, w), depth[b, w] = w) reproduces the linear
+    verify bit for bit, which is how mixed linear/tree pools share the
+    tree program."""
+    def body(block, caches, tokens, *address):
+        address = [NDArray(a) for a in address]
+        kw = {}
+        if n_tree:
+            kw["tree"] = tuple(address[-n_tree:])
+            del address[-n_tree:]
+        return block.cached_forward(form, NDArray(tokens), caches,
+                                    *address, **kw)
+    return body
+
+
+def _slot_prefill_body(block, caches, tokens, slot):
+    """Compiled slot prefill: run the (1, Tb) prompt through the
+    block's chunked prefill against a FRESH batch-1 scratch cache
+    of length Tb, then write the scratch K/V into pool row ``slot``
+    (a traced scalar — one program per bucket serves every slot).
+    The scratch cache is an in-program constant; XLA fuses the
+    zero-init away."""
+    tokens = NDArray(tokens)
+    ck0 = caches[0][0]
+    dt = "int8" if isinstance(ck0, tuple) else str(ck0.dtype)
+    scratch = block.init_cache(1, tokens.shape[1], dt)
+    logits, scratch = block.prefill(tokens, scratch)
+    return logits, block.write_cache_slot(caches, scratch,
+                                          NDArray(slot))
+
+
+def _page_prefill_body(block, caches, tokens, table, start_pos, cow_src,
+                       cow_dst, total_len=None):
+    """Compiled paged chunk-prefill: an optional copy-on-write of
+    one page (``cow_src`` → ``cow_dst``; equal scalars are a
+    bit-exact no-op, so the COW and no-COW admissions share ONE
+    program), then one (1, Tb) chunk scattered/attended through the
+    traced block ``table`` at traced ``start_pos``.  ``total_len``
+    is STATIC (None for dense blocks; the full prompt length for
+    MoE expert-capacity budgeting — capacity is a shape)."""
+    caches = block.copy_block(caches, NDArray(cow_src),
+                              NDArray(cow_dst))
+    return block.cached_forward("prefill_pages", NDArray(tokens), caches,
+                                NDArray(table), NDArray(start_pos),
+                                total_len=total_len)
+
+
+def _fixup_slots_body(block, caches, pos, src_lane):
+    """Post-acceptance cache fix-up (tree verify rollback): rewrite
+    rows pos[b]+j from the accepted path's window lanes (``src_lane``
+    (B, W), -1 beyond the accepted count) so the surviving K/V land in
+    SEQUENTIAL arrangement — a host position fix-up expressed as one
+    in-place gather/scatter, never an allocator op.  src_lane[b, j] >= j
+    always (parents precede children in lane order), so the
+    gather-before-scatter inside the op reads pre-permute rows."""
+    return NDArray(pos), block.permute_cache_span(
+        caches, NDArray(pos), NDArray(src_lane))
+
+
+def _fixup_pages_body(block, caches, tables, pos, src_lane):
+    """Paged twin of _fixup_slots_body: the same span permute
+    routed through the block tables (out-of-range destinations fall
+    on the reserved null page 0)."""
+    return NDArray(pos), block.permute_pool_span(
+        caches, NDArray(tables), NDArray(pos), NDArray(src_lane))
+
+
+class _Program(NamedTuple):
+    """One row of PROGRAMS."""
+    body: Callable
+    #: the mixer's cache form the body drives; None for the fix-ups,
+    #: which are operations on cache leaves, not forms
+    form: Optional[str] = None
+    #: index (among the extras) of the block tables, whose shape enters
+    #: the key
+    tables: Optional[int] = None
+    #: names of keyword inputs that are closed over, not traced, and
+    #: enter the key by value
+    static: Tuple[str, ...] = ()
+    #: a kernel choice baked at trace time: its verdict enters the key
+    gate: Optional[Callable[[], bool]] = None
+    #: a fix-up: no tokens come in (the LAST input, src_lane, leads
+    #: the key in their place) and no logits come out (_run returns the
+    #: caches alone)
+    caches_only: bool = False
+
+
+#: kind -> row.  The verify kinds' window width W (tokens (B, W)) comes
+#: from the engines' power-of-two ladder, and a tree's perm/depth/anc
+#: shapes are functions of (B, W): each verify site compiles at most
+#: |ladder| programs — the bounded family the compile discipline allows
+#: (C004, never C001) — shared by every tree SHAPE in a bucket.  The
+#: fix-ups compile one program per (pool shape, W).
+PROGRAMS = {
+    "step": _Program(_form_body("step"), "step"),
+    "prefill": _Program(_form_body("prefill"), "prefill"),
+    "step_slots": _Program(_form_body("step_slots"), "step_slots"),
+    "slot_prefill": _Program(_slot_prefill_body, "prefill"),
+    "verify_slots": _Program(_form_body("verify_slots"), "verify_slots"),
+    "verify_tree_slots": _Program(_form_body("verify_slots", n_tree=2),
+                                  "verify_slots"),
+    "fixup_slots": _Program(_fixup_slots_body, caches_only=True),
+    "step_pages": _Program(_form_body("step_pages"), "step_pages",
+                           tables=1, gate=_paged_attn_gate),
+    "page_prefill": _Program(_page_prefill_body, "prefill_pages",
+                             tables=1, static=("total_len",),
+                             gate=_paged_prefill_gate),
+    "verify_pages": _Program(_form_body("verify_pages"), "verify_pages",
+                             tables=1, gate=_paged_attn_gate),
+    "verify_tree_pages": _Program(_form_body("verify_pages", n_tree=3),
+                                  "verify_pages", tables=1,
+                                  gate=_paged_attn_gate),
+    "fixup_pages": _Program(_fixup_pages_body, tables=0,
+                            caches_only=True),
+}
 
 
 class ShardedDecoder:
@@ -379,127 +511,6 @@ class ShardedDecoder:
         return jax.jit(program, in_shardings=in_sh,
                        out_shardings=(rep, cache_sh), donate_argnums=(1,))
 
-    @staticmethod
-    def _step_body(block, caches, token, pos):
-        return block.step(NDArray(token), caches, NDArray(pos))
-
-    @staticmethod
-    def _prefill_body(block, caches, tokens):
-        return block.prefill(NDArray(tokens), caches)
-
-    @staticmethod
-    def _step_slots_body(block, caches, token, pos):
-        """Pool decode step: pos is a (B,) vector — every slot at its
-        own position, one compiled program for all combinations."""
-        return block.step_slots(NDArray(token), caches, NDArray(pos))
-
-    @staticmethod
-    def _slot_prefill_body(block, caches, tokens, slot):
-        """Compiled slot prefill: run the (1, Tb) prompt through the
-        block's chunked prefill against a FRESH batch-1 scratch cache
-        of length Tb, then write the scratch K/V into pool row ``slot``
-        (a traced scalar — one program per bucket serves every slot).
-        The scratch cache is an in-program constant; XLA fuses the
-        zero-init away."""
-        tokens = NDArray(tokens)
-        ck0 = caches[0][0]
-        dt = "int8" if isinstance(ck0, tuple) else str(ck0.dtype)
-        scratch = block.init_cache(1, tokens.shape[1], dt)
-        logits, scratch = block.prefill(tokens, scratch)
-        return logits, block.write_cache_slot(caches, scratch,
-                                              NDArray(slot))
-
-    @staticmethod
-    def _verify_slots_body(block, caches, tokens, pos, valid_len):
-        """Pooled speculative verification: ``tokens`` (B, W) is each
-        row's candidate window (last sampled token + drafts) at traced
-        per-row start positions — ONE compiled program per window-size
-        bucket scores every draft position against the cache in one
-        read (see TransformerLM.verify_slots)."""
-        return block.verify_slots(NDArray(tokens), caches, NDArray(pos),
-                                  NDArray(valid_len))
-
-    @staticmethod
-    def _verify_pages_body(block, caches, tokens, tables, pos,
-                           valid_len):
-        """Block-paged speculative verification (traced tables +
-        per-row positions; see TransformerLM.verify_pages)."""
-        return block.verify_pages(NDArray(tokens), caches,
-                                  NDArray(tables), NDArray(pos),
-                                  NDArray(valid_len))
-
-    @staticmethod
-    def _verify_tree_slots_body(block, caches, tokens, pos, valid_len,
-                                perm, depth):
-        """Tree-speculative verification over the slot pool: ``tokens``
-        (B, W) holds a draft TREE in window-lane order (lane 0 = root)
-        and ``perm``/``depth`` carry each lane's root-to-self ancestor
-        chain — one pooled cache read scores every branch (see
-        MultiHeadAttention.verify_slots).  A degenerate chain
-        (perm[b, w, i] = min(i, w), depth[b, w] = w) reproduces the
-        linear verify bit for bit, which is how mixed linear/tree pools
-        share this program."""
-        return block.verify_slots(NDArray(tokens), caches, NDArray(pos),
-                                  NDArray(valid_len),
-                                  tree=(NDArray(perm), NDArray(depth)))
-
-    @staticmethod
-    def _verify_tree_pages_body(block, caches, tokens, tables, pos,
-                                valid_len, perm, depth, anc):
-        """Block-paged tree verification: ``anc`` additionally carries
-        the (B, W) int32 strict-ancestor bitmask the Pallas kernel's
-        tree mask reads via scalar prefetch (see
-        ops/pallas/paged_attention.py)."""
-        return block.verify_pages(NDArray(tokens), caches,
-                                  NDArray(tables), NDArray(pos),
-                                  NDArray(valid_len),
-                                  tree=(NDArray(perm), NDArray(depth),
-                                        NDArray(anc)))
-
-    @staticmethod
-    def _fixup_slots_body(block, caches, pos, src_lane):
-        """Post-acceptance cache fix-up: rewrite rows pos[b]+j from the
-        accepted path's window lanes (``src_lane`` (B, W), -1 beyond
-        the accepted count) so the surviving K/V land in SEQUENTIAL
-        arrangement — a host position fix-up expressed as one in-place
-        gather/scatter, never an allocator op.  src_lane[b, j] >= j
-        always (parents precede children in lane order), so the
-        gather-before-scatter inside the op reads pre-permute rows."""
-        return NDArray(pos), block.permute_cache_span(
-            caches, NDArray(pos), NDArray(src_lane))
-
-    @staticmethod
-    def _fixup_pages_body(block, caches, tables, pos, src_lane):
-        """Paged twin of _fixup_slots_body: the same span permute
-        routed through the block tables (out-of-range destinations fall
-        on the reserved null page 0)."""
-        return NDArray(pos), block.permute_pool_span(
-            caches, NDArray(tables), NDArray(pos), NDArray(src_lane))
-
-    @staticmethod
-    def _step_pages_body(block, caches, token, tables, pos):
-        """Block-paged pool decode step: ``tables`` (B, M) block tables
-        and ``pos`` (B,) positions are both traced — ONE compiled
-        program serves every table content and position combination."""
-        return block.step_pages(NDArray(token), caches, NDArray(tables),
-                                NDArray(pos))
-
-    @staticmethod
-    def _page_prefill_body(total_len, block, caches, tokens, table,
-                           start_pos, cow_src, cow_dst):
-        """Compiled paged chunk-prefill: an optional copy-on-write of
-        one page (``cow_src`` → ``cow_dst``; equal scalars are a
-        bit-exact no-op, so the COW and no-COW admissions share ONE
-        program), then one (1, Tb) chunk scattered/attended through the
-        traced block ``table`` at traced ``start_pos``.  ``total_len``
-        is STATIC (None for dense blocks; the full prompt length for
-        MoE expert-capacity budgeting — capacity is a shape)."""
-        caches = block.copy_block(caches, NDArray(cow_src),
-                                  NDArray(cow_dst))
-        return block.prefill_pages(NDArray(tokens), caches,
-                                   NDArray(table), NDArray(start_pos),
-                                   total_len=total_len)
-
     def _build_swap_program(self, cache_template):
         """ONE bounded copy program for the hierarchical cache's
         device↔host page moves (docs/inference.md): reads page ``bid``
@@ -569,195 +580,36 @@ class ShardedDecoder:
             weak=(),
             static=(kind,)), hit=hit)
 
-    def _step_jitted(self, cache_leaves, token, pos):
-        key = ("step", _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), token.shape, token.dtype)
+    def _run(self, kind, cache_leaves, *extras, **static):
+        """Run one program of PROGRAMS (by name) over ``cache_leaves``:
+        key → compile-ledger report → build on a miss → call with the
+        live parameter leaves.  ``extras`` are the kind's replicated
+        inputs in its body's order; ``static`` (``total_len``) is
+        closed over and keyed, not traced.  Returns (logits,
+        new_cache_leaves), or the new leaves alone for a kind that
+        computes no logits."""
+        row = PROGRAMS[kind]
+        # the input the ledger reports and whose shape — and dtype, for
+        # tokens — leads the key
+        lead = extras[-1] if row.caches_only else extras[0]
+        key = (kind, _cache_shapes(cache_leaves), _cache_dt(cache_leaves),
+               lead.shape)
+        if not row.caches_only:
+            key += (lead.dtype,)
+        if row.tables is not None:
+            key += (extras[row.tables].shape,)
+        key += tuple(static.get(name) for name in row.static)
+        if row.gate is not None:
+            key += (row.gate(),)
         hit = key in self._jit_cache
-        self._ledger_report("step", cache_leaves, (token,), hit)
+        self._ledger_report(kind, cache_leaves, (lead,), hit)
         if not hit:
             self._jit_cache[key] = self._build_program(
-                self._step_body, cache_leaves, n_extra_inputs=2)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, token, pos)
-
-    def _prefill_jitted(self, cache_leaves, tokens):
-        key = ("prefill", _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), tokens.shape, tokens.dtype)
-        hit = key in self._jit_cache
-        self._ledger_report("prefill", cache_leaves, (tokens,), hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._prefill_body, cache_leaves, n_extra_inputs=1)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, tokens)
-
-    def _step_slots_jitted(self, cache_leaves, token, pos):
-        key = ("step_slots", _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), token.shape, token.dtype)
-        hit = key in self._jit_cache
-        self._ledger_report("step_slots", cache_leaves, (token,), hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._step_slots_body, cache_leaves,
-                n_extra_inputs=2)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, token, pos)
-
-    def _slot_prefill_jitted(self, cache_leaves, tokens, slot):
-        key = ("slot_prefill",
-               _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), tokens.shape, tokens.dtype)
-        hit = key in self._jit_cache
-        self._ledger_report("slot_prefill", cache_leaves, (tokens,), hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._slot_prefill_body, cache_leaves,
-                n_extra_inputs=2)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, tokens,
-                                    slot)
-
-    def _verify_slots_jitted(self, cache_leaves, tokens, pos, valid_len):
-        """Speculative verify step over the slot pool: the window width
-        W in ``tokens`` (B, W) comes from the engine's power-of-two
-        ladder, so this site compiles at most |ladder| programs — the
-        bounded family the compile discipline allows (C004, never
-        C001)."""
-        key = ("verify_slots",
-               _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), tokens.shape, tokens.dtype)
-        hit = key in self._jit_cache
-        self._ledger_report("verify_slots", cache_leaves, (tokens,), hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._verify_slots_body, cache_leaves,
-                n_extra_inputs=3)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, tokens,
-                                    pos, valid_len)
-
-    def _verify_pages_jitted(self, cache_leaves, tokens, tables, pos,
-                             valid_len):
-        """Block-paged speculative verify step (same bounded
-        window-ladder family as _verify_slots_jitted)."""
-        key = ("verify_pages",
-               _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), tokens.shape, tokens.dtype,
-               tables.shape, _paged_attn_gate())
-        hit = key in self._jit_cache
-        self._ledger_report("verify_pages", cache_leaves, (tokens,), hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._verify_pages_body, cache_leaves,
-                n_extra_inputs=4)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, tokens,
-                                    tables, pos, valid_len)
-
-    def _verify_tree_slots_jitted(self, cache_leaves, tokens, pos,
-                                  valid_len, perm, depth):
-        """Tree verify over the slot pool: W rides the same power-of-two
-        node ladder as the linear verify, and perm/depth shapes are
-        functions of (B, W) — so this site compiles at most |ladder|
-        programs (the compile_budget bound), shared by every tree SHAPE
-        in the bucket including degenerate linear chains."""
-        key = ("verify_tree_slots",
-               _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), tokens.shape, tokens.dtype)
-        hit = key in self._jit_cache
-        self._ledger_report("verify_tree_slots", cache_leaves, (tokens,),
-                            hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._verify_tree_slots_body, cache_leaves,
-                n_extra_inputs=5)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, tokens,
-                                    pos, valid_len, perm, depth)
-
-    def _verify_tree_pages_jitted(self, cache_leaves, tokens, tables,
-                                  pos, valid_len, perm, depth, anc):
-        """Block-paged tree verify (same bounded window-ladder family
-        as _verify_tree_slots_jitted)."""
-        key = ("verify_tree_pages",
-               _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), tokens.shape, tokens.dtype,
-               tables.shape, _paged_attn_gate())
-        hit = key in self._jit_cache
-        self._ledger_report("verify_tree_pages", cache_leaves, (tokens,),
-                            hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._verify_tree_pages_body, cache_leaves,
-                n_extra_inputs=7)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, tokens,
-                                    tables, pos, valid_len, perm, depth,
-                                    anc)
-
-    def _fixup_slots_jitted(self, cache_leaves, pos, src_lane):
-        """Accepted-path cache permute over the slot pool (tree verify
-        rollback; one program per (pool shape, W) pair)."""
-        key = ("fixup_slots", _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), src_lane.shape)
-        hit = key in self._jit_cache
-        self._ledger_report("fixup_slots", cache_leaves, (src_lane,),
-                            hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._fixup_slots_body, cache_leaves, n_extra_inputs=2)
-        param_leaves = self._live_param_leaves()
-        _, caches = self._jit_cache[key](param_leaves, cache_leaves,
-                                         pos, src_lane)
-        return caches
-
-    def _fixup_pages_jitted(self, cache_leaves, tables, pos, src_lane):
-        """Paged accepted-path cache permute (see _fixup_slots_jitted)."""
-        key = ("fixup_pages", _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), src_lane.shape, tables.shape)
-        hit = key in self._jit_cache
-        self._ledger_report("fixup_pages", cache_leaves, (src_lane,),
-                            hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._fixup_pages_body, cache_leaves, n_extra_inputs=3)
-        param_leaves = self._live_param_leaves()
-        _, caches = self._jit_cache[key](param_leaves, cache_leaves,
-                                         tables, pos, src_lane)
-        return caches
-
-    def _step_pages_jitted(self, cache_leaves, token, tables, pos):
-        key = ("step_pages", _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), token.shape, token.dtype,
-               tables.shape, _paged_attn_gate())
-        hit = key in self._jit_cache
-        self._ledger_report("step_pages", cache_leaves, (token,), hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                self._step_pages_body, cache_leaves,
-                n_extra_inputs=3)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, token,
-                                    tables, pos)
-
-    def _page_prefill_jitted(self, cache_leaves, tokens, table,
-                             start_pos, cow_src, cow_dst,
-                             total_len=None):
-        import functools
-
-        key = ("page_prefill",
-               _cache_shapes(cache_leaves),
-               _cache_dt(cache_leaves), tokens.shape, tokens.dtype,
-               table.shape, total_len, _paged_prefill_gate())
-        hit = key in self._jit_cache
-        self._ledger_report("page_prefill", cache_leaves, (tokens,), hit)
-        if not hit:
-            self._jit_cache[key] = self._build_program(
-                functools.partial(self._page_prefill_body, total_len),
-                cache_leaves, n_extra_inputs=5)
-        param_leaves = self._live_param_leaves()
-        return self._jit_cache[key](param_leaves, cache_leaves, tokens,
-                                    table, start_pos, cow_src, cow_dst)
+                functools.partial(row.body, **static), cache_leaves,
+                n_extra_inputs=len(extras))
+        out = self._jit_cache[key](self._live_param_leaves(), cache_leaves,
+                                   *extras)
+        return out[1] if row.caches_only else out
 
     def _ensure_staged(self, sample_ids):
         """Resolve deferred parameter shapes (one imperative forward if
@@ -822,7 +674,7 @@ class ShardedDecoder:
             Tb = min(_bucket(Tp), max_length)
             if Tb > Tp:
                 raw = jnp.pad(raw, ((0, 0), (0, Tb - Tp)))
-        logits, cache_leaves = self._prefill_jitted(cache_leaves, raw)
+        logits, cache_leaves = self._run("prefill", cache_leaves, raw)
         logits = logits[:, :Tp]  # padded-query logits are garbage
         if seed is not None and temperature and temperature > 0.0:
             # after prefill: deferred init / staging must not shift the
@@ -855,7 +707,7 @@ class ShardedDecoder:
             if penalized:
                 seen = seen.at[jnp.arange(B), nxt[:, 0]].set(True)
             if pos < total - 1:
-                logits, cache_leaves = self._step_jitted(
-                    cache_leaves, nxt, jnp.int32(pos))
+                logits, cache_leaves = self._run(
+                    "step", cache_leaves, nxt, jnp.int32(pos))
         out = jnp.concatenate([t._data for t in tokens], axis=1)
         return NDArray(out)

@@ -12,7 +12,7 @@ discipline TPUs want):
   sharded KV cache (allocated once, donated between steps, never
   reallocated per request);
 - per-slot ``pos``/active state threaded through a single compiled
-  per-row-position decode step (``TransformerLM.step_slots``): finished
+  per-row-position decode step (cache form ``step_slots``): finished
   sequences free their row MID-FLIGHT and queued requests join at the
   next iteration boundary;
 - admission via a compiled SLOT PREFILL: the prompt is right-padded to
@@ -40,7 +40,7 @@ in ONE compiled call is a direct tokens/s multiplier.  A host-side
 n-gram / prompt-lookup drafter (``models.sampler.NGramDrafter`` — no
 extra weights, no extra HBM) proposes up to ``spec_k`` tokens per slot
 from the request's own prompt+output history; one pooled
-``TransformerLM.verify_slots`` / ``verify_pages`` program scores every
+``verify_slots`` / ``verify_pages`` program scores every
 row's window in one cache read and the engine accepts the longest
 prefix whose candidates equal what sequential decode would have
 emitted.  Parity is preserved EXACTLY: the emitted token at each
@@ -277,8 +277,8 @@ class ContinuousBatchingEngine:
 
     Parameters
     ----------
-    block : TransformerLM-like block (init_cache / prefill / step_slots /
-        write_cache_slot).
+    block : TransformerLM-like block (init_cache / prefill /
+        cached_forward / write_cache_slot).
     mesh / rules / cache_spec : as ShardedDecoder — training shardings
         are consumed in place, caches live on-mesh over the kv-head axis.
     num_slots : pool size B (the compiled step's batch dimension).
@@ -789,8 +789,8 @@ class ContinuousBatchingEngine:
             Tb = min(_bucket(Tp), self._max_length)
             if Tb > Tp:
                 raw = jnp.pad(raw, ((0, 0), (0, Tb - Tp)))
-        logits, self._pool = self._dec._slot_prefill_jitted(
-            self._pool, raw, jnp.int32(slot_idx))
+        logits, self._pool = self._dec._run(
+            "slot_prefill", self._pool, raw, jnp.int32(slot_idx))
         self._prefill_tokens += Tp
         last = logits[:, Tp - 1]                       # (1, V)
         keys = None
@@ -866,8 +866,8 @@ class ContinuousBatchingEngine:
             Tb = min(_bucket(Tp), self._max_length)
             if Tb > Tp:
                 raw = jnp.pad(raw, ((0, 0), (0, Tb - Tp)))
-        _, self._draft_pool = self._draft_dec._slot_prefill_jitted(
-            self._draft_pool, raw, jnp.int32(row))
+        _, self._draft_pool = self._draft_dec._run(
+            "slot_prefill", self._draft_pool, raw, jnp.int32(row))
 
     def _spec_extent(self, slot):
         """Hard cache extent of one slot in positions — drafted windows
@@ -990,8 +990,8 @@ class ContinuousBatchingEngine:
         # non-drafting rows flow through with garbage (fixed shapes);
         # their draft rows are dead and absorb the writes
         for w in range(j + 1):
-            logits, self._draft_pool = self._draft_dec._step_slots_jitted(
-                self._draft_pool, tok, jnp.asarray(pos + w))
+            logits, self._draft_pool = self._draft_dec._run(
+                "step_slots", self._draft_pool, tok, jnp.asarray(pos + w))
             if w < j:
                 nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
                 proposals.append(nxt)
@@ -1016,14 +1016,14 @@ class ContinuousBatchingEngine:
         return pos
 
     def _run_step(self, state):
-        logits, self._pool = self._dec._step_slots_jitted(
-            self._pool, self._last_tokens.reshape(-1, 1),
+        logits, self._pool = self._dec._run(
+            "step_slots", self._pool, self._last_tokens.reshape(-1, 1),
             jnp.asarray(state))
         return logits
 
     def _run_verify(self, state, window, valid_len):
-        logits, self._pool = self._dec._verify_slots_jitted(
-            self._pool, window, jnp.asarray(state),
+        logits, self._pool = self._dec._run(
+            "verify_slots", self._pool, window, jnp.asarray(state),
             jnp.asarray(valid_len))
         return logits
 
@@ -1341,15 +1341,16 @@ class ContinuousBatchingEngine:
 
     def _run_verify_tree(self, state, window, valid_len, perm, depth,
                          anc):
-        logits, self._pool = self._dec._verify_tree_slots_jitted(
-            self._pool, window, jnp.asarray(state),
+        logits, self._pool = self._dec._run(
+            "verify_tree_slots", self._pool, window, jnp.asarray(state),
             jnp.asarray(valid_len), jnp.asarray(perm),
             jnp.asarray(depth))
         return logits
 
     def _run_fixup(self, state, src_lane):
-        self._pool = self._dec._fixup_slots_jitted(
-            self._pool, jnp.asarray(state), jnp.asarray(src_lane))
+        self._pool = self._dec._run(
+            "fixup_slots", self._pool, jnp.asarray(state),
+            jnp.asarray(src_lane))
 
     def _sample_window_tree(self, logits, active, window, W, perm,
                             depth, sample_next_token):
@@ -1874,7 +1875,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     - **Paged pool** — per-layer (num_blocks+1, KV, block_size, D)
       caches (page 0 reserved as the null page that absorbs dead-lane
       writes).  Each slot holds a padded int32 block table threaded
-      through the compiled step; ``TransformerLM.step_pages`` /
+      through the compiled step; the cache forms ``step_pages`` /
       ``prefill_pages`` gather/scatter through the table, reproducing
       the contiguous cache bit-for-bit.  A request holds
       ceil(need/block_size) pages instead of max_length positions.
@@ -2580,8 +2581,9 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         src, dst = slot.cow if slot.cow is not None else (0, 0)
         slot.cow = None                      # COW runs exactly once
         moe = self._dec._block_has_moe()
-        logits, self._pool = self._dec._page_prefill_jitted(
-            self._pool, raw, jnp.asarray(self._table_row(slot_idx)),
+        logits, self._pool = self._dec._run(
+            "page_prefill", self._pool, raw,
+            jnp.asarray(self._table_row(slot_idx)),
             jnp.int32(start), jnp.int32(src), jnp.int32(dst),
             total_len=(slot.Tp if moe else None))
         self._prefill_tokens += Tact
@@ -2647,31 +2649,31 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     def _run_step(self, state):
         pos, tables = state
-        logits, self._pool = self._dec._step_pages_jitted(
-            self._pool, self._last_tokens.reshape(-1, 1),
+        logits, self._pool = self._dec._run(
+            "step_pages", self._pool, self._last_tokens.reshape(-1, 1),
             jnp.asarray(tables), jnp.asarray(pos))
         return logits
 
     def _run_verify(self, state, window, valid_len):
         pos, tables = state
-        logits, self._pool = self._dec._verify_pages_jitted(
-            self._pool, window, jnp.asarray(tables), jnp.asarray(pos),
-            jnp.asarray(valid_len))
+        logits, self._pool = self._dec._run(
+            "verify_pages", self._pool, window, jnp.asarray(tables),
+            jnp.asarray(pos), jnp.asarray(valid_len))
         return logits
 
     def _run_verify_tree(self, state, window, valid_len, perm, depth,
                          anc):
         pos, tables = state
-        logits, self._pool = self._dec._verify_tree_pages_jitted(
-            self._pool, window, jnp.asarray(tables), jnp.asarray(pos),
-            jnp.asarray(valid_len), jnp.asarray(perm),
+        logits, self._pool = self._dec._run(
+            "verify_tree_pages", self._pool, window, jnp.asarray(tables),
+            jnp.asarray(pos), jnp.asarray(valid_len), jnp.asarray(perm),
             jnp.asarray(depth), jnp.asarray(anc))
         return logits
 
     def _run_fixup(self, state, src_lane):
         pos, tables = state
-        self._pool = self._dec._fixup_pages_jitted(
-            self._pool, jnp.asarray(tables), jnp.asarray(pos),
+        self._pool = self._dec._run(
+            "fixup_pages", self._pool, jnp.asarray(tables), jnp.asarray(pos),
             jnp.asarray(src_lane))
 
     # -- one scheduler iteration ----------------------------------------

@@ -18,7 +18,7 @@ __all__ = ["Feature", "feature_list", "Features", "enable_compile_cache"]
 
 def enable_compile_cache():
     """Turn on JAX's persistent compilation cache and return its
-    directory.  For entry points only (chip_smoke.py, bench.py, the
+    directory.  For entry points only (chip_smoke.py, chipbench, the
     serving worker, tools/) — never at ``import mxtpu`` and never from
     tests.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
     itself and the cache is there and nowhere else; otherwise it is

@@ -59,16 +59,46 @@ def test_dataset_shard_take_filter():
     assert len(ds.filter(lambda x: x % 2 == 0)) == 5
 
 
-def test_multi_worker():
-    ds = ArrayDataset(np.arange(64).astype("float32").reshape(16, 4),
-                      np.arange(16))
-    for workers in (0, 2):
-        loader = DataLoader(ds, 4, num_workers=workers)
-        seen = []
-        for x, y in loader:
-            assert x.shape == (4, 4)
-            seen.extend(y.asnumpy().tolist())
-        assert sorted(seen) == list(range(16))
+class SlowDataset(Dataset):
+    """CPU-bound per-item work; module-level so forkserver/spawn workers
+    can pickle it."""
+
+    def __len__(self):
+        return 64
+
+    def __getitem__(self, idx):
+        a = np.random.RandomState(idx).rand(64, 64)
+        for _ in range(5):
+            a = a @ a.T
+            a /= np.abs(a).max()
+        return a.astype("float32"), np.float32(idx % 10)
+
+
+def _assert_same_batches(got, ref):
+    assert len(got) == len(ref)
+    for (xa, ya), (xb, yb) in zip(got, ref):
+        np.testing.assert_array_equal(xa, xb)
+        np.testing.assert_array_equal(ya, yb)
+
+
+def _array16():
+    return ArrayDataset(np.arange(64).astype("float32").reshape(16, 4),
+                        np.arange(16))
+
+
+@pytest.mark.parametrize("make_ds, batch", [(_array16, 4),
+                                            (SlowDataset, 8)],
+                         ids=["array", "cpu_bound"])
+def test_multi_worker(make_ds, batch):
+    """Worker processes yield the same batches in the same order as the
+    loading thread alone.  No rate is asserted: a wall-clock claim on a
+    shared CPU host is not a measurement (ROADMAP W6)."""
+    ds = make_ds()
+    ref, got = ([(x.asnumpy(), y.asnumpy())
+                 for x, y in DataLoader(ds, batch, num_workers=workers)]
+                for workers in (0, 2))
+    assert len(ref) == len(ds) // batch
+    _assert_same_batches(got, ref)
 
 
 def test_multi_worker_thread_pool():
@@ -196,47 +226,5 @@ def test_dataloader_forkserver_regression():
     assert {k: os.environ.get(k) for k in watched} == env_before
     ref = [(x.asnumpy(), y.asnumpy())
            for x, y in DataLoader(ds, 8, num_workers=0)]
-    assert len(got) == len(ref) == 6
-    for (xa, ya), (xb, yb) in zip(got, ref):
-        np.testing.assert_array_equal(xa, xb)
-        np.testing.assert_array_equal(ya, yb)
-
-
-class SlowDataset(Dataset):
-    """CPU-bound per-item work; module-level so forkserver/spawn workers
-    can pickle it."""
-
-    def __len__(self):
-        return 64
-
-    def __getitem__(self, idx):
-        a = np.random.RandomState(idx).rand(64, 64)
-        for _ in range(5):
-            a = a @ a.T
-            a /= np.abs(a).max()
-        return a.astype("float32"), np.float32(idx % 10)
-
-
-@pytest.mark.skipif(os.cpu_count() is None or os.cpu_count() < 2,
-                    reason="worker scaling needs >1 core (this host has "
-                           "%s); claim stays falsifiable on multi-core "
-                           "hardware" % os.cpu_count())
-def test_dataloader_worker_scaling_throughput():
-    """PERF.md's '~6 cores suffice' claim is arithmetic from a 1-core
-    host; the moment hardware allows, this measures it: multi-worker
-    loading of a CPU-bound dataset must not be slower than single-thread
-    (round-3 verdict weak item 5)."""
-    import time
-
-    def run(workers):
-        loader = DataLoader(SlowDataset(), batch_size=8,
-                            num_workers=workers)
-        t0 = time.perf_counter()
-        n = sum(batch[0].shape[0] for batch in loader)
-        dt = time.perf_counter() - t0
-        return n / dt
-
-    single = run(0)
-    multi = run(min(4, os.cpu_count()))
-    # generous bound: parallel workers must recover their overhead
-    assert multi > single * 0.9, (single, multi)
+    assert len(ref) == 6
+    _assert_same_batches(got, ref)

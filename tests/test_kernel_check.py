@@ -4,7 +4,7 @@ grid-safety analysis for Pallas kernels.
 Three claims pinned here:
 
 1. **Self-application is the merge gate** — the shipped kernels
-   (flash_attention fwd+bwd, conv_bwd, paged_attention) at their REAL
+   (flash_attention fwd+bwd, kda, paged_attention) at their REAL
    TPU serving/training geometries (fp32 and int8, decode and W-wide
    verify) report ZERO ERROR, so every ROADMAP-item-2 kernel lands
    behind an asserted-on-CPU geometry verdict.
@@ -42,7 +42,7 @@ def _spec(block, array, dtype="float32", kind="in", grid=(4,),
 # ------------------------------------------------ 1. self-application
 
 def test_shipped_kernels_pass_clean_at_tpu_geometries():
-    """The merge gate: flash fwd+bwd (fp32 + bf16), conv_bwd, and
+    """The merge gate: flash fwd+bwd (fp32 + bf16) and
     paged_attention (fp32 bs=16 + int8 bs=32, W=1 decode + W=8 verify)
     — zero ERROR, zero WARNING, one M007 pricing INFO per spec."""
     specs = default_kernel_specs()
@@ -51,7 +51,6 @@ def test_shipped_kernels_pass_clean_at_tpu_geometries():
     assert "flash_attention.bwd[" in names
     assert "flash_attention.bwd_dq" not in names
     assert "flash_attention.bwd_dkv" not in names
-    assert "conv_bwd" in names
     assert "paged_attention[int8,W=8" in names
     assert "paged_attention[float32,W=1" in names
     rep = check_kernels(specs)

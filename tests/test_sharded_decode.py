@@ -131,3 +131,107 @@ def test_moe_block_disables_bucketing():
     expect = lm.generate(prompt, max_new_tokens=3).asnumpy()
     got = dec.generate(prompt, max_new_tokens=3).asnumpy()
     np.testing.assert_array_equal(got, expect)
+
+
+# -- the decoder's table of program kinds ------------------------------
+# One case a row of parallel.decode.PROGRAMS: the kind builds, keys,
+# reports and hits through the one path (ShardedDecoder._run).  What
+# the programs COMPUTE is the parity suites' business
+# (test_serving*.py, test_speculative*.py, test_tree_speculative.py).
+
+_B, _T, _W, _BS, _NB, _M = 2, 32, 4, 8, 9, 4
+
+
+def _i32(*shape):
+    import jax.numpy as jnp
+    return jnp.zeros(shape, jnp.int32)
+
+
+# kind -> (over the block pool?, its inputs after the cache leaves)
+_KIND_INPUTS = {
+    "step": (False, lambda: (_i32(_B, 1), _i32())),
+    "prefill": (False, lambda: (_i32(_B, 8),)),
+    "step_slots": (False, lambda: (_i32(_B, 1), _i32(_B))),
+    "slot_prefill": (False, lambda: (_i32(1, 8), _i32())),
+    "verify_slots": (False, lambda: (_i32(_B, _W), _i32(_B), _i32(_B))),
+    "verify_tree_slots": (False, lambda: (
+        _i32(_B, _W), _i32(_B), _i32(_B), _i32(_B, _W, _W),
+        _i32(_B, _W))),
+    "fixup_slots": (False, lambda: (_i32(_B), _i32(_B, _W))),
+    "step_pages": (True, lambda: (_i32(_B, 1), _i32(_B, _M), _i32(_B))),
+    "page_prefill": (True, lambda: (_i32(1, 8), _i32(_M), _i32(), _i32(),
+                                    _i32())),
+    "verify_pages": (True, lambda: (_i32(_B, _W), _i32(_B, _M), _i32(_B),
+                                    _i32(_B))),
+    "verify_tree_pages": (True, lambda: (
+        _i32(_B, _W), _i32(_B, _M), _i32(_B), _i32(_B), _i32(_B, _W, _W),
+        _i32(_B, _W), _i32(_B, _W))),
+    "fixup_pages": (True, lambda: (_i32(_B, _M), _i32(_B), _i32(_B, _W))),
+}
+
+
+@pytest.fixture(scope="module")
+def kinds_dec(tiny):
+    dec = ShardedDecoder(tiny, make_mesh(tp=1),
+                         transformer_lm_sharding_rules())
+    dec._ensure_staged(nd.array(np.zeros((_B, 8)), dtype="int32"))
+    return dec
+
+
+@pytest.mark.parametrize("kind", sorted(_KIND_INPUTS))
+def test_program_kind_builds_keys_reports_and_hits(tiny, kinds_dec, kind):
+    from mxtpu.analysis import get_ledger
+    from mxtpu.parallel.decode import PROGRAMS
+
+    dec = kinds_dec
+    paged, extras = _KIND_INPUTS[kind]
+
+    def leaves():       # donated by every call: fresh ones each time
+        return dec._place_cache(
+            tiny.init_block_pool(_NB, _BS) if paged
+            else tiny.init_cache(_B, _T))
+
+    def lookups():
+        rec = get_ledger().site("serving.%s" % kind)
+        return (rec.hits, rec.miss_count) if rec else (0, 0)
+
+    hits, misses = lookups()
+    programs = len(dec._jit_cache)
+    out = dec._run(kind, leaves(), *extras())
+    assert len(dec._jit_cache) == programs + 1
+    assert sum(1 for k in dec._jit_cache if k[0] == kind) == 1
+    assert lookups() == (hits, misses + 1)
+    dec._run(kind, leaves(), *extras())
+    assert len(dec._jit_cache) == programs + 1
+    assert lookups() == (hits + 1, misses + 1)
+    new_leaves = out if PROGRAMS[kind].caches_only else out[1]
+    assert len(new_leaves) == len(tiny.layers)
+    if not PROGRAMS[kind].caches_only:
+        assert out[0].shape[-1] == 50       # logits over the vocabulary
+
+
+def test_only_the_mixer_implements_the_cache_forms():
+    """The seam: a cache form is written once, in the sequence mixer.
+    The layers define none and the model keeps the two names its public
+    callers use (generate, models/sampler.py); every form passes through
+    cached_forward by NAME; every program kind of the decoder drives a
+    form the mixer has, or (the fix-ups) an operation on cache leaves."""
+    from mxtpu.models.moe import MoEDecoderLayer
+    from mxtpu.models.transformer import (CACHE_FORMS, PREFILL_FORMS,
+                                          LlamaDecoderLayer,
+                                          MultiHeadAttention,
+                                          TransformerLM)
+    from mxtpu.parallel.decode import PROGRAMS
+
+    for cls, named in ((LlamaDecoderLayer, set()), (MoEDecoderLayer, set()),
+                       (TransformerLM, {"step", "prefill"})):
+        assert {f for f in CACHE_FORMS if hasattr(cls, f)} == named, cls
+        assert "cached_forward" in vars(cls), cls
+    for form in CACHE_FORMS:
+        assert callable(getattr(MultiHeadAttention, form)), form
+    assert set(PREFILL_FORMS) <= set(CACHE_FORMS)
+    assert set(PROGRAMS) == set(_KIND_INPUTS)
+    for kind, row in PROGRAMS.items():
+        assert row.form in CACHE_FORMS or kind.startswith("fixup_"), kind
+    assert {row.form for row in PROGRAMS.values()} - {None} == \
+        set(CACHE_FORMS)

@@ -141,11 +141,18 @@ def test_tp2_hierarchical_swap_over_sharded_kernel(monkeypatch):
 # ------------------------------------------------ fused int8 epilogue
 
 
-def test_fused_epilogue_projection_is_bitexact():
+def test_fused_epilogue_projection_within_ulps():
     """The split projection (wq_matmul_i8 on the Q/K columns +
-    wq_matmul_i8_q8 on the V columns) reproduces the unfused qkv
-    projection bitwise, and the pre-quantized V rows are exactly what
-    quantize-on-write (_q8_quantize) would have stored."""
+    wq_matmul_i8_q8 on the V columns) is the unfused qkv projection to
+    within a few ulp of the row's largest value, and the pre-quantized
+    V rows are what quantize-on-write (_q8_quantize) would have stored,
+    give or take one step where a value sits on a rounding boundary.
+    NOT bitwise: a product over a slice of the weight and a slice of
+    the product over the whole weight are two XLA programs, free to
+    tile the contraction differently (73 of 96 q/k values differ on
+    this host, by at most 8.9e-8 at magnitudes up to 0.71).  What a
+    user relies on — the same tokens end to end — is the next test's
+    and tests/test_quantized_serving.py's to hold."""
     import jax.numpy as jnp
     from mxtpu.ops.tensor import _q8_quantize
 
@@ -157,12 +164,16 @@ def test_fused_epilogue_projection_is_bitexact():
                  .astype("float32"))
     full = attn.qkv(x).asnumpy()
     qk, vq, vs = attn._project_qkv_fused_q8(x)
-    assert np.array_equal(qk.asnumpy(), full[:, :, :cut])
+    ulp = np.spacing(np.abs(full).max())
+    np.testing.assert_allclose(qk.asnumpy(), full[:, :, :cut], rtol=0,
+                               atol=4 * ulp)
     q_ref, s_ref = _q8_quantize(
         jnp.asarray(full[:, :, cut:].reshape(2, 1, KV, D)))
-    assert np.array_equal(vq.asnumpy().reshape(2, 1, KV, D),
-                          np.asarray(q_ref))
-    assert np.array_equal(vs.asnumpy(), np.asarray(s_ref))
+    steps = np.abs(vq.asnumpy().reshape(2, 1, KV, D).astype(np.int32)
+                   - np.asarray(q_ref).astype(np.int32))
+    assert steps.max() <= 1
+    np.testing.assert_allclose(vs.asnumpy(), np.asarray(s_ref),
+                               rtol=1e-6)
 
 
 @pytest.mark.parametrize("tp", [1, 2])

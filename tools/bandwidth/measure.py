@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""All-reduce bandwidth benchmark (parity: tools/bandwidth/measure.py —
-BASELINE metric 3).
+"""All-reduce bandwidth benchmark (parity: the reference's
+tools/bandwidth/measure.py, its KVStore all-reduce GB/s).
 
 The reference measured KVStore push+pull bandwidth across GPUs (ps-lite or
 NCCL transport). Here the measured path is the compiled XLA all-reduce over

@@ -954,7 +954,7 @@ def check_kernel_geometry():
         specs = default_kernel_specs()
         rep = check_kernels(specs)
         print("kernel specs :", len(specs), "pallas_call geometrie(s) "
-              "(flash fwd/bwd, conv_bwd, paged decode+prefill "
+              "(flash fwd/bwd, KDA, paged decode+prefill "
               "fp32/int8 incl. tp-sharded)")
         print("verdict      :", rep.summary())
         for d in rep.errors:
