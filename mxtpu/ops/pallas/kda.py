@@ -17,9 +17,13 @@ starts from, the rule unrolls to
 so everything that does not involve ``S`` — the decayed products ``A``
 and ``M``, the inverse ``T = (I + A)^-1`` and ``W = T b K e^G``,
 ``U0 = T b V`` — is parallel over chunks: the Pallas kernel
-``kda_chunk_fwd`` forms it a chunk a grid step (``_chunk_math``), and
-``kda_chunk_bwd`` is the same function's ``jax.vjp`` inside a kernel.
-Only
+``kda_chunk_fwd`` forms it a chunk a grid step (``_chunk_math``).  Its
+backward ``kda_chunk_bwd`` (``_chunk_bwd_math``) is written by hand and
+forms no product of the forward again: it is handed ``T``, which the
+forward that the backward pass runs (``kda_chunk_fwd_inverse``) writes
+out beside the operands, so ``dA = -T^T dT T^T`` is two products, and
+``A`` and ``M`` are bilinear in operands that are decayed again element
+by element.  Only
 
     U = U0 - W S,   O = (Q e^G) S + M U,   S' = Diag(e^(G_C)) S + Khat^T U
 
@@ -33,8 +37,11 @@ block of rows in the later half of a span against the columns of its
 earlier half takes its reference at the first later row, so both factors
 decay — and the inverse is built up the same spans
 (``[[T1, 0], [-T2 A21 T1, T2]]``, as ``T - T A21 T`` on the whole tile).
-Each partial sum of ``g`` is formed over its own span (a product with a
-0/1 matrix), never as a difference of two long sums.
+Each partial sum of ``g`` is formed over its own span, never as a
+difference of two long sums: all of a chunk's sums are one product of a
+0/1 matrix (exact in bfloat16) with the three bfloat16 pieces of ``g`` —
+the three of ``Precision.HIGHEST``'s six passes that do not multiply by
+zero.  Every other product takes float32 operands at ``HIGHEST``.
 """
 
 from __future__ import annotations
@@ -54,6 +61,7 @@ from . import counters
 __all__ = ["kda", "kda_mixer", "kernel_specs"]
 
 CHUNK_FWD_NAME = "kda_chunk_fwd"
+CHUNK_FWD_INVERSE_NAME = "kda_chunk_fwd_inverse"  # .. and writes T as well
 CHUNK_BWD_NAME = "kda_chunk_bwd"
 FWD_NAME = "kda_state_fwd"
 FWD_STATES_NAME = "kda_state_fwd_states"    # the forward the backward runs
@@ -66,9 +74,10 @@ _HI = jax.lax.Precision.HIGHEST
 def kernel_specs(B, H, T, K, V=None, chunk=CHUNK, interpret=False):
     """KernelSpec descriptors (mxtpu.analysis.kernel_check) of the four
     kernels a backward pass issues at this geometry, in its order: the
-    chunks' operands forward, the state pass forward as the backward runs
-    it (writing every chunk's starting state), the state pass backward,
-    the chunks' operands backward."""
+    chunks' operands forward as the backward runs it (writing every
+    chunk's inverse, transposed), the state pass forward likewise (writing
+    every chunk's starting state), the state pass backward, the chunks'
+    operands backward (which reads the inverse)."""
     from ...analysis.kernel_check import (BlockOperand, KernelSpec,
                                           ScratchOperand)
 
@@ -89,14 +98,15 @@ def kernel_specs(B, H, T, K, V=None, chunk=CHUNK, interpret=False):
             ("g", C, K)]
     operands = [("w", C, K), ("u0", C, V), ("qg", C, K), ("m", C, C),
                 ("khat", C, K), ("gamma", 1, K)]
+    inverse = [("t_t", C, C)]
     ins = lambda names, pre="": [(pre + n, "in", r, c)        # noqa: E731
                                  for n, r, c in names]
     outs = lambda names, pre="": [(pre + n, "out", r, c)      # noqa: E731
                                   for n, r, c in names]
     tag = "[float32,T=%d,K=%d,V=%d,C=%d]" % (T, K, V, C)
     return [
-        KernelSpec(CHUNK_FWD_NAME + tag, grid=(BH, N),
-                   operands=blocks(at, ins(rows) + outs(operands)),
+        KernelSpec(CHUNK_FWD_INVERSE_NAME + tag, grid=(BH, N),
+                   operands=blocks(at, ins(rows) + outs(operands + inverse)),
                    interpret=interpret),
         KernelSpec(FWD_STATES_NAME + tag, grid=(BH, N),
                    operands=blocks(at, ins(operands) + [
@@ -110,8 +120,8 @@ def kernel_specs(B, H, T, K, V=None, chunk=CHUNK, interpret=False):
                    scratch=[ScratchOperand("dstate", (V, K), "float32")],
                    interpret=interpret),
         KernelSpec(CHUNK_BWD_NAME + tag, grid=(BH, N),
-                   operands=blocks(at, ins(rows) + ins(operands, "d")
-                                   + outs(rows, "d")),
+                   operands=blocks(at, ins(rows + inverse)
+                                   + ins(operands, "d") + outs(rows, "d")),
                    interpret=interpret)]
 
 
@@ -237,64 +247,172 @@ def _make_state_pass(interpret):
 # ------------------------------------------------- parallel over chunks
 #
 # One chunk's operands from its rows, as whole (C, C) and (C, K) tiles —
-# masks and products, no reshape below a tile — so that the same function
-# is the body of the forward kernel and, through ``jax.vjp``, of the
-# backward one.  (As batched XLA products the same algebra cost the chip's
-# compiler 24 s and 58 MB of code an instance, four instances a layer:
-# PERF.md, PR 29.)
+# masks and products, no reshape below a tile.  ``_chunk_math`` is the body
+# of the forward kernel and ``_chunk_bwd_math``, written by hand, of the
+# backward one: ``jax.vjp(_chunk_math)`` is what the tests hold it to.
+# (As batched XLA products the same algebra cost the chip's compiler 24 s
+# and 58 MB of code an instance, four instances a layer: PERF.md, PR 29.)
+
+def _halvings(C):
+    """The half-spans of a chunk of C rows: 1, 2, .. C / 2."""
+    return [1 << i for i in range(C.bit_length() - 1)]
+
+
+def _tile_indices(C):
+    return (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+
+def _pair(row, col, h):
+    """Rows of a later half against the columns of their span's earlier
+    half, and nothing else."""
+    span = 2 * h
+    return (((row & (span - 1)) >= h) & ((col & -span) == (row & -span))
+            & ((col & (span - 1)) < h))
+
+
+def _span_matrix(C):
+    """The 0/1 matrix whose product with g gives every partial sum of g a
+    chunk needs, a block of C rows each, none of them positive.  A block a
+    halving: with ``first`` the first later row of the span a row lies in,
+    for a later row the sum over the rows after ``first`` up to it, for an
+    earlier row over the rows after it up to ``first``.  Then G (the rows
+    up to a row) and G_C - G (the rows after it).  bfloat16 holds 0 and 1
+    exactly."""
+    row, col = _tile_indices(C)
+    blocks = []
+    for h in _halvings(C):
+        first = (row & -(2 * h)) + h
+        later = (row & (2 * h - 1)) >= h
+        blocks.append((later & (col > first) & (col <= row))
+                      | (~later & (col > row) & (col <= first)))
+    blocks += [col <= row, col > row]
+    return jnp.concatenate([jnp.where(b, 1.0, 0.0) for b in blocks],
+                           axis=0).astype(jnp.bfloat16)
+
+
+def _top(x):
+    """float32 ``x`` with its significand cut to bfloat16's eight bits."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-65536)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _exact_product(spans, x, dims):
+    """``spans`` (0/1, bfloat16) times float32 ``x`` along ``dims``, as
+    ``Precision.HIGHEST`` gives it: ``x`` is the sum of three bfloat16
+    pieces, and of HIGHEST's six passes the three that take a lower piece
+    of ``spans`` multiply by zero.  The other three, as one product."""
+    K = x.shape[1]
+    hi = _top(x)
+    mid = _top(x - hi)
+    pieces = jnp.concatenate([hi, mid, x - hi - mid], axis=1)
+    p = jax.lax.dot_general(spans, pieces.astype(jnp.bfloat16), dims,
+                            preferred_element_type=jnp.float32)
+    return p[:, :K] + (p[:, K:2 * K] + p[:, 2 * K:])
+
+
+@jax.custom_vjp
+def _span_sums(spans, g):
+    """Every partial sum of g, ((halvings + 2) C, K).  (Linear in g; the
+    rule is written out because the pieces are cut bit by bit.)"""
+    return _exact_product(spans, g, _A_B)
+
+
+_span_sums.defvjp(
+    lambda spans, g: (_span_sums(spans, g), spans),
+    lambda spans, ct: (jnp.zeros_like(spans),
+                       _exact_product(spans, ct, _AT_B)))
+
+
+def _decays(spans, g):
+    """exp of every partial sum of g: a (C, K) tile a halving, then e^G,
+    e^(G_C - G) and e^(G_C) (1, K)."""
+    C = g.shape[0]
+    sums = _span_sums(spans, g)
+    *halvings, decay, after = (jnp.exp(sums[i:i + C])
+                               for i in range(0, sums.shape[0], C))
+    return halvings, decay, after, jnp.exp(jnp.sum(g, axis=0, keepdims=True))
+
 
 def _chunk_math(q, k, bk, bv, g):
-    """(W, U0, Qg, M, Khat, gamma) of one chunk.  q, k, g (C, K); bk =
-    beta k (C, K) and bv = beta v (C, V): the rows' write strength comes
-    folded in.  C a power of two."""
-    C = k.shape[0]
-    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    own = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)      # a row's index
-    ones = lambda mask: jnp.where(mask, 1.0, 0.0)               # noqa: E731
-    t_inv = ones(row == col)
-    m = t_inv * _dot(q, k, _A_BT)                   # spans of one row: q.k
-    h = 1
-    while h < C:
-        span = 2 * h
-        # the first later row of the span a row lies in, and which half
-        first = (row & -span) + h
-        later = (row & (span - 1)) >= h
-        # partial sums of g, never positive: for a later row over the rows
-        # after `first` up to it, for an earlier row over the rows after it
-        # up to `first`
-        sums = ones((later & (col > first) & (col <= row))
-                    | (~later & (col > row) & (col <= first)))
-        decay = jnp.exp(_dot(sums, g, _A_B))
-        late = (own & (span - 1)) >= h
-        k_up = jnp.where(late, 0.0, k * decay)
-        # rows of a later half against the columns of their span's earlier
-        # half, and nothing else
-        pair = later & ((col & -span) == (row & -span)) & (col < first)
-        a21 = jnp.where(pair, _dot(jnp.where(late, bk * decay, 0.0), k_up,
-                                   _A_BT), 0.0)
-        m = m + jnp.where(pair, _dot(jnp.where(late, q * decay, 0.0), k_up,
-                                     _A_BT), 0.0)
-        # [[T1, 0], [-T2 A21 T1, T2]] of every span at once
-        t_inv = t_inv - _dot(_dot(t_inv, a21, _A_B), t_inv, _A_B)
-        h = span
-    decay = jnp.exp(_dot(ones(col <= row), g, _A_B))            # e^G
-    after = jnp.exp(_dot(ones(col > row), g, _A_B))             # e^(G_C - G)
-    gamma = jnp.exp(jnp.sum(g, axis=0, keepdims=True))          # e^(G_C)
-    return (_dot(t_inv, bk * decay, _A_B), _dot(t_inv, bv, _A_B), q * decay,
-            m, k * after, gamma)
+    """(W, U0, Qg, M, Khat, gamma, T^T) of one chunk.  q, k, g (C, K); bk
+    = beta k (C, K) and bv = beta v (C, V): the rows' write strength comes
+    folded in.  C a power of two.
+
+    A, M and T are held transposed: a halving's two decayed products are
+    then one product, (K d) [bK d; Q d]^T, whose 2 C columns fill the
+    matrix unit's width, and the backward pass wants T^T."""
+    C, K = k.shape
+    row, col = _tile_indices(C)
+    decays, decay, after, gamma = _decays(_span_matrix(C), g)
+    t_t = jnp.where(row == col, 1.0, 0.0)
+    m_t = t_t * jnp.sum(q * k, axis=1, keepdims=True)   # spans of one row
+    for h, d in zip(_halvings(C), decays):
+        # both factors decay, the later rows from `first` on and the
+        # earlier ones up to it; what is no pair of the two is masked away
+        pair_t = _pair(col, row, h)
+        am_t = _dot(k * d, jnp.concatenate([bk * d, q * d], axis=0),
+                    _A_BT)                                      # (C, 2 C)
+        a21_t = jnp.where(pair_t, am_t[:, :C], 0.0)
+        m_t = m_t + jnp.where(pair_t, am_t[:, C:], 0.0)
+        # [[T1, 0], [-T2 A21 T1, T2]] of every span at once; the spans of
+        # one row start from T = I, where that is I - A21
+        t_t = t_t - (a21_t if h == 1 else
+                     _dot(_dot(t_t, a21_t, _A_B), t_t, _A_B))
+    wu = _dot(t_t, jnp.concatenate([bk * decay, bv], axis=1), _AT_B)
+    return wu[:, :K], wu[:, K:], q * decay, m_t.T, k * after, gamma, t_t
+
+
+def _chunk_bwd_math(q, k, bk, bv, g, t_t, dw, du0, dqg, dm, dkhat, dgamma):
+    """(dq, dk, dbk, dbv, dg) of one chunk from its rows, ``t_t`` = T^T
+    as the forward wrote it (T = (I + A)^-1), and the cotangents of (W,
+    U0, Qg, M, Khat, gamma).  No product of the forward is formed again:
+    ``dA = -T^T dT T^T``, and A and M are bilinear in their decayed
+    operands, which are formed again element by element."""
+    C, K = k.shape
+    row, col = _tile_indices(C)
+    spans = _span_matrix(C)
+    decays, decay, after, gamma = _decays(spans, g)
+    # W = T (bK e^G), U0 = T (bV)
+    dwu = jnp.concatenate([dw, du0], axis=1)
+    dt = _dot(dwu, jnp.concatenate([bk * decay, bv], axis=1), _A_BT)
+    back = _dot(t_t, dwu, _A_B)
+    dp, dbv = back[:, :K], back[:, K:]
+    da = -_dot(_dot(t_t, dt, _A_B), t_t, _A_B)
+    own = jnp.sum(jnp.where(row == col, dm, 0.0), axis=1, keepdims=True)
+    dq = dqg * decay + own * k
+    dk = dkhat * after + own * q
+    dbk = dp * decay
+    dsums = []
+    for h, d in zip(_halvings(C), decays):
+        pair = _pair(row, col, h)
+        cts = jnp.concatenate([jnp.where(pair, da, 0.0),
+                               jnp.where(pair, dm, 0.0)], axis=0)   # (2C, C)
+        # of the later rows' (bK d; Q d) and of the earlier rows' K d: the
+        # mask has left nothing in the other rows
+        late = _dot(cts, k * d, _A_B)                           # (2 C, K)
+        early = _dot(cts, jnp.concatenate([bk * d, q * d], axis=0), _AT_B)
+        dbk = dbk + late[:C] * d
+        dq = dq + late[C:] * d
+        dk = dk + early * d
+        # a log decay's cotangent: value times cotangent
+        dsums.append((late[:C] * bk + late[C:] * q + early * k) * d)
+    dsums += [(dp * bk + dqg * q) * decay, dkhat * k * after]
+    dg = _exact_product(spans, jnp.concatenate(dsums, axis=0), _AT_B)
+    return dq, dk, dbk, dbv, dg + dgamma * gamma
 
 
 def _chunk_fwd_kernel(*refs):
+    """Writes as many of ``_chunk_math``'s values as it has outputs: the
+    six operands, and T^T after them where the backward pass asks."""
     ins, outs = refs[:5], refs[5:]
     for ref, value in zip(outs, _chunk_math(*(r[0, 0] for r in ins))):
         ref[0, 0] = value
 
 
 def _chunk_bwd_kernel(*refs):
-    ins, cts, outs = refs[:5], refs[5:11], refs[11:]
-    _, back = jax.vjp(_chunk_math, *(r[0, 0] for r in ins))
-    for ref, value in zip(outs, back(tuple(r[0, 0] for r in cts))):
+    ins, outs = refs[:12], refs[12:]
+    for ref, value in zip(outs, _chunk_bwd_math(*(r[0, 0] for r in ins))):
         ref[0, 0] = value
 
 
@@ -323,23 +441,31 @@ def _chunk_call(kernel, name, ins, outs, interpret):
 @functools.lru_cache(maxsize=None)
 def _make_chunk_operands(interpret):
     """(W, U0, Qg, M, Khat, gamma) of every chunk from (BH, N, C, .)
-    rows: one kernel forward, one backward (which forms the forward again
-    inside, chunk by chunk)."""
-    def forward(*ins):
+    rows: one kernel forward, one backward.  Under differentiation the
+    forward also writes every chunk's inverse (T^T, C x C a chunk), the
+    one thing the backward kernel is handed beside the rows."""
+    def forward(ins, name, inverse):
         (BH, N, C, K), V = ins[0].shape, ins[3].shape[-1]
-        return tuple(_chunk_call(_chunk_fwd_kernel, CHUNK_FWD_NAME, ins,
-                                 _operand_shapes(BH, N, C, K, V), interpret))
+        outs = _operand_shapes(BH, N, C, K, V) + (
+            [(BH, N, C, C)] if inverse else [])
+        return tuple(_chunk_call(_chunk_fwd_kernel, name, ins, outs,
+                                 interpret))
 
     @jax.custom_vjp
     def chunk_operands(*ins):
-        return forward(*ins)
+        return forward(ins, CHUNK_FWD_NAME, False)
 
-    def bwd(ins, cts):
+    def fwd(*ins):
+        *ops, t_t = forward(ins, CHUNK_FWD_INVERSE_NAME, True)
+        return tuple(ops), (ins, t_t)
+
+    def bwd(kept, cts):
+        ins, t_t = kept
         return tuple(_chunk_call(_chunk_bwd_kernel, CHUNK_BWD_NAME,
-                                 tuple(ins) + tuple(cts),
+                                 tuple(ins) + (t_t,) + tuple(cts),
                                  [x.shape for x in ins], interpret))
 
-    chunk_operands.defvjp(lambda *ins: (forward(*ins), ins), bwd)
+    chunk_operands.defvjp(fwd, bwd, optimize_remat=True)
     return chunk_operands
 
 

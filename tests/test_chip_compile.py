@@ -128,18 +128,19 @@ def test_flash_attention_with_narrower_values_at_8k(on_chip):
 
 @pytest.mark.parametrize("T", [8192, 96], ids=["cell", "toy"])
 def test_kda_kernels(on_chip, T, heads=4, K=128):
-    """KDA's four kernels, forward and backward, at one call of the cell
-    (4 heads at a time x 128 chunks of 64 x 128): the chunks' operands
-    (the backward is the forward's ``jax.vjp`` inside a kernel) and the
-    pass that carries the state."""
+    """KDA's kernels, forward and backward, at one call of the cell (4
+    heads at a time x 128 chunks of 64 x 128): the chunks' operands (the
+    forward, the forward that also writes the inverses, the hand-written
+    backward that reads them) and the pass that carries the state."""
     rows = _shape((1, T, heads, K), F32, on_chip)
     beta = _shape((1, T, heads), F32, on_chip)
     text = jax.jit(jax.grad(
         lambda *a: kda._recurrence(*a, chunk=kda.CHUNK).sum(),
         argnums=(0, 1, 2, 3, 4))).lower(
         rows, rows, rows, rows, beta).compile().as_text()
-    for name in (kda.CHUNK_FWD_NAME, kda.CHUNK_BWD_NAME, kda.FWD_NAME,
-                 kda.FWD_STATES_NAME, kda.BWD_NAME):
+    for name in (kda.CHUNK_FWD_NAME, kda.CHUNK_FWD_INVERSE_NAME,
+                 kda.CHUNK_BWD_NAME, kda.FWD_NAME, kda.FWD_STATES_NAME,
+                 kda.BWD_NAME):
         assert name in text
     for cached in (kda._make_state_pass, kda._make_chunk_operands):
         cached.cache_clear()

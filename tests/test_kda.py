@@ -2,12 +2,14 @@
 interpret mode) against the recurrence it stands for, computed here one
 position at a time."""
 
+import collections
 import importlib
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 
 import mxtpu as mx
 from mxtpu.analysis import check_kernels
@@ -53,17 +55,21 @@ CASES = [
     (64, 16, (1.0, 12.0)),      # decay near 0: a = exp(-12) a step
     (48, 16, (1e-6, 1e-5)),     # decay near 1
     (40, 64, (1e-2, 1.0)),      # shorter than one chunk
+    # G down to -768 inside a chunk, through all six halvings: a positive
+    # exponent anywhere overflows to inf
+    (128, 64, (3.0, 12.0)),
 ]
 IDS = ["T128_c64", "T100_c32_ragged", "T64_c16_decay_near_0",
-       "T48_c16_decay_near_1", "T40_c64_short"]
+       "T48_c16_decay_near_1", "T40_c64_short", "T128_c64_decay_near_0"]
 
 
 @pytest.mark.parametrize("T,chunk,decay", CASES, ids=IDS)
 def test_forward_matches_the_recurrence(T, chunk, decay):
     args, _ = inputs(T, decay)
-    np.testing.assert_allclose(
-        np.asarray(kda.kda(*args, chunk=chunk)),
-        np.asarray(recurrence(*args)), rtol=2e-5, atol=2e-6)
+    got = np.asarray(kda.kda(*args, chunk=chunk))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(recurrence(*args)),
+                               rtol=2e-5, atol=2e-6)
 
 
 @pytest.fixture(scope="module", params=list(zip(CASES, IDS)),
@@ -81,9 +87,123 @@ def gradients(request):
 @pytest.mark.parametrize("name", ["q", "k", "v", "g", "beta"])
 def test_every_inputs_gradient_matches_the_recurrence(gradients, name):
     got, want = gradients[name]
+    assert np.isfinite(np.asarray(got)).all()
     scale = float(jnp.max(jnp.abs(want)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=2e-6 * max(scale, 1.0))
+
+
+# ------------------- the chunk kernels against jax.vjp of the forward's body
+
+def _chunk_rows(C, decay, BH=2, N=3, K=16, V=8):
+    """Rows of BH x N chunks as the kernels take them, and a cotangent for
+    each of the six operands."""
+    (q, k, v, g, beta), _ = inputs(N * C, decay, B=1, H=BH, K=K, V=V, seed=C)
+    chunks = lambda a: jnp.moveaxis(a, 1, 2).reshape(    # noqa: E731
+        (BH, N, C) + a.shape[3:])
+    b = beta[..., None]
+    rows = tuple(map(chunks, (q, k, b * k, b * v, g)))
+    keys = jax.random.split(jax.random.PRNGKey(C + 1), 6)
+    cts = tuple(jax.random.normal(key, shape) for key, shape in
+                zip(keys, kda._operand_shapes(BH, N, C, K, V)))
+    return rows, cts
+
+
+@pytest.mark.parametrize("decay", [c[2] for c in CASES[:5]],
+                         ids=["decay_%g_%g" % c[2] for c in CASES[:5]])
+@pytest.mark.parametrize("C", [16, 32, 64])
+def test_the_handwritten_backward_matches_the_vjp_of_the_forward(C, decay):
+    """``kda_chunk_fwd_inverse`` and ``kda_chunk_bwd`` (interpret mode)
+    against ``jax.vjp(_chunk_math)`` chunk by chunk: the operands, and
+    each of the five cotangents to 1e-5 of its tile's norm."""
+    rows, cts = _chunk_rows(C, decay)
+    (BH, N, _, K), V = rows[0].shape, rows[3].shape[-1]
+    shapes = kda._operand_shapes(BH, N, C, K, V)
+    *ops, t_t = kda._chunk_call(
+        kda._chunk_fwd_kernel, kda.CHUNK_FWD_INVERSE_NAME, rows,
+        shapes + [(BH, N, C, C)], True)
+    got = kda._chunk_call(
+        kda._chunk_bwd_kernel, kda.CHUNK_BWD_NAME, rows + (t_t,) + cts,
+        [x.shape for x in rows], True)
+
+    def reference(chunk):       # one chunk at a time, as the kernels go
+        rows, cts = chunk
+        ops, back = jax.vjp(lambda *a: kda._chunk_math(*a)[:6], *rows)
+        return ops, back(cts)
+
+    flat = lambda a: a.reshape((BH * N,) + a.shape[2:])    # noqa: E731
+    want_ops, want = jax.tree_util.tree_map(
+        lambda a: a.reshape((BH, N) + a.shape[1:]),
+        jax.lax.map(reference, jax.tree_util.tree_map(flat, (rows, cts))))
+    tiles = lambda a: np.sqrt(np.sum(                   # noqa: E731
+        np.square(np.asarray(a, dtype="float64")), axis=(2, 3)))
+    for a, b in zip(ops, want_ops):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)
+    for name, a, b in zip(("q", "k", "bk", "bv", "g"), got, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        assert (tiles(a - b) <= 1e-5 * tiles(b)).all(), \
+            (name, float((tiles(a - b) / tiles(b)).max()))
+
+
+def _products(jaxpr, computed):
+    """Every ``dot_general`` under ``jaxpr`` with, per operand, whether
+    an input of the kernel reaches it (a 0/1 matrix made of iotas is
+    reached by none)."""
+    computed = set(computed)
+    for eqn in jaxpr.eqns:
+        reached = [not isinstance(v, Literal) and v in computed
+                   for v in eqn.invars]
+        if eqn.primitive.name == "dot_general":
+            yield eqn, reached
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            assert len(sub.invars) == len(eqn.invars)
+            yield from _products(
+                sub, [v for v, r in zip(sub.invars, reached) if r])
+        if any(reached):
+            computed.update(eqn.outvars)
+
+
+def _kernel_body(kernel, ins, outs):
+    text = jax.make_jaxpr(lambda *a: kda._chunk_call(
+        kernel, "body", a, outs, True))(*[jnp.zeros(s) for s in ins])
+    (call,) = [e for e in text.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return call.params["jaxpr"]
+
+
+@pytest.mark.parametrize("kernel, most_products, most_units",
+                         [("fwd", 35, 29), ("bwd", 48, 40)])
+def test_chunk_kernels_stay_inside_their_product_budget(
+        kernel, most_products, most_units):
+    """The ``dot_general`` equations in the body of a chunk kernel at C =
+    64, K = V = 128, and their cost in units of a 64 x 64 x 128 product at
+    six bfloat16 passes (``Precision.HIGHEST`` on float32; a product of
+    bfloat16 operands is one pass).  PR 32 brought the forward from 35
+    products of 29 units to 18 of 23, the backward from 95 of 78 to 18 of
+    37.  No product of two computed operands runs below HIGHEST."""
+    C, K = 64, 128
+    rows = [(1, 1, C, K)] * 5
+    operands = kda._operand_shapes(1, 1, C, K, K)
+    inverse = [(1, 1, C, C)]
+    body = _kernel_body(kda._chunk_fwd_kernel, rows, operands + inverse) \
+        if kernel == "fwd" else \
+        _kernel_body(kda._chunk_bwd_kernel, rows + inverse + operands, rows)
+    count = units = 0
+    for eqn, reached in _products(body, body.invars):
+        a, b = (v.aval for v in eqn.invars)
+        (contracted, _), _ = eqn.params["dimension_numbers"]
+        macs = np.prod(eqn.outvars[0].aval.shape) * np.prod(
+            [a.shape[d] for d in contracted])
+        if eqn.params["precision"] in (HI, (HI, HI)):
+            assert a.dtype == b.dtype == jnp.float32
+            passes = 6
+        else:   # exact all the same: one factor is a 0/1 matrix
+            assert a.dtype == b.dtype == jnp.bfloat16
+            assert not all(reached), eqn
+            passes = 1
+        count += 1
+        units += macs * passes / (6 * 64 * 64 * 128)
+    assert count <= most_products and units <= most_units, (count, units)
 
 
 def test_heads_go_through_in_groups_and_give_the_same(monkeypatch):
@@ -128,7 +248,7 @@ def test_bfloat16_model_gets_bfloat16_back():
 def test_kernel_specs_pass_the_static_check(T):
     specs = kda.kernel_specs(B=1, H=8, T=T, K=128)
     assert [s.name.split("[")[0] for s in specs] == \
-        [kda.CHUNK_FWD_NAME, kda.FWD_STATES_NAME, kda.BWD_NAME,
+        [kda.CHUNK_FWD_INVERSE_NAME, kda.FWD_STATES_NAME, kda.BWD_NAME,
          kda.CHUNK_BWD_NAME]
     report = check_kernels(specs)
     assert not report.errors, [str(d) for d in report.errors]
@@ -136,9 +256,9 @@ def test_kernel_specs_pass_the_static_check(T):
 
 def test_kernel_specs_describe_the_real_calls(monkeypatch):
     """kernel_specs == the four pallas_calls a backward pass issues after
-    it has run the forward again: the chunks' operands, the state pass
-    that writes the chunks' states, its backward, the operands'
-    backward."""
+    it has run the forward again: the chunks' operands with their
+    inverses, the state pass that writes the chunks' states, its backward,
+    the operands' backward."""
     calls = []
     real = kda.pl.pallas_call
 
@@ -153,12 +273,16 @@ def test_kernel_specs_describe_the_real_calls(monkeypatch):
     jax.grad(lambda *a: kda.kda(*a, chunk=32).sum())(*args)
     for cached in (kda._make_state_pass, kda._make_chunk_operands):
         cached.cache_clear()
-    forward = [kda.CHUNK_FWD_NAME, kda.FWD_NAME]
-    assert [c["name"] for c in calls] == forward + [
-        kda.CHUNK_FWD_NAME, kda.FWD_NAME, kda.FWD_STATES_NAME, kda.BWD_NAME,
-        kda.CHUNK_BWD_NAME]
+    # traced calls: the forward pass, then the backward pass's own forward
+    # (the kernel that writes no inverse is traced once more for the pass
+    # that needs no residuals) and its two backward kernels
+    assert collections.Counter(c["name"] for c in calls) == {
+        kda.CHUNK_FWD_NAME: 2, kda.FWD_NAME: 2,
+        kda.CHUNK_FWD_INVERSE_NAME: 1, kda.FWD_STATES_NAME: 1,
+        kda.BWD_NAME: 1, kda.CHUNK_BWD_NAME: 1}
     specs = kda.kernel_specs(B=1, H=2, T=96, K=16, chunk=32, interpret=True)
-    issued = [calls[2]] + calls[4:]
+    by_name = {c["name"]: c for c in calls}
+    issued = [by_name[spec.name.split("[")[0]] for spec in specs]
     for call, spec in zip(issued, specs):
         assert tuple(call["grid"]) == spec.grid
         for kind, key in (("in", "in_specs"), ("out", "out_specs")):
@@ -175,7 +299,6 @@ def _under_a_unit(op, args, w, policy):
     """Gradient of ``sum(tanh(op(x) @ w))`` through a checkpoint as a
     unit of recomputation has it, with the unit's policy or none: the
     jaxpr's kernels by name, what the policy kept, the gradients."""
-    import collections
     import re
     from mxtpu.ops import remat
 
@@ -206,7 +329,10 @@ def test_a_unit_keeps_the_output_and_runs_the_forward_once_less(op):
     w = jax.random.normal(jax.random.PRNGKey(7), (16, 4))
     kept, counts, grads = _under_a_unit(fn, args, w, remat.policy)
     alone, nothing, want = _under_a_unit(fn, args, w, None)
-    assert (kept[kda.CHUNK_FWD_NAME], alone[kda.CHUNK_FWD_NAME]) == (2, 3)
+    # one of the runs is the groups' own, which also writes the inverses
+    assert (kept[kda.CHUNK_FWD_NAME], alone[kda.CHUNK_FWD_NAME]) == (1, 2)
+    assert (kept[kda.CHUNK_FWD_INVERSE_NAME],
+            alone[kda.CHUNK_FWD_INVERSE_NAME]) == (1, 1)
     # the groups' own recomputation reads the state pass's output only in
     # the mixer (its norm and gate); the bare op's backward does not
     states = (2, 3) if op == "kda_mixer" else (1, 2)

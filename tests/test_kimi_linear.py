@@ -273,15 +273,24 @@ def toy_steps():
 
 
 @pytest.mark.parametrize("unit, names, kept_runs, alone_runs", [
-    ("kda", ("kda_chunk_fwd", "kda_state_fwd"), 2, 3),
-    ("mla", ("flash_attention_fwd",), 1, 2)])
+    # of the chunks' forward, one run is the groups' own, which also
+    # writes the inverses for the backward kernel, under a name of its own
+    ("kda", (("kda_chunk_fwd", "kda_chunk_fwd_inverse"),
+             ("kda_state_fwd",)), 2, 3),
+    ("mla", (("flash_attention_fwd",),), 1, 2)])
 def test_the_backward_pass_runs_a_marked_kernels_forward_once_less(
         toy_steps, unit, names, kept_runs, alone_runs):
     layers = sum(mixer == unit for mixer, _ in ref.layer_kinds(CFG))
-    for name in names:
-        assert toy_steps["kept"]["kernels"][name] == layers * kept_runs
-        assert toy_steps["input_alone"]["kernels"][name] == \
-            layers * alone_runs
+    for forms in names:
+        runs = {which: sum(toy_steps[which]["kernels"][name]
+                           for name in forms)
+                for which in ("kept", "input_alone")}
+        assert runs == {"kept": layers * kept_runs,
+                        "input_alone": layers * alone_runs}
+    if unit == "kda":
+        for which in ("kept", "input_alone"):
+            assert toy_steps[which]["kernels"]["kda_chunk_fwd_inverse"] == \
+                layers
     for name in ("kda_chunk_bwd", "kda_state_bwd", "kda_state_fwd_states",
                  "flash_attention_bwd"):
         assert toy_steps["kept"]["kernels"][name] == \
