@@ -16,7 +16,7 @@ from .block import HybridBlock
 __all__ = ["Loss", "L2Loss", "L1Loss",
            "SigmoidBinaryCrossEntropyLoss", "SigmoidBCELoss",
            "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
-           "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
+           "MultiTokenLoss", "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
            "PoissonNLLLoss", "CosineEmbeddingLoss"]
 
@@ -138,6 +138,59 @@ class SoftmaxCrossEntropyLoss(Loss):
 
 
 SoftmaxCELoss = SoftmaxCrossEntropyLoss
+
+
+class MultiTokenLoss(Loss):
+    """Next-token cross-entropy plus ``mtp_weight`` times that of a
+    multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437 section
+    2.2), for a model that returns (logits, the module's logits), both
+    (B, T, V), with labels (B, T) that are each position's next token.
+    The module at position i predicts the token after the next, which is
+    label i + 1; its last position has no target and is left out.  Each
+    term is a mean over its own positions: T, and T - 1.
+
+    With ``head`` — the ``Dense`` block (no bias) both sets of logits
+    come from — the model's outputs are the head's two inputs instead,
+    (B, T, C) each, and each cross-entropy is taken through the head in
+    blocks of rows (``F.linear_cross_entropy``): neither set of logits is
+    ever whole.  The head's parameters stay the model's.
+
+    ``record``, if given, is called in every pass with (positions that
+    entered the module's term, the main term's sum, the module's sum),
+    all on the device (a model's counters: ``PredictionModule.count``).
+    """
+
+    #: SPMDTrainer hands the model's whole output to such a loss
+    accepts_full_output = True
+
+    def __init__(self, mtp_weight=0.3, record=None, head=None,
+                 batch_axis=0, **kwargs):
+        super().__init__(mtp_weight, batch_axis, **kwargs)
+        self._record, self._head = record, head
+
+    def forward(self, outputs, label):
+        main, mtp = outputs
+        return super().forward(main, mtp, label)
+
+    def _cross_entropy(self, F, out, label):
+        if self._head is None:
+            return -F.pick(F.log_softmax(out, axis=-1), label, axis=-1)
+        return F.linear_cross_entropy(
+            out, self._head.weight.data(out.context), label)
+
+    def hybrid_forward(self, F, main_out, mtp_out, label):
+        main = self._cross_entropy(F, main_out, label)
+        # the labels one position on; the module's output is not sliced
+        # (a copy of (B, T - 1, V) logits): its last position, whose
+        # target here is a filler, leaves with the per-position losses
+        ahead = F.concat(label[:, 1:], label[:, :1], dim=1)
+        mtp = self._cross_entropy(F, mtp_out, ahead)[:, :-1]
+        if self._record is not None:
+            count = mtp.shape[0] * mtp.shape[1]
+            self._record(F.full((1,), count, dtype="int32"), F.sum(main),
+                         F.sum(mtp))
+        return F.mean(main, axis=self._batch_axis, exclude=True) \
+            + self._weight * F.mean(mtp, axis=self._batch_axis, exclude=True)
 
 
 class KLDivLoss(Loss):

@@ -184,6 +184,31 @@ class Parameter:
             d._grad = g
             d._grad_req = self._grad_req
 
+    def release_grad(self):
+        """Give up the eager gradient buffers (one more copy of the
+        parameter on the device) while ``grad_req`` stays as it is: for
+        an owner that takes its gradients elsewhere (``SPMDTrainer``:
+        inside its compiled step).  The data stay leaves of the eager
+        tape; whoever asks for the gradient next finds what a backward
+        pass has written since, or zeros."""
+        self._grad = None
+        for d in self._data or ():
+            d._grad = None
+
+    def _ensure_grad(self):
+        """The buffers again after ``release_grad``: what an eager
+        backward pass wrote on the data in the meantime is adopted, not
+        zeroed."""
+        if self._grad is not None or self._data is None \
+                or self._grad_req == "null":
+            return
+        self._grad = [d._grad if d._grad is not None
+                      else NDArray(jnp.zeros(d.shape, d.data.dtype))
+                      for d in self._data]
+        for d, g in zip(self._data, self._grad):
+            d._grad = g
+            d._grad_req = self._grad_req
+
     # -- access -----------------------------------------------------------
     def _check_and_get(self, arr_list, ctx):
         if arr_list is not None:
@@ -220,6 +245,7 @@ class Parameter:
         return self._check_and_get(self._data, list)
 
     def grad(self, ctx=None) -> NDArray:
+        self._ensure_grad()
         if self._data is not None and self._grad is None:
             raise MXTPUError(
                 f"Cannot get gradient array for Parameter {self.name} "
@@ -228,6 +254,7 @@ class Parameter:
         return self._sparsify_grad(g)
 
     def list_grad(self) -> List[NDArray]:
+        self._ensure_grad()
         if self._data is not None and self._grad is None:
             raise MXTPUError(
                 f"Cannot get gradient array for Parameter {self.name} "
@@ -266,6 +293,7 @@ class Parameter:
     def _list_dense_grad(self):
         """Dense grad buffers for kvstore allreduce (the reduced result is
         written back in place; sparse views are re-derived afterwards)."""
+        self._ensure_grad()
         return self._check_and_get(self._grad, list)
 
     def list_ctx(self) -> List[Context]:
@@ -293,8 +321,10 @@ class Parameter:
             d._rebind(jnp.asarray(src, d.data.dtype))
 
     def zero_grad(self):
-        if self._grad is None:
-            return
+        if self._grad is None \
+                and all(d._grad is None for d in self._data or ()):
+            return      # none asked for, or released and none written since
+        self._ensure_grad()
         self._consume_sparse_row_ids()
         for g in self._grad:
             g._rebind(jnp.zeros(g.shape, g.data.dtype))
@@ -323,6 +353,8 @@ class Parameter:
         with autograd.pause():
             self._data = [NDArray(d.data.astype(jnp.dtype(dtype)))
                           for d in self._data]
+            for d in self._data:        # leaves still, buffers or none
+                d._grad_req = self._grad_req
             if self._grad is not None:
                 self._grad = [NDArray(g.data.astype(jnp.dtype(dtype)))
                               for g in self._grad]

@@ -81,19 +81,30 @@ class KDAMixer(HybridBlock):
 
 
 class LatentAttention(HybridBlock):
-    """Multi-head latent attention without positions (NoPE), expanded
-    form: keys and values come up from a normed low-rank latent, every
-    head's key is its own part beside one part shared by all heads,
-    values are narrower than keys; causal flash attention."""
+    """Multi-head latent attention, expanded form: keys and values come
+    up from a normed low-rank latent, every head's key is its own part
+    beside one part shared by all heads, values have a width of their
+    own; causal flash attention.  The query is one full-rank projection,
+    or with ``q_rank`` low-rank too (down, RMSNorm, up).  Without
+    ``rope_base`` there are no positions (NoPE); with it the query's last
+    ``shared_dim`` columns and the shared key part are rotated by their
+    position (``F.rope``: pairs are a column and the one half a width
+    on)."""
 
     def __init__(self, units, num_heads, kv_rank, nope_dim, shared_dim,
-                 v_dim, eps=1e-5, **kwargs):
+                 v_dim, eps=1e-5, q_rank=None, rope_base=None, **kwargs):
         super().__init__(**kwargs)
         self._heads, self._rank = num_heads, kv_rank
         self._nope, self._shared, self._v = nope_dim, shared_dim, v_dim
+        self._q_rank, self._rope = q_rank, rope_base
+        q_units = num_heads * (nope_dim + shared_dim)
         with self.name_scope():
-            self.q_proj = _dense(num_heads * (nope_dim + shared_dim), units,
-                                 "q_")
+            if q_rank is None:
+                self.q_proj = _dense(q_units, units, "q_")
+            else:
+                self.q_a_proj = _dense(q_rank, units, "q_a_")
+                self.q_norm = RMSNorm(q_rank, eps=eps, prefix="q_norm_")
+                self.q_b_proj = _dense(q_units, q_rank, "q_b_")
             self.dkv_proj = _dense(kv_rank + shared_dim, units, "dkv_")
             self.kv_norm = RMSNorm(kv_rank, eps=eps, prefix="kv_norm_")
             self.ukv_proj = _dense(num_heads * (nope_dim + v_dim), kv_rank,
@@ -103,14 +114,21 @@ class LatentAttention(HybridBlock):
     def hybrid_forward(self, F, x):
         B, T, _ = x.shape
         H, dn, ds = self._heads, self._nope, self._shared
-        q = self.q_proj(x).reshape((B, T, H, dn + ds))
+        q = self.q_proj(x) if self._q_rank is None else \
+            self.q_b_proj(self.q_norm(self.q_a_proj(x)))
+        q = q.reshape((B, T, H, dn + ds))
         ckv = self.dkv_proj(x)
         kv = self.ukv_proj(self.kv_norm(ckv[:, :, :self._rank])).reshape(
             (B, T, H, dn + self._v))
-        shared = F.broadcast_to(
-            ckv[:, :, self._rank:].reshape((B, T, 1, ds)), (B, T, H, ds))
+        shared = ckv[:, :, self._rank:]
+        if self._rope is not None:
+            shared = F.rope(shared, base=self._rope)
+        shared = F.broadcast_to(shared.reshape((B, T, 1, ds)), (B, T, H, ds))
         k = F.concat(kv[:, :, :, :dn], shared, dim=-1)
         q, k, v = (a.transpose((0, 2, 1, 3)) for a in (q, k, kv[:, :, :, dn:]))
+        if self._rope is not None:
+            q = F.concat(q[:, :, :, :dn],
+                         F.rope(q[:, :, :, dn:], base=self._rope), dim=-1)
         o = F.flash_attention(q, k, v, causal=True)       # (B, H, T, v_dim)
         return self.out_proj(o.transpose((0, 2, 1, 3)).reshape((B, T, -1)))
 

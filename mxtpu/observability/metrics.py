@@ -46,6 +46,10 @@ source              pulls
                     ``.elsewhere_sum`` — models/kimi_linear.py
                     ``expert_loads``; reads a few numbers a layer
                     from the device)
+``mtp``             the multi-token-prediction module's counters
+                    (``mtp.positions``, ``mtp.loss_sum``,
+                    ``mtp.main_loss_sum`` — models/glm4_moe_lite.py
+                    ``mtp_counts``; three numbers from the device)
 ==================  ====================================================
 
 Live objects (engines, gateways, supervisors, routers) register with
@@ -265,9 +269,20 @@ def _src_moe() -> dict:
     ``.held_sum`` / ``.elsewhere_sum``): kept on the device by the layer,
     read here (models/kimi_linear.py ``expert_loads``)."""
     from ..models.kimi_linear import expert_loads
-    return {layer: {name: dict(enumerate(v)) if isinstance(v, list) else v
+    return {layer: {name: {str(i): x for i, x in enumerate(v)}
+                    if isinstance(v, list) else v
                     for name, v in load.items()}
             for layer, load in expert_loads().items()}
+
+
+def _src_mtp() -> dict:
+    """The prediction modules' counters since their start:
+    ``mtp.positions`` that entered the module's loss, and the sums of
+    the module's and the main cross-entropies (``mtp.loss_sum``,
+    ``mtp.main_loss_sum``): a program that drops the second loss is seen
+    without a reference (models/glm4_moe_lite.py ``mtp_counts``)."""
+    from ..models.glm4_moe_lite import mtp_counts
+    return mtp_counts()
 
 
 def default_registry() -> MetricsRegistry:
@@ -284,6 +299,7 @@ def default_registry() -> MetricsRegistry:
     reg.register_source("remat", _src_remat)
     reg.register_source("lifecycle", _src_lifecycle)
     reg.register_source("moe", _src_moe)
+    reg.register_source("mtp", _src_mtp)
     return reg
 
 

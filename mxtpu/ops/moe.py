@@ -137,10 +137,27 @@ def switch_moe(x, router_w, w1, w2, capacity_factor=1.25,
 
 
 #: sorted (token, expert) pairs that go through the grouped products at a
-#: time.  The held pairs are taken in as many tiles as they fill, so time
-#: and memory follow the load this share really has; a share that every
-#: token chose for every slot just takes more tiles.
+#: time, as a measure: a tile takes up to half as many again
+#: (``_tile_rows``).  The held pairs are taken in as many tiles as they
+#: fill, so time and memory follow the load this share really has; a
+#: share that every token chose for every slot just takes more tiles.
 PAIRS_PER_TILE = 4096
+
+
+def _tile_rows(pairs, expected):
+    """Rows of a tile for ``pairs`` (token, expert) pairs of which an
+    even router sends ``expected`` to this share.  A tile costs what its
+    rows cost however few pairs it holds, and large tiles run the grouped
+    products nearer their ceiling than small ones, so the tiles are the
+    fewest, of at most one and a half ``PAIRS_PER_TILE``, that hold the
+    even load and half a ``PAIRS_PER_TILE`` more (in whole quarters of
+    it): the even load never ends at a tile's edge, where every
+    fluctuation of the routers would decide whether one more tile runs.
+    2,048 expected pairs take one tile of 4,096, and 4,096 one of 6,144."""
+    need = expected + PAIRS_PER_TILE // 2
+    n = math.ceil(need / (1.5 * PAIRS_PER_TILE))
+    quarter = max(1, PAIRS_PER_TILE // 4)
+    return min(math.ceil(need / n / quarter) * quarter, pairs)
 
 
 def _tile(xf, pair_weight, w_gate, w_up, w_down, order, bounds, i, k, rows,
@@ -220,8 +237,9 @@ def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
     ``sigmoid(x router_w^T) + select_bias`` and weighs them by the
     sigmoid scores themselves, renormalised over all the chosen (held
     here or not) and scaled by ``scale``.  The (token, expert) pairs that
-    fall to held experts are sorted by expert and go, ``PAIRS_PER_TILE``
-    at a time, through three grouped products (``lax.ragged_dot``),
+    fall to held experts are sorted by expert and go, a tile at a time
+    (``_tile_rows``: about ``PAIRS_PER_TILE``), through three grouped
+    products (``lax.ragged_dot``),
     ``w_down(silu(w_gate x) * w_up x)``; there is no capacity and no
     pair is dropped.  Pairs that fall to experts held elsewhere add
     nothing here: on one chip there is no exchange.
@@ -247,7 +265,7 @@ def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
     mine = (local >= 0) & (local < held)
     group = jnp.where(mine, local, held)          # elsewhere: sorted last
     load = jnp.sum(jax.nn.one_hot(group, held + 1, dtype=jnp.int32), 0)
-    rows = min(PAIRS_PER_TILE, group.size)
+    rows = _tile_rows(group.size, group.size * held / router_w.shape[0])
     order = jnp.argsort(group, stable=True).astype(jnp.int32)
     order = jnp.pad(order, (0, (-order.size) % rows))
     bounds = jnp.concatenate([jnp.zeros(1, jnp.int32),

@@ -315,6 +315,44 @@ def softmax_cross_entropy(data, label):
     return jnp.sum(nll)
 
 
+#: rows of a head's logits that ``linear_cross_entropy`` forms at a time
+CROSS_ENTROPY_ROWS = 1024
+
+
+@register_op("linear_cross_entropy")
+def linear_cross_entropy(data, weight, label,
+                         block_rows=CROSS_ENTROPY_ROWS):
+    """Softmax cross-entropy of a linear head, ``-log softmax(data
+    weight^T)[label]`` for every row, without the logits: ``data``
+    (.., C), ``weight`` (V, C) as ``FullyConnected`` lays it, ``label``
+    (..) class indices; returns (..).  The rows go through in blocks of
+    at most ``block_rows`` and each block is formed again in the
+    backward pass, so a block's (rows, V) logits are alive at a time —
+    where a head's whole logits (8,192 x 19,360 float32 are 634 MB, and
+    their log-softmax and cotangent as much again) would not fit.  The
+    price is the head's product a second time."""
+    from .tensor import matmul_precision
+
+    lead, width = data.shape[:-1], data.shape[-1]
+    x = data.reshape(-1, width)
+    y = label.astype(jnp.int32).reshape(-1)
+    total = x.shape[0]
+    rows = max(r for r in range(1, min(int(block_rows), total) + 1)
+               if total % r == 0)
+
+    @jax.checkpoint
+    def block(start):
+        xb = lax.dynamic_slice_in_dim(x, start, rows, 0)
+        yb = lax.dynamic_slice_in_dim(y, start, rows, 0)
+        logits = jnp.matmul(xb, weight.T,
+                            precision=matmul_precision(xb, weight))
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
+
+    nll = lax.map(block, jnp.arange(0, total, rows))
+    return nll.reshape(lead).astype(data.dtype)
+
+
 # ---------------------------------------------------------------------------
 # normalisation
 # ---------------------------------------------------------------------------

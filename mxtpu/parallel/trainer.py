@@ -152,6 +152,11 @@ class SPMDTrainer:
         self._diff_params = [p for p in params if p.grad_req != "null"]
         self._aux_params = [p for p in params if p.grad_req == "null"]
         jm = self._mesh.jax_mesh
+        for p in self._diff_params:
+            # the step takes its gradients inside the compiled program:
+            # the eager buffers would be one more copy of the model on
+            # the device that nothing reads
+            p.release_grad()
         for p in self._diff_params + self._aux_params:
             holder = p.data()
             sh = self._rules.sharding_for(p.name, holder.ndim, self._mesh) \

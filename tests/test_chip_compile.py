@@ -9,7 +9,9 @@ and four of them were refused by Mosaic.
 Geometries are the real ones — BERT-base training (batch 32, 12 heads
 of 64, seq 128 in bfloat16 and the benchmark cell's seq 512 in float32),
 Kimi-Linear's cell (32 heads at 8,192 positions: latent attention with
-keys of 192 and values of 128, KDA's state kernels at 128) and
+keys of 192 and values of 128, KDA's state kernels at 128),
+GLM-4.7-Flash's cell (20 heads at 8,192 positions, keys and values of
+256, and its whole training step, for the compiler's memory bound) and
 Llama-3-8B serving (32 Q / 8 KV heads of 128).
 Nothing runs, so results are covered by the interpret-mode suites
 (test_flash_attention / test_paged_attention_pallas /
@@ -124,6 +126,77 @@ def test_flash_attention_with_narrower_values_at_8k(on_chip):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, q, v).compile().as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+
+
+def test_flash_attention_with_keys_and_values_of_256_at_8k(on_chip):
+    """Rotary latent attention of the GLM-4.7-Flash cell: one row of 20
+    heads, 8,192 positions, keys of 192 + 64 and values of 256, float32,
+    causal: whole heads in VMEM still (the forward asks for 57 MiB, the
+    backward for 94 of the chip's 128)."""
+    q = _shape((1, 20, 8192, 256), F32, on_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile()
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert fa._vmem_limit(fa._bwd_vmem(8192, 512, 256, 256, "float32")) \
+        < 128 * 2 ** 20
+
+
+def test_the_whole_glm_step_fits_the_chip(on_chip, record_property):
+    """``SPMDTrainer``'s step of the GLM-4.7-Flash cell at its own
+    sizes (706.5 M trained parameters, one sequence of 8,192, Adam,
+    recomputation per unit, the two cross-entropies through the head in
+    blocks of rows) compiles for the described chip inside its memory:
+    8.48 GB of weights and Adam state beside the compiler's bound on
+    everything else.  With both sets of logits whole the bound is
+    16.3 GB of the 16.9 the chip gives (PERF.md, PR 33)."""
+    import mxtpu as mx
+    from chipbench import harness, models
+    from mxtpu.models.glm4_moe_lite import glm4_moe_lite_from_config
+    from mxtpu.parallel import SPMDTrainer
+
+    cfg = harness.load_json(harness.HERE, "configs", "glm-4.7-flash.json")
+    net = glm4_moe_lite_from_config(
+        cfg, held=(cfg["held_experts_first"], cfg["n_routed_experts"]),
+        num_experts_total=cfg["num_experts_total"], return_logits=False)
+    net.initialize(mx.init.Zero())
+    trainer = SPMDTrainer(
+        net, net.loss(cfg["mtp_weight"]), cfg["train"]["optimizer"],
+        models.one_chip_mesh(jax.devices()[:1]),
+        optimizer_params={"learning_rate": cfg["train"]["learning_rate"]},
+        remat=cfg["train"]["remat"])
+    trainer._stage_params()             # no eager forward: shapes are known
+    step = trainer._make_step_fns()[0]
+    like = lambda a: _shape(a.shape, a.dtype, on_chip)
+    scalar, tokens = _shape((), F32, on_chip), _shape((1, 8192), jnp.int32,
+                                                      on_chip)
+    args = [tuple(like(p.data()._data) for p in trainer._diff_params),
+            tuple(like(p.data()._data) for p in trainer._aux_params),
+            jax.tree_util.tree_map(like, tuple(trainer._opt_states)),
+            scalar, scalar, tokens, tokens,
+            _shape((2,), jnp.uint32, on_chip)]
+    assert sum(a.size for a in args[0]) == cfg["trained_parameters"]
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("flash_attention_fwd") >= 6
+    assert text.count("flash_attention_bwd") >= 6
+    m = compiled.memory_analysis()
+    bound = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    record_property("glm_step_code_bytes", m.generated_code_size_in_bytes)
+    record_property("glm_step_memory_bound_bytes", bound)
+    print("GLM step: code %.3f GB, arguments %.3f GB, temporaries %.3f GB, "
+          "bound %.3f GB" % (m.generated_code_size_in_bytes / 1e9,
+                             m.argument_size_in_bytes / 1e9,
+                             m.temp_size_in_bytes / 1e9, bound / 1e9))
+    # weights and Adam's two moments, 12 B a parameter, and little else
+    assert 0 <= m.argument_size_in_bytes \
+        - 12 * cfg["trained_parameters"] < 2 ** 20
+    assert bound < 15.9e9               # the chip gives 16.909 GB
 
 
 @pytest.mark.parametrize("T", [8192, 96], ids=["cell", "toy"])

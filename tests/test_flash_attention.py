@@ -253,8 +253,11 @@ def test_flash_shard_mapped_under_a_sharding_scope(qkv, dp, tp):
     (150, 192, 128, True, dict(q_block=64, kv_block=128)),
     (200, 48, 16, False, {}),
     (256, 64, 64, False, {}),                           # BERT's: one width
+    (256, 256, 256, True, {}),          # rotary latent attention: 192 + 64
+    (300, 256, 256, True, dict(q_block=128, kv_block=64)),
 ], ids=["causal_D192_Dv128_T256", "causal_D192_Dv128_T150_q64_k128",
-        "D48_Dv16_T200", "D64_Dv64_T256"])
+        "D48_Dv16_T200", "D64_Dv64_T256", "causal_D256_Dv256_T256",
+        "causal_D256_Dv256_T300_q128_k64"])
 def test_flash_with_a_value_width_of_its_own(direction, T, D, Dv, causal,
                                              blocks):
     rng = np.random.RandomState(T + D)
@@ -293,6 +296,13 @@ def test_a_long_head_asks_for_its_vmem_and_a_short_one_for_nothing():
     assert 24 * 2 ** 20 < asked.vmem_limit_bytes < 128 * 2 ** 20
     back = fa._compiler_params(fa._bwd_vmem(8192, 512, 192, 128, "float32"))
     assert asked.vmem_limit_bytes < back.vmem_limit_bytes < 128 * 2 ** 20
+    # keys and values of 256: 16 MiB of K and V a head, 8 MiB each of Q,
+    # dO, dQ and its accumulator; still inside the chip's 128 MiB
+    wide = fa._compiler_params(fa._fwd_vmem(128, 8192, 256, 256, "float32"))
+    wide_back = fa._compiler_params(fa._bwd_vmem(8192, 512, 256, 256,
+                                                 "float32"))
+    assert asked.vmem_limit_bytes < wide.vmem_limit_bytes \
+        < wide_back.vmem_limit_bytes < 128 * 2 ** 20
 
 
 # ---------------------------- under a unit of recomputation (ops/remat)

@@ -51,7 +51,8 @@ def share(w, x, first, held):
         held_first=first, top_k=K, scale=SCALE)
 
 
-@pytest.mark.parametrize("tile", [4096, 48], ids=["one_tile", "many_tiles"])
+@pytest.mark.parametrize("tile", [4096, 48],
+                         ids=["wide_tiles", "narrow_tiles"])
 @pytest.mark.parametrize("skew", [0.0, 4.0], ids=["even", "skewed"])
 @pytest.mark.parametrize("shares", [1, 4], ids=["whole", "four_shares"])
 def test_the_shares_add_up_to_the_uncut_layer(monkeypatch, shares, skew,
@@ -78,7 +79,8 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch, shares, skew,
         assert load[3] == x.shape[0] * x.shape[1]          # every token
 
 
-@pytest.mark.parametrize("tile", [4096, 48], ids=["one_tile", "many_tiles"])
+@pytest.mark.parametrize("tile", [4096, 48],
+                         ids=["wide_tiles", "narrow_tiles"])
 def test_gradients_of_a_share_match_the_reference(monkeypatch, tile):
     monkeypatch.setattr(moe, "PAIRS_PER_TILE", tile)
     w = weights(skew=1.0, seed=1)
@@ -100,6 +102,27 @@ def test_gradients_of_a_share_match_the_reference(monkeypatch, tile):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4,
                                    atol=1e-5 * float(jnp.max(jnp.abs(r))),
                                    err_msg=name)
+
+
+@pytest.mark.parametrize("pairs,held,total,want", [
+    (8192 * 8, 8, 256, 4096),    # 2,048 expected: half of one tile
+    (8192 * 4, 8, 64, 6144),     # 4,096 expected: two thirds of one tile
+    (2 * 8192 * 4, 8, 64, 5120),        # 8,192: two tiles, 1.6 filled
+    (3 * 8192 * 4, 8, 64, 5120),        # 12,288: three tiles, 2.4 filled
+    (32 * 8192 * 8, 8, 256, 6144),      # 65,536: eleven tiles
+    (4096 * 8, 8, 256, 3072),    # a shorter sequence takes a smaller tile
+    (320, 4, 16, 320),           # fewer pairs than a tile: toy sizes
+], ids=["kimi_linear_cell", "glm_cell", "two_sequences", "three_sequences",
+        "thirty_two_sequences", "half_a_sequence", "toy"])
+def test_the_even_load_never_ends_at_a_tiles_edge(pairs, held, total, want):
+    expected = pairs * held / total
+    rows = moe._tile_rows(pairs, expected)
+    assert rows == want and rows <= 1.5 * moe.PAIRS_PER_TILE
+    if rows < pairs:
+        # half a PAIRS_PER_TILE of room in the last tile the load reaches
+        tiles = -(-expected // rows)
+        assert tiles * rows - expected >= moe.PAIRS_PER_TILE // 2
+        assert expected - (tiles - 1) * rows > 0
 
 
 def test_the_selection_bias_chooses_and_takes_no_gradient():

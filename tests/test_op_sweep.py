@@ -368,6 +368,8 @@ CASES.update({
     "softmin": C(lambda: (A(3, 4),)),
     "softmax_cross_entropy": C(lambda: (A(3, 5), IDX(3, n=5)),
                                grad_args=(0,)),
+    "linear_cross_entropy": C(lambda: (A(6, 4), A(5, 4), IDX(6, n=5)),
+                              {"block_rows": 2}, grad_args=(0, 1)),
     "LayerNorm": C(lambda: (A(3, 8), POS(8), A(8))),
     "GroupNorm": C(lambda: (A(2, 4, 3, 3), POS(4), A(4)),
                    {"num_groups": 2}),

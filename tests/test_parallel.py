@@ -373,3 +373,63 @@ def test_spmd_trainer_global_norm_clip():
         expect = w0[n] - lr * grads[n] * scale
         np.testing.assert_allclose(got[n], expect, rtol=1e-4,
                                    atol=1e-5)
+
+
+@pytest.mark.parametrize("reader", ["grad", "list_grad", "gluon_trainer",
+                                    "zero_grad", "cast"])
+def test_eager_gradients_survive_the_spmd_trainers_release(reader):
+    """``SPMDTrainer`` gives up the parameters' eager gradient buffers
+    when it stages (``Parameter.release_grad``).  An eager backward pass
+    on the same net afterwards writes its gradients on the data: whoever
+    asks next (``grad``, ``list_grad``, ``gluon.Trainer``'s dense list,
+    ``zero_grad``) gets those, not fresh zeros."""
+    from mxtpu import autograd
+    np.random.seed(5)
+    X = mx.nd.array(np.random.randn(16, 6).astype("float32"))
+    y = mx.nd.array((np.random.rand(16) * 3).astype("int32"))
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def build():
+        np.random.seed(11)
+        mx.random.seed(11)
+        net = nn.HybridSequential()
+        net.add(nn.Dense(12, activation="relu", in_units=6),
+                nn.Dense(3, in_units=12))
+        net.initialize(force_reinit=True)
+        return net
+
+    def backward(net):
+        with autograd.record():
+            loss = loss_fn(net(X), y)
+        loss.backward()
+
+    plain = build()
+    backward(plain)
+    want = [p.grad().asnumpy() for p in plain.collect_params().values()]
+    assert all(np.abs(g).max() > 0 for g in want)
+
+    net = build()
+    trainer = SPMDTrainer(net, loss_fn, "sgd", make_mesh(dp=1), None,
+                          {"learning_rate": 0.0})
+    trainer.step(X, y)                  # stages, releases; moves nothing
+    params = list(net.collect_params().values())
+    assert all(p._grad is None for p in params)
+    if reader == "cast":                # released AND re-made data
+        for p in params:
+            p.cast("float32")
+    backward(net)
+    if reader in ("grad", "cast"):
+        got = [p.grad().asnumpy() for p in params]
+    elif reader == "list_grad":
+        got = [p.list_grad()[0].asnumpy() for p in params]
+    elif reader == "gluon_trainer":
+        before = [p.data().asnumpy() for p in params]
+        gluon.Trainer(net.collect_params(), "sgd",
+                      {"learning_rate": 1.0}).step(1)
+        got = [b - p.data().asnumpy() for b, p in zip(before, params)]
+    else:
+        net.collect_params().zero_grad()
+        got = [p.grad().asnumpy() for p in params]
+        want = [np.zeros_like(g) for g in want]
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
