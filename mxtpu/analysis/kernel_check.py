@@ -613,10 +613,12 @@ def default_kernel_specs() -> List[KernelSpec]:
     kernel``) verdicts as the merge gate:
 
     - flash_attention fwd + the one backward kernel, fp32 training
-      shape and the bf16 serving-prefill shape (T=2048, D=128, 128/128
-      blocks forward, the backward's 512 x 512 tiles), and latent
-      attention's (T=8192, keys 192, values 128, fp32: whole heads in
-      VMEM, asked for through ``vmem_limit``);
+      shape and the bf16 serving-prefill shape (T=2048, D=128, blocks
+      of 128, both kernels' 512 x 512 tiles), and the three benchmark
+      cells' calls, fp32: BERT's (384 heads x 512 x 64, one tile a
+      head), latent attention's (T=8192, keys 192, values 128) and
+      rotary latent attention's (T=8192, keys and values 256), whole
+      heads in VMEM, asked for through ``vmem_limit``;
     - KDA's kernels (the chunks' operands forward and backward, the
       state pass forward writing states and backward) at 8,192 positions
       and at a toy length, heads and chunks of 128 x 64;
@@ -650,11 +652,17 @@ def default_kernel_specs() -> List[KernelSpec]:
     for dtype in ("float32", "bfloat16"):
         specs.extend(flash_attention.kernel_specs(
             B=4, H=8, T=2048, D=128, dtype=dtype))
-    # latent attention of the Kimi-Linear cell: keys 192, values 128,
-    # 8,192 positions; and KDA's kernels there (4 heads a call) and at
-    # the toy length of the CPU tests
+    # the benchmark's cells: BERT's 32 x 12 heads of 64 at 512
+    # positions, latent attention of the Kimi-Linear cell (keys 192,
+    # values 128, 8,192 positions) and of the GLM-4.7-Flash cell (256 /
+    # 256); and KDA's kernels (4 heads a call) at the cell's length and
+    # at the toy length of the CPU tests
+    specs.extend(flash_attention.kernel_specs(
+        B=32, H=12, T=512, D=64, dtype="float32"))
     specs.extend(flash_attention.kernel_specs(
         B=1, H=32, T=8192, D=192, Dv=128, dtype="float32"))
+    specs.extend(flash_attention.kernel_specs(
+        B=1, H=20, T=8192, D=256, dtype="float32"))
     for T in (8192, 96):
         specs.extend(kda.kernel_specs(B=1, H=4, T=T, K=128))
     for cache_dtype, block_size in (("float32", 16), ("int8", 32)):

@@ -2,9 +2,13 @@
 
 Replaces the reference's fused interleaved-MHA CUDA kernels
 (src/operator/contrib/transformer.cc) with the memory-optimal streaming
-algorithm: Q blocks stay resident in VMEM while K/V blocks stream through,
-softmax runs in online (max/denominator-carrying) form, so HBM traffic is
-O(T·D) instead of O(T²).
+algorithm: a tile of Q stays resident in VMEM while tiles of K/V stream
+through, softmax runs in online (max/denominator-carrying) form, so HBM
+traffic is O(T·D) instead of O(T²).  The caller's ``q_block`` /
+``kv_block`` are the granule the sequence is padded to; both kernels
+walk the widest run of whole blocks up to ``TILE`` rows, on transposed
+tiles (S^T = K Q^T); the forward visits of a causal call's diagonal
+tile only the squares the mask leaves.
 
 Backward (SURVEY §7 hard-part 7) is the FlashAttention-2 formulation in
 one Pallas kernel: the forward additionally emits the per-row
@@ -56,14 +60,15 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
     Tq = math.ceil(T / qb) * qb
     Tk = math.ceil(T / kb) * kb
     BH = B * H
+    qb, kb = _tile(qb, Tq), _tile(kb, Tk)
+    nq = Tq // qb
 
     def blk(name, kind, shape, array, dt, imap):
         # the q/kv block tiles are chosen parameters, strict on the
         # sublane dim.  The head width is the arrays' whole last axis:
         # VMEM pads it to the lanes, and the chip's compiler takes 64
         # (BERT's cell) and 192 (latent attention) as it takes 128
-        # (tests/test_chip_compile.py); the forward's lse column carries
-        # a trailing unit dim likewise
+        # (tests/test_chip_compile.py)
         return BlockOperand(name, kind, shape, array, dt, imap,
                             strict_dims=(-2,))
 
@@ -71,22 +76,23 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
     full_im = lambda b, i: (b, 0, 0)   # noqa: E731
     tag = "[%s,T=%d,D=%d]" % (dtype, T, D) if Dv == D else \
         "[%s,T=%d,D=%d,Dv=%d]" % (dtype, T, D, Dv)
+    # lse lies along lanes, a row a Q tile: its block is the head's,
+    # resident over the Q-tile axis (the grid's innermost: K006 holds)
     specs = [KernelSpec(
         "flash_attention.fwd" + tag,
-        grid=(BH, Tq // qb),
+        grid=(BH, nq),
         operands=[
             blk("q", "in", (1, qb, D), (BH, Tq, D), dtype, q_im),
             blk("k", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
             blk("v", "in", (1, Tk, Dv), (BH, Tk, Dv), dtype, full_im),
             blk("o", "out", (1, qb, Dv), (BH, Tq, Dv), dtype, q_im),
-            blk("lse", "out", (1, qb, 1), (BH, Tq, 1), "float32", q_im),
+            BlockOperand("lse", "out", (1, nq, qb), (BH, nq, qb),
+                         "float32", full_im),
         ],
         interpret=interpret,
-        vmem_limit=_vmem_limit(_fwd_vmem(qb, Tk, D, Dv, dtype)))]
+        vmem_limit=_vmem_limit(_fwd_vmem(qb, kb, Tk, D, Dv, dtype)))]
     if not backward:
         return specs
-    qb, kb = _bwd_tile(qb, Tq), _bwd_tile(kb, Tk)
-    nq = Tq // qb
     kv_im = lambda b, j: (b, j, 0)     # noqa: E731 — mirrors _flash_bwd
     # dq's block is resident over the kv-block axis — the grid's
     # innermost, so K006 holds — beside its float32 accumulator; lse and
@@ -117,7 +123,9 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
 # (backward) whole in VMEM.  Up to the compiler's own limit per kernel
 # nothing is asked for; a longer head (8,192 x 192 float32 is 8 MiB an
 # operand, as VMEM pads its lanes to 128) asks for what its blocks take,
-# twice over for the pipeline's second buffer, and some room.
+# twice over for the pipeline's second buffer, what the forward's tile
+# forms (3.5 MiB at 512 x 512), and some room: 64 MiB forward and 94 MiB
+# backward at 8,192 x 256 / 256, of the chip's 128.
 
 _SCOPED_VMEM = 16 * (1 << 20)
 
@@ -126,10 +134,15 @@ def _padded(rows, cols, dtype):
     return rows * (-(-cols // 128) * 128) * jnp.dtype(dtype).itemsize
 
 
-def _fwd_vmem(q_block, Tk, D, Dv, dtype):
-    return 2 * (_padded(Tk, D, dtype) + _padded(Tk, Dv, dtype)
-                + _padded(q_block, D, dtype) + _padded(q_block, Dv, dtype)
-                + _padded(q_block, 1, "float32"))
+def _fwd_vmem(q_tile, kv_tile, Tk, D, Dv, dtype):
+    """The forward's blocks, twice buffered (lse's: the head's rows of
+    lanes, Tq reckoned as Tk), and what a trip forms of its tile:
+    scores, probabilities and the probabilities' three bf16 pieces (14 B
+    an element), O^T before and after its rescale."""
+    blocks = 2 * (_padded(Tk, D, dtype) + _padded(Tk, Dv, dtype)
+                  + _padded(q_tile, D, dtype) + _padded(q_tile, Dv, dtype)
+                  + _padded(8 + Tk // q_tile, q_tile, "float32"))
+    return blocks + 14 * kv_tile * q_tile + 2 * _padded(Dv, q_tile, "float32")
 
 
 def _bwd_vmem(Tq, kv_block, D, Dv, dtype):
@@ -154,56 +167,106 @@ def _compiler_params(needed):
         vmem_limit_bytes=limit)
 
 
+#: both kernels' tiles grow to this many rows, q side and kv side.  On a
+#: v5e, float32, the kernel alone, ms a call.  The backward (PERF.md,
+#: PR 28), 384 heads x 512 x 64: 4.05 at 512 x 512 where 128 x 128 took
+#: 5.46.  The forward (PERF.md, PR 34), at tiles of 128 in rows (as
+#: before PR 34) / 512 in rows / 256 transposed / 512 transposed / 1,024
+#: transposed: 20 heads x 8,192 x 256 / 256 causal 31.74 / 24.19 / 24.10
+#: / 23.71 / 30.19; 32 x 8,192 x 192 / 128 causal 47.36 / 28.92 / 29.40
+#: / 28.50 / 36.09; 384 x 512 x 64 not causal 3.74 / 1.69 / 1.66 / 1.37.
+#: 512 x 256 and 256 x 512 lie between 256 and 512.  Masking the tile on
+#: the diagonal alone instead of every tile moved nothing (23.71 /
+#: 23.66): every trip is masked by what the call is, as the backward's
+TILE = 512
+#: the key steps in which a causal forward walks its diagonal tile, each
+#: against the queries from its own first on: 10 of the tile's 16 squares
+#: of 128.  The two causal figures above at steps of 512 (the whole
+#: tile) / 256 / 128: 23.71 / 22.88 / 22.68 and 28.50 / 27.55 / 27.27 ms
+#: (22.69 and 27.34 as the kernel stands, every trip masked)
+FWD_DIAG = 128
+
+
+def _tile(block, padded):
+    """The widest run of whole blocks that is at most ``TILE`` rows (one
+    block where a block is wider) and tiles ``padded``."""
+    n = padded // block
+    return block * max(m for m in range(1, n + 1)
+                       if n % m == 0 and (m == 1 or block * m <= TILE))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 q_block, kv_block, seq_len, valid_len, hi_prec):
+    """One tile of queries against the head's keys, transposed as the
+    backward's tiles are: S^T = K Q^T, so the running maximum, the
+    denominator and the rescale are (1, Bq) rows on the lanes, and
+    O^T = sum V^T P^T is turned once, when the tile leaves.  A causal
+    call with square tiles walks the tiles below the diagonal and then
+    the diagonal one in steps of FWD_DIAG keys, each against the queries
+    from its own first on."""
     # fp32 inputs keep true-fp32 dots; bf16 inputs use the fast MXU default
     # (jax>=0.9 interpret mode emulates TPU bf16 default precision, so the
     # fp32 contract must be explicit)
     prec = jax.lax.Precision.HIGHEST if hi_prec else None
+    dot = functools.partial(jax.lax.dot_general, precision=prec,
+                            preferred_element_type=jnp.float32)
+    a_bt = (((1,), (1,)), ((), ()))               # a @ b.T
+    at_b = (((0,), (0,)), ((), ()))               # a.T @ b
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale          # (Bq, D)
-    bq, d = q.shape
-    nkv_total = seq_len // kv_block
-    if causal:
-        # kv blocks strictly below the diagonal run unmasked; the block
-        # overlapping the diagonal gets the triangular mask
-        nkv = jnp.minimum(((qi + 1) * q_block + kv_block - 1) // kv_block,
-                          nkv_total)
-    else:
-        nkv = nkv_total
+    bq = q.shape[0]
 
-    def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[0, pl.ds(j * kv_block, kv_block), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * kv_block, kv_block), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                    precision=prec)  # (Bq, Bkv)
-        k_pos = j * kv_block + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, kv_block), 1)
+    def visit(carry, start, size, first=0):
+        """Keys [start, start + size) against the tile's queries from
+        its ``first``-th on: the online softmax's update of their part
+        of (m, l, O^T)."""
+        rows = pl.ds(pl.multiple_of(start, size), size)
+        k = k_ref[0, rows, :].astype(jnp.float32)
+        v = v_ref[0, rows, :].astype(jnp.float32)
+        m, l, acc = (a[:, first:] for a in carry)
+        st = dot(k, q[first:], a_bt)                  # (size, Bq - first)
+        if causal or valid_len != seq_len:
+            k_pos = start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
         if valid_len != seq_len:  # zero-padded keys must not attend
-            s = jnp.where(k_pos < valid_len, s, _NEG_INF)
+            st = jnp.where(k_pos < valid_len, st, _NEG_INF)
         if causal:
-            q_pos = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, kv_block), 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_blk)
-        p = jnp.exp(s - m_new)
+            q_pos = qi * q_block + first + jax.lax.broadcasted_iota(
+                jnp.int32, st.shape, 1)
+            st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
+        pt = jnp.exp(st - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * corr + jnp.dot(p, v,
-                                       preferred_element_type=jnp.float32,
-                                       precision=prec)
-        return m_new, l_new, acc_new
+        new = (m_new, l * corr + jnp.sum(pt, axis=0, keepdims=True),
+               acc * corr + dot(v, pt, at_b))         # O^T: (Dv, Bq - first)
+        if first:
+            new = tuple(jnp.concatenate([a[:, :first], b], axis=1)
+                        for a, b in zip(carry, new))
+        return new
 
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nkv, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    def tile(j, carry):
+        return visit(carry, j * kv_block, kv_block)
+
+    carry = (jnp.full((1, bq), _NEG_INF, jnp.float32),
+             jnp.zeros((1, bq), jnp.float32),
+             jnp.zeros((v_ref.shape[-1], bq), jnp.float32))
+    if causal and q_block == kv_block:
+        # square tiles: qi below the diagonal, then the diagonal one
+        carry = jax.lax.fori_loop(0, qi, tile, carry)
+        step = FWD_DIAG if bq % FWD_DIAG == 0 else bq
+        for first in range(0, bq, step):
+            carry = visit(carry, qi * q_block + first, step, first)
+    else:
+        nkv = seq_len // kv_block
+        if causal:  # the walk stops at the tile that holds the last row
+            nkv = jnp.minimum(pl.cdiv((qi + 1) * q_block, kv_block), nkv)
+        carry = jax.lax.fori_loop(0, nkv, tile, carry)
+    m, l, acc = carry
+    l = jnp.maximum(l, 1e-30)
+    o_ref[0] = (acc / l).T.astype(o_ref.dtype)
     # logsumexp residual for the Pallas backward (fp32; the softmax is
-    # re-derived there as exp(s - lse) without a second online pass)
-    lse_ref[0] = m + jnp.log(jnp.maximum(l, 1e-30))
+    # re-derived there as exp(s - lse) without a second online pass): a
+    # row of the head's block, which leaves with the head's last tile
+    lse_ref[0, pl.ds(qi, 1), :] = m + jnp.log(l)
 
 
 def _pad_to(x, axis, multiple):
@@ -224,29 +287,35 @@ def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
     vp, _ = _pad_to(v, 2, kv_block)
     Tq = qp.shape[2]
     Tk = kp.shape[2]
-    qp = qp.reshape(B * H, Tq, D)
-    kp = kp.reshape(B * H, Tk, D)
-    vp = vp.reshape(B * H, Tk, Dv)
+    # the blocks are the granule of the padding; the kernel walks tiles
+    q_block = _tile(q_block, Tq)
+    kv_block = _tile(kv_block, Tk)
+    BH = B * H
+    qp = qp.reshape(BH, Tq, D)
+    kp = kp.reshape(BH, Tk, D)
+    vp = vp.reshape(BH, Tk, Dv)
 
-    grid = (B * H, Tq // q_block)
+    nq = Tq // q_block
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                q_block=q_block, kv_block=kv_block,
                                seq_len=Tk, valid_len=T,
                                hi_prec=q.dtype == jnp.float32)
+    tile = lambda b, i: (b, i, 0)           # noqa: E731
+    head = lambda b, i: (b, 0, 0)           # noqa: E731 — resident over i
     out, lse = pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((B * H, Tq, Dv), q.dtype),
-                   jax.ShapeDtypeStruct((B * H, Tq, 1), jnp.float32)],
-        grid=grid,
+        out_shape=[jax.ShapeDtypeStruct((BH, Tq, Dv), q.dtype),
+                   jax.ShapeDtypeStruct((BH, nq, q_block), jnp.float32)],
+        grid=(BH, nq),
         in_specs=[
-            pl.BlockSpec((1, q_block, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, Dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, q_block, D), tile),
+            pl.BlockSpec((1, Tk, D), head),
+            pl.BlockSpec((1, Tk, Dv), head),
         ],
-        out_specs=[pl.BlockSpec((1, q_block, Dv), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, q_block, 1), lambda b, i: (b, i, 0))],
+        out_specs=[pl.BlockSpec((1, q_block, Dv), tile),
+                   pl.BlockSpec((1, nq, q_block), head)],
         compiler_params=_compiler_params(
-            _fwd_vmem(q_block, Tk, D, Dv, q.dtype)),
+            _fwd_vmem(q_block, kv_block, Tk, D, Dv, q.dtype)),
         interpret=interpret,
         name="flash_attention_fwd",
     )(qp, kp, vp)
@@ -317,20 +386,6 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = (scale * dq_acc[...]).astype(dq_ref.dtype)
 
 
-#: the backward's tiles grow to this many rows: at 512 x 512 the one
-#: kernel took 4.05 ms a call on a v5e where 128 x 128 took 5.46 (384
-#: heads x 512 x 64 float32; PERF.md, PR 28)
-BWD_TILE = 512
-
-
-def _bwd_tile(block, padded):
-    """The widest run of whole forward blocks that is at most BWD_TILE
-    rows (one block where a block is wider) and tiles ``padded``."""
-    n = padded // block
-    return block * max(m for m in range(1, n + 1)
-                       if n % m == 0 and (m == 1 or block * m <= BWD_TILE))
-
-
 def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
                interpret):
     B, H, T, D = q.shape
@@ -341,19 +396,18 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
     gp, _ = _pad_to(g, 2, q_block)          # zero-padded dO: no gradient
     op, _ = _pad_to(o, 2, q_block)
     Tq, Tk = qp.shape[2], kp.shape[2]
-    # same padded lengths as the forward (lse has Tq rows), wider tiles
-    q_block = _bwd_tile(q_block, Tq)
-    kv_block = _bwd_tile(kv_block, Tk)
+    # same padded lengths and tiles as the forward
+    q_block = _tile(q_block, Tq)
+    kv_block = _tile(kv_block, Tk)
     BH = B * H
     qp = qp.reshape(BH, Tq, D)
     kp = kp.reshape(BH, Tk, D)
     vp = vp.reshape(BH, Tk, Dv)
     gp = gp.reshape(BH, Tq, Dv)
     op = op.reshape(BH, Tq, Dv)
-    # the per-row vectors lie along lanes, one row a Q block.  lse comes
-    # padded from the forward already, as a (BH, Tq, 1) column
+    # the per-row vectors lie along lanes, one row a Q tile, as lse
+    # comes from the forward
     nq = Tq // q_block
-    lse = lse.reshape(BH, nq, q_block)
     delta = jnp.sum(gp.astype(jnp.float32) * op.astype(jnp.float32),
                     axis=-1).reshape(BH, nq, q_block)
 

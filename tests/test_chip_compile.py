@@ -106,6 +106,12 @@ def test_flash_attention(on_chip, geometry, dtype, causal, backward):
     fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
     text = jax.jit(fn).lower(q, q, q).compile().as_text()
     assert "flash_attention_fwd" in text
+    # the forward walks tiles (512 rows where the length allows)
+    # and hands lse over along lanes, a row a tile
+    B, H, T, _ = geometry
+    tile = fa._tile(min(128, T), T)
+    assert tile == min(T, 512)
+    assert "f32[%d,%d,%d]" % (B * H, T // tile, tile) in text
     # one backward kernel, and neither of the two it replaced
     assert ("flash_attention_bwd" in text) == backward
     assert "flash_attention_dq" not in text
@@ -126,13 +132,14 @@ def test_flash_attention_with_narrower_values_at_8k(on_chip):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, q, v).compile().as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "f32[32,16,512]" in text         # lse: 16 tiles of 512 a head
 
 
 def test_flash_attention_with_keys_and_values_of_256_at_8k(on_chip):
     """Rotary latent attention of the GLM-4.7-Flash cell: one row of 20
     heads, 8,192 positions, keys of 192 + 64 and values of 256, float32,
-    causal: whole heads in VMEM still (the forward asks for 57 MiB, the
-    backward for 94 of the chip's 128)."""
+    causal: whole heads in VMEM still (the forward, at its tiles of 512,
+    asks for 64 MiB, the backward for 94 of the chip's 128)."""
     q = _shape((1, 20, 8192, 256), F32, on_chip)
 
     def loss(q, k, v):
@@ -142,7 +149,9 @@ def test_flash_attention_with_keys_and_values_of_256_at_8k(on_chip):
         q, q, q).compile()
     text = compiled.as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
-    assert fa._vmem_limit(fa._bwd_vmem(8192, 512, 256, 256, "float32")) \
+    assert "f32[20,16,512]" in text         # lse: 16 tiles of 512 a head
+    assert fa._vmem_limit(fa._fwd_vmem(512, 512, 8192, 256, 256, "float32")) \
+        < fa._vmem_limit(fa._bwd_vmem(8192, 512, 256, 256, "float32")) \
         < 128 * 2 ** 20
 
 

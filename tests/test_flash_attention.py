@@ -125,6 +125,14 @@ def test_pallas_backward_bf16(qkv):
                                    err_msg=name)
 
 
+def _tiles_of(monkeypatch, tile):
+    """Both kernels held to tiles of at most ``tile`` rows, so that a
+    short sequence is walked in several trips."""
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "TILE", tile)
+    return fa
+
+
 def _vjp_both(q, k, v, ct, causal, **blocks):
     """(dq, dk, dv) of the fused backward and of the dense reference
     under the same cotangent."""
@@ -150,11 +158,10 @@ def _vjp_both(q, k, v, ct, causal, **blocks):
 def test_fused_backward_matches_dense_vjp(monkeypatch, tile, T, causal,
                                           blocks):
     """One kernel gives dq, dk and dv: against jax.vjp of the dense
-    reference, at the caller's tiles (BWD_TILE = 128: the kv-block grid
+    reference, at the caller's tiles (TILE = 128: the kv-block grid
     axis has several steps and dq is carried across them) and at the
     widened ones."""
-    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
-    monkeypatch.setattr(fa, "BWD_TILE", tile)
+    _tiles_of(monkeypatch, tile)
     rng = np.random.RandomState(T + tile)
     q, k, v, ct = (jnp.asarray(rng.randn(2, 2, T, 16).astype("float32"))
                    for _ in range(4))
@@ -169,8 +176,7 @@ def test_fused_backward_is_repeatable_bit_for_bit(monkeypatch, causal):
     """dq's accumulator is zeroed at the first kv block of every head,
     not left from the head or the call before: the same inputs give the
     same bits, in one call across equal heads and from call to call."""
-    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
-    monkeypatch.setattr(fa, "BWD_TILE", 128)
+    _tiles_of(monkeypatch, 128)
     rng = np.random.RandomState(11)
     one = [rng.randn(1, 1, 384, 16).astype("float32") for _ in range(4)]
     # three equal heads: head 2's dq must not hold head 1's sum
@@ -187,12 +193,200 @@ def test_fused_backward_is_repeatable_bit_for_bit(monkeypatch, causal):
 @pytest.mark.parametrize("block,padded,want", [
     (128, 512, 512), (128, 2048, 512), (128, 640, 128), (128, 768, 384),
     (64, 192, 192), (100, 100, 100), (1024, 2048, 1024), (256, 512, 512),
+    (128, 8192, 512), (16, 16, 16), (128, 1024, 512), (64, 640, 320),
+    (256, 1280, 256), (128, 1536, 512), (32, 160, 160), (128, 256, 256),
+    (64, 704, 64), (128, 1664, 128),
 ])
-def test_backward_tile_is_whole_forward_blocks(block, padded, want):
-    from mxtpu.ops.pallas.flash_attention import _bwd_tile
+def test_a_tile_is_the_widest_run_of_whole_blocks(block, padded, want):
+    """Both kernels walk the widest run of whole blocks up to TILE rows
+    that tiles the padded length: the caller's blocks stay the granule
+    of the padding (eleven or thirteen blocks leave one block a tile)."""
+    from mxtpu.ops.pallas.flash_attention import _tile
 
-    assert _bwd_tile(block, padded) == want
+    assert _tile(block, padded) == want
     assert padded % want == 0 and want % block == 0
+
+
+# ------------------------------- the forward's wide tiles (TILE), PR 34
+
+WIDE_CASES = [
+    # T a multiple of neither block: Tq = 192, Tk = 256
+    (150, 16, 16, True, dict(q_block=64, kv_block=128)),
+    (150, 16, 16, False, dict(q_block=128, kv_block=64)),
+    # three tiles of 128 a side (or one of 384), padded keys in the last
+    (300, 16, 16, True, dict(q_block=128, kv_block=128)),
+    (300, 16, 16, False, dict(q_block=128, kv_block=128)),
+    # q tiles of 256 over key tiles of 128 and the other way round
+    (300, 16, 16, True, dict(q_block=256, kv_block=64)),
+    (384, 16, 16, True, dict(q_block=64, kv_block=128)),
+    # values narrower than keys, and both at 256, at a short T
+    (160, 192, 128, True, dict(q_block=32, kv_block=32)),
+    (160, 256, 256, True, dict(q_block=32, kv_block=32)),
+]
+WIDE_IDS = ["causal_q64_k128_T150", "q128_k64_T150", "causal_T300", "T300",
+            "causal_q256_k64_T300", "causal_q64_k128_T384",
+            "causal_D192_Dv128_T160", "causal_D256_Dv256_T160"]
+
+
+@pytest.mark.parametrize("tile", [128, 512], ids=["tile128", "tile512"])
+@pytest.mark.parametrize("T,D,Dv,causal,blocks", WIDE_CASES, ids=WIDE_IDS)
+def test_wide_forward_tiles_match_dense(monkeypatch, tile, T, D, Dv, causal,
+                                        blocks):
+    """Output and all three gradients against the dense reference, at
+    the tiles the module chooses (512: one trip, or few) and held to 128
+    (several trips a tile of queries: the walk below the diagonal, the
+    diagonal, the padded last tile)."""
+    _tiles_of(monkeypatch, tile)
+    rng = np.random.RandomState(T + D + tile)
+    q, k = (jnp.asarray(rng.randn(1, 2, T, D).astype("float32"))
+            for _ in range(2))
+    v, ct = (jnp.asarray(rng.randn(1, 2, T, Dv).astype("float32"))
+             for _ in range(2))
+    scale = 1.0 / np.sqrt(D)
+    out, flash = jax.vjp(lambda a, b, c: flash_attention(
+        a, b, c, causal=causal, **blocks), q, k, v)
+    want, dense = jax.vjp(lambda a, b, c: _dense_attention(
+        a, b, c, scale, causal), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    for g, w, name in zip(flash(ct), dense(ct), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("T,blocks", [
+    (150, dict(q_block=64, kv_block=128)),
+    (384, dict(q_block=128, kv_block=128)),
+    (300, dict(q_block=128, kv_block=64)),
+], ids=["T150_q64_k128", "T384", "T300_q128_k64"])
+def test_forward_lse_is_the_reference_logsumexp(monkeypatch, T, blocks,
+                                                causal):
+    """The residual the backward reads: a row of lanes a tile of
+    queries, equal to the logsumexp of the masked, scaled scores."""
+    fa = _tiles_of(monkeypatch, 128)
+    rng = np.random.RandomState(T)
+    q, k, v = (jnp.asarray(rng.randn(2, 2, T, 16).astype("float32"))
+               for _ in range(3))
+    qb, kb = min(blocks["q_block"], T), min(blocks["kv_block"], T)
+    _, lse = fa._flash_fwd(q, k, v, 0.25, causal, qb, kb, True)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * 0.25
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((T, T), jnp.bool_)), s, -jnp.inf)
+    want = jax.nn.logsumexp(s, axis=-1)
+    Tq = -(-T // qb) * qb
+    assert lse.shape == (4, Tq // fa._tile(qb, Tq), fa._tile(qb, Tq))
+    np.testing.assert_allclose(
+        np.asarray(lse).reshape(2, 2, Tq)[:, :, :T], np.asarray(want),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_tiles_below_on_and_above_the_diagonal(monkeypatch):
+    """Three tiles of 128 a side, causal: the middle tile of queries
+    walks one tile below the diagonal, the one on it, and skips one
+    (above).  The walk is held to the answer of the same call as ONE
+    tile of 384, and the skipped tile is never read: its keys and values
+    are NaN, which a masked visit would carry into the sum."""
+    rng = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rng.randn(1, 2, 384, 16).astype("float32"))
+               for _ in range(3))
+    blocks = dict(causal=True, q_block=128, kv_block=128)
+    _tiles_of(monkeypatch, 512)
+    one_tile = flash_attention(q, k, v, **blocks)
+    _tiles_of(monkeypatch, 128)
+    tiled = flash_attention(q, k, v, **blocks)
+    np.testing.assert_allclose(np.asarray(tiled), np.asarray(one_tile),
+                               rtol=1e-5, atol=1e-6)
+    ref = _dense_attention(q, k, v, 0.25, True)
+    np.testing.assert_allclose(np.asarray(tiled), np.asarray(ref),
+                               rtol=1e-4, atol=1e-5)
+    nan = jnp.full((1, 2, 128, 16), jnp.nan, jnp.float32)
+    k_bad = jnp.concatenate([k[:, :, :256], nan], axis=2)
+    v_bad = jnp.concatenate([v[:, :, :256], nan], axis=2)
+    poisoned = np.asarray(flash_attention(q, k_bad, v_bad, **blocks))
+    np.testing.assert_array_equal(poisoned[:, :, :256],
+                                  np.asarray(tiled)[:, :, :256])
+    assert np.isnan(poisoned[:, :, 256:]).all()
+
+
+@pytest.mark.parametrize("T,causal,blocks,tiles", [
+    (640, True, {}, (128, 128)),             # five tiles: the diagonal whole
+    (640, False, {}, (128, 128)),
+    (1000, True, {}, (512, 512)),            # two: the diagonal in 4 steps
+    (600, True, dict(q_block=64, kv_block=128), (320, 128)),
+    (600, False, dict(q_block=128, kv_block=64), (128, 320)),
+], ids=["causal_T640", "T640", "causal_T1000", "causal_q64_k128_T600",
+        "q128_k64_T600"])
+def test_the_module_s_own_tiles_in_several_trips(T, causal, blocks, tiles):
+    """No constant held down: lengths at which TILE = 512 itself leaves
+    several trips a tile of queries (five blocks a side stay five tiles;
+    1,024 padded rows are two tiles of 512; tiles of 320 queries over
+    tiles of 128 keys).  Output, lse and all three gradients against the
+    dense reference; the calls test_kernel_check.py only traces at
+    T = 150 and T = 640 run here."""
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    rng = np.random.RandomState(T)
+    q, k, v, ct = (jnp.asarray(rng.randn(1, 2, T, 16).astype("float32"))
+                   for _ in range(4))
+    qb, kb = blocks.get("q_block", 128), blocks.get("kv_block", 128)
+    Tq, Tk = -(-T // qb) * qb, -(-T // kb) * kb
+    assert (fa._tile(qb, Tq), fa._tile(kb, Tk)) == tiles
+    out, flash = jax.vjp(lambda a, b, c: flash_attention(
+        a, b, c, causal=causal, **blocks), q, k, v)
+    want, dense = jax.vjp(lambda a, b, c: _dense_attention(
+        a, b, c, 0.25, causal), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    for g, w, name in zip(flash(ct), dense(ct), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5, err_msg=name)
+    _, lse = fa._flash_fwd(q, k, v, 0.25, causal, qb, kb, True)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * 0.25
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((T, T), jnp.bool_)), s, -jnp.inf)
+    assert lse.shape == (2, Tq // tiles[0], tiles[0])
+    np.testing.assert_allclose(
+        np.asarray(lse).reshape(1, 2, Tq)[:, :, :T],
+        np.asarray(jax.nn.logsumexp(s, axis=-1)), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("diag", [128, 256], ids=["diag128", "diag256"])
+def test_the_diagonal_tile_is_walked_in_key_steps(monkeypatch, diag):
+    """Two square tiles of 256, causal: the second walks one tile
+    unmasked and then its diagonal tile in steps of ``diag`` keys, each
+    against the queries from its own first on (two steps of 128, or the
+    whole tile at once): values and gradients of the dense reference."""
+    fa = _tiles_of(monkeypatch, 256)
+    monkeypatch.setattr(fa, "FWD_DIAG", diag)
+    rng = np.random.RandomState(diag)
+    q, k, v, ct = (jnp.asarray(rng.randn(1, 2, 500, 16).astype("float32"))
+                   for _ in range(4))
+    out, flash = jax.vjp(lambda a, b, c: flash_attention(
+        a, b, c, causal=True), q, k, v)
+    want, dense = jax.vjp(lambda a, b, c: _dense_attention(
+        a, b, c, 0.25, True), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    for g, w, name in zip(flash(ct), dense(ct), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("tile", [128, 512], ids=["tile128", "tile512"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_wide_forward_tiles_bf16(monkeypatch, qkv, tile, causal):
+    """bf16 inputs (``hi_prec=False``: the matrix unit's default
+    precision) within 2e-2 of the float32 dense reference."""
+    _tiles_of(monkeypatch, tile)
+    q, k, v = (jnp.concatenate([a, a[:, :, :64]], axis=2) for a in qkv)
+    out = flash_attention(*(a.astype(jnp.bfloat16) for a in (q, k, v)),
+                          causal=causal, q_block=64, kv_block=64)
+    assert out.dtype == jnp.bfloat16
+    ref = _dense_attention(q, k, v, 0.25, causal)
+    np.testing.assert_allclose(np.asarray(out, dtype="float32"),
+                               np.asarray(ref), rtol=2e-2, atol=2e-2)
 
 
 def test_pallas_backward_mixed_block_sizes(qkv):
@@ -287,18 +481,20 @@ def test_flash_with_a_value_width_of_its_own(direction, T, D, Dv, causal,
 def test_a_long_head_asks_for_its_vmem_and_a_short_one_for_nothing():
     fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
     # BERT's call: the compiler's own limit, as before
-    assert fa._compiler_params(fa._fwd_vmem(128, 512, 64, 64,
+    assert fa._compiler_params(fa._fwd_vmem(512, 512, 512, 64, 64,
                                             "float32")) is None
     assert fa._compiler_params(fa._bwd_vmem(512, 512, 64, 64,
                                             "float32")) is None
     # 8,192 keys of 192: K and V whole in VMEM are 12 MiB, twice buffered
-    asked = fa._compiler_params(fa._fwd_vmem(128, 8192, 192, 128, "float32"))
+    asked = fa._compiler_params(fa._fwd_vmem(512, 512, 8192, 192, 128,
+                                             "float32"))
     assert 24 * 2 ** 20 < asked.vmem_limit_bytes < 128 * 2 ** 20
     back = fa._compiler_params(fa._bwd_vmem(8192, 512, 192, 128, "float32"))
     assert asked.vmem_limit_bytes < back.vmem_limit_bytes < 128 * 2 ** 20
     # keys and values of 256: 16 MiB of K and V a head, 8 MiB each of Q,
     # dO, dQ and its accumulator; still inside the chip's 128 MiB
-    wide = fa._compiler_params(fa._fwd_vmem(128, 8192, 256, 256, "float32"))
+    wide = fa._compiler_params(fa._fwd_vmem(512, 512, 8192, 256, 256,
+                                            "float32"))
     wide_back = fa._compiler_params(fa._bwd_vmem(8192, 512, 256, 256,
                                                  "float32"))
     assert asked.vmem_limit_bytes < wide.vmem_limit_bytes \

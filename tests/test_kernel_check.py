@@ -390,19 +390,11 @@ def test_vmem_estimate_prices_the_real_call(monkeypatch, cache_dtype):
         kernel_vmem_estimate(spec)["total_bytes"]
 
 
-@pytest.mark.parametrize("T,blocks", [
-    (256, dict(q_block=128, kv_block=128)),     # widened to one tile
-    (150, dict(q_block=64, kv_block=128)),      # Tq = 192, Tk = 256
-    (640, dict(q_block=128, kv_block=128)),     # five tiles of 128
-], ids=["T256", "T150_q64_k128", "T640"])
-def test_flash_specs_describe_the_real_calls(monkeypatch, T, blocks):
-    """flash_attention.kernel_specs == the two pallas_calls a forward
-    and backward issue: names, grids, block shapes, dq's scratch."""
-    import importlib
-
+def _flash_calls(monkeypatch, fa, q, k, v, causal=False, **blocks):
+    """The keyword arguments of the pallas_calls one forward and
+    backward issue: traced, never run."""
     import jax
 
-    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
     calls = []
     real = fa.pl.pallas_call
 
@@ -412,11 +404,14 @@ def test_flash_specs_describe_the_real_calls(monkeypatch, T, blocks):
 
     monkeypatch.setattr(fa.pl, "pallas_call", spy)
     fa._make_flash.cache_clear()
-    B, H, D = 1, 2, 16
-    x = jnp.ones((B, H, T, D), jnp.float32)
-    jax.grad(lambda q, k, v: fa.flash_attention(q, k, v, **blocks).sum(),
-             argnums=(0, 1, 2))(x, x, x)
-    specs = fa.kernel_specs(B, H, T, D, interpret=True, **blocks)
+    jax.make_jaxpr(jax.grad(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, **blocks).sum(),
+        argnums=(0, 1, 2)))(q, k, v)
+    return calls
+
+
+def _assert_specs_are_the_calls(calls, specs):
     assert [c["name"] for c in calls] == \
         ["flash_attention_fwd", "flash_attention_bwd"]
     assert [s.name.split("[")[0] for s in specs] == \
@@ -430,6 +425,68 @@ def test_flash_specs_describe_the_real_calls(monkeypatch, T, blocks):
         assert [(tuple(sc.shape), str(jnp.dtype(sc.dtype)))
                 for sc in call.get("scratch_shapes", ())] == \
             [(sc.shape, sc.dtype) for sc in spec.scratch]
+        params = call.get("compiler_params")
+        assert (None if params is None else params.vmem_limit_bytes) == \
+            spec.vmem_limit, spec.name
+
+
+@pytest.mark.parametrize("T,blocks", [
+    (256, dict(q_block=128, kv_block=128)),     # widened to one tile
+    (150, dict(q_block=64, kv_block=128)),      # Tq = 192, Tk = 256
+    (640, dict(q_block=128, kv_block=128)),     # five tiles of 128
+    (1024, dict(q_block=128, kv_block=128)),    # two tiles of 512
+], ids=["T256", "T150_q64_k128", "T640", "T1024"])
+def test_flash_specs_describe_the_real_calls(monkeypatch, T, blocks):
+    """flash_attention.kernel_specs == the two pallas_calls a forward
+    and backward issue: names, grids, block shapes (lse's: the head's
+    rows of lanes, a row a tile), dq's scratch, the VMEM asked for.
+    Traced only: the same calls run, against the dense reference, in
+    tests/test_flash_attention.py (T = 150 at q64 / k128 under
+    test_wide_forward_tiles_match_dense, T = 640 and 1,024 padded rows
+    under test_the_module_s_own_tiles_in_several_trips)."""
+    import importlib
+
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    B, H, D = 1, 2, 16
+    x = jnp.ones((B, H, T, D), jnp.float32)
+    calls = _flash_calls(monkeypatch, fa, x, x, x, **blocks)
+    _assert_specs_are_the_calls(
+        calls, fa.kernel_specs(B, H, T, D, interpret=True, **blocks))
+
+
+@pytest.mark.parametrize("B,H,T,D,Dv,causal,fwd_grid,lse", [
+    (32, 12, 512, 64, 64, False, (384, 1), (1, 1, 512)),
+    (1, 32, 8192, 192, 128, True, (32, 16), (1, 16, 512)),
+    (1, 20, 8192, 256, 256, True, (20, 16), (1, 16, 512)),
+], ids=["bert_base_seq512", "kimi_linear_seq8192", "glm_4_7_flash_seq8192"])
+def test_flash_specs_at_the_three_cells_geometries(monkeypatch, B, H, T, D,
+                                                   Dv, causal, fwd_grid,
+                                                   lse):
+    """The benchmark's three cells, float32: the specs in the merge gate
+    are the calls the program traces there (tiles of 512 rows forward
+    and backward, lse a row of 512 lanes a tile, whole heads in VMEM and
+    the VMEM the calls ask for), and they pass the static rules."""
+    import importlib
+
+    import jax
+
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    q = jax.ShapeDtypeStruct((B, H, T, D), jnp.float32)
+    v = jax.ShapeDtypeStruct((B, H, T, Dv), jnp.float32)
+    monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+    calls = _flash_calls(monkeypatch, fa, q, q, v, causal=causal)
+    specs = fa.kernel_specs(B, H, T, D, Dv=Dv)
+    _assert_specs_are_the_calls(calls, specs)
+    assert specs[0].grid == fwd_grid
+    assert [op.block_shape for op in specs[0].operands
+            if op.name == "lse"] == [lse]
+    names = {s.name for s in default_kernel_specs()}
+    assert {s.name for s in specs} <= names
+    for spec in specs:
+        limit = spec.vmem_limit
+        assert limit is None or limit < 128 * 2 ** 20, spec.name
+    rep = check_kernels(specs)
+    assert rep.ok and not rep.warnings, str(rep)
 
 
 def test_m007_details_decompose_the_total():
