@@ -619,6 +619,10 @@ def default_kernel_specs() -> List[KernelSpec]:
       head), latent attention's (T=8192, keys 192, values 128) and
       rotary latent attention's (T=8192, keys and values 256), whole
       heads in VMEM, asked for through ``vmem_limit``;
+    - the Keye-VL cell's indexed attention (T=8192): the flash kernels
+      with kept keys at 32 heads of 128, and the indexer's scores
+      forward, mean probabilities and two backward passes (16 heads of
+      64), an output tile carried over the grid's innermost axes;
     - KDA's kernels (the chunks' operands forward and backward, the
       state pass forward writing states and backward) at 8,192 positions
       and at a toy length, heads and chunks of 128 x 64;
@@ -640,6 +644,7 @@ def default_kernel_specs() -> List[KernelSpec]:
 
     from ..ops.pallas import paged_attention
     kda = importlib.import_module("mxtpu.ops.pallas.kda")
+    indexer = importlib.import_module("mxtpu.ops.pallas.indexer")
 
     # the package re-exports the flash_attention FUNCTION under the
     # module's name; import the module itself for its spec builder
@@ -665,6 +670,14 @@ def default_kernel_specs() -> List[KernelSpec]:
         B=1, H=20, T=8192, D=256, dtype="float32"))
     for T in (8192, 96):
         specs.extend(kda.kernel_specs(B=1, H=4, T=T, K=128))
+    # indexed sparse attention of the Keye-VL cell: the flash kernels
+    # with a sequence's kept keys (32 heads of 128, a tile's column or a
+    # block's row of the (T, T) selection scores beside the head), and
+    # the indexer's four kernels (16 heads of 64; 4 key heads)
+    specs.extend(flash_attention.kernel_specs(
+        B=1, H=32, T=8192, D=128, dtype="float32", kept=True))
+    specs.extend(indexer.kernel_specs(B=1, Hi=16, T=8192, d=64, H=32, G=4,
+                                      D=128))
     for cache_dtype, block_size in (("float32", 16), ("int8", 32)):
         for W in (1, 8):
             specs.append(paged_attention.kernel_spec(

@@ -16,7 +16,8 @@ from .block import HybridBlock
 __all__ = ["Loss", "L2Loss", "L1Loss",
            "SigmoidBinaryCrossEntropyLoss", "SigmoidBCELoss",
            "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
-           "MultiTokenLoss", "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
+           "MultiTokenLoss", "IndexedAttentionLoss", "KLDivLoss", "CTCLoss",
+           "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
            "PoissonNLLLoss", "CosineEmbeddingLoss"]
 
@@ -140,6 +141,15 @@ class SoftmaxCrossEntropyLoss(Loss):
 SoftmaxCELoss = SoftmaxCrossEntropyLoss
 
 
+def _head_cross_entropy(F, head, out, label):
+    """Per-position cross-entropy of ``out``: logits when ``head`` is
+    None, else the input of ``head`` (a ``Dense`` block, no bias), taken
+    through it in blocks of rows (``F.linear_cross_entropy``)."""
+    if head is None:
+        return -F.pick(F.log_softmax(out, axis=-1), label, axis=-1)
+    return F.linear_cross_entropy(out, head.weight.data(out.context), label)
+
+
 class MultiTokenLoss(Loss):
     """Next-token cross-entropy plus ``mtp_weight`` times that of a
     multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437 section
@@ -173,10 +183,7 @@ class MultiTokenLoss(Loss):
         return super().forward(main, mtp, label)
 
     def _cross_entropy(self, F, out, label):
-        if self._head is None:
-            return -F.pick(F.log_softmax(out, axis=-1), label, axis=-1)
-        return F.linear_cross_entropy(
-            out, self._head.weight.data(out.context), label)
+        return _head_cross_entropy(F, self._head, out, label)
 
     def hybrid_forward(self, F, main_out, mtp_out, label):
         main = self._cross_entropy(F, main_out, label)
@@ -191,6 +198,37 @@ class MultiTokenLoss(Loss):
                          F.sum(mtp))
         return F.mean(main, axis=self._batch_axis, exclude=True) \
             + self._weight * F.mean(mtp, axis=self._batch_axis, exclude=True)
+
+
+class IndexedAttentionLoss(Loss):
+    """Next-token cross-entropy plus ``index_weight`` times the loss of
+    the attention layers' indexers (DeepSeek Sparse Attention's sparse
+    stage), for a model that returns (logits (B, T, V), the indexers'
+    loss (B,) summed over its layers) with labels (B, T).  The second
+    term reaches the indexers' parameters alone and the first everything
+    else: the model sees to that (``ops/dsa.py``), the loss only adds.
+
+    With ``head`` — the ``Dense`` block (no bias) the logits come from —
+    the model's first output is the head's input (B, T, C) and the
+    cross-entropy is taken through the head in blocks of rows
+    (``F.linear_cross_entropy``).
+    """
+
+    #: SPMDTrainer hands the model's whole output to such a loss
+    accepts_full_output = True
+
+    def __init__(self, index_weight=1.0, head=None, batch_axis=0, **kwargs):
+        super().__init__(index_weight, batch_axis, **kwargs)
+        self._head = head
+
+    def forward(self, outputs, label):
+        main, index_loss = outputs
+        return super().forward(main, index_loss, label)
+
+    def hybrid_forward(self, F, out, index_loss, label):
+        ce = _head_cross_entropy(F, self._head, out, label)
+        return F.mean(ce, axis=self._batch_axis, exclude=True) \
+            + self._weight * index_loss
 
 
 class KLDivLoss(Loss):

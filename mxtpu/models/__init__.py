@@ -21,6 +21,8 @@ from . import kimi_linear
 from .kimi_linear import KimiLinearLM, kimi_linear_from_config
 from . import glm4_moe_lite
 from .glm4_moe_lite import Glm4MoeLiteLM, glm4_moe_lite_from_config
+from . import keye_vl
+from .keye_vl import KeyeVLTextLM, keye_vl_from_config
 from . import sampler
 from .sampler import (BeamSearchSampler, NGramDrafter, SequenceSampler,
                       beam_search)

@@ -153,9 +153,11 @@ _EXPERT_LAYERS = weakref.WeakSet()
 class ExpertShare(HybridBlock):
     """An expert layer that holds ``held = (first, count)`` of
     ``num_experts_total`` gated experts (one expert-parallel rank's
-    share; the whole layer when ``held`` is None) and one shared expert.
-    The router scores all experts; what the experts held elsewhere would
-    add is not in the result (on one chip there is no exchange).
+    share; the whole layer when ``held`` is None) and ``num_shared``
+    shared experts.  The router scores all experts, each by a sigmoid of
+    its own or (``score="softmax"``) by a softmax over them all; what the
+    experts held elsewhere would add is not in the result (on one chip
+    there is no exchange).
 
     ``select_bias`` (added to the scores for the choice only) is frozen:
     the family moves it by a rule outside the gradient, which is not
@@ -166,7 +168,7 @@ class ExpertShare(HybridBlock):
 
     def __init__(self, units, hidden_size, num_experts_total, top_k,
                  held=None, routed_scale=1.0, renormalize=True,
-                 num_shared=1, **kwargs):
+                 num_shared=1, score="sigmoid", **kwargs):
         super().__init__(**kwargs)
         first, count = held if held is not None else (0, num_experts_total)
         if not 0 <= first <= first + count <= num_experts_total:
@@ -174,6 +176,7 @@ class ExpertShare(HybridBlock):
                              % (held, num_experts_total))
         self._first, self._k = first, top_k
         self._scale, self._renorm = routed_scale, renormalize
+        self._score = score
         with self.name_scope():
             self.router = _dense(num_experts_total, units, "router_")
             self.select_bias = self.params.get(
@@ -210,7 +213,8 @@ class ExpertShare(HybridBlock):
         y, now = F.moe_expert_share(
             x, self.router.weight.data(x.context), select_bias,
             experts_gate, experts_up, experts_down, held_first=self._first,
-            top_k=self._k, renormalize=self._renorm, scale=self._scale)
+            top_k=self._k, renormalize=self._renorm, scale=self._scale,
+            score=self._score)
         with autograd.pause():
             self.load.data(None)._rebind(now.data)
             self.load_sum.data(None)._rebind(

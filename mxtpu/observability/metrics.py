@@ -50,6 +50,11 @@ source              pulls
                     (``mtp.positions``, ``mtp.loss_sum``,
                     ``mtp.main_loss_sum`` — models/glm4_moe_lite.py
                     ``mtp_counts``; three numbers from the device)
+``dsa``             the indexed-attention layers' counters
+                    (``dsa.selected_pairs``, ``dsa.kl_sum``,
+                    ``dsa.<layer>.kept_mean`` — models/keye_vl.py
+                    ``dsa_counts``; three numbers a layer from the
+                    device)
 ==================  ====================================================
 
 Live objects (engines, gateways, supervisors, routers) register with
@@ -285,6 +290,17 @@ def _src_mtp() -> dict:
     return mtp_counts()
 
 
+def _src_dsa() -> dict:
+    """The indexed-attention layers' counters: ``dsa.selected_pairs``
+    (query, key) pairs the indexers' selections kept and ``dsa.kl_sum``
+    the indexers' loss, both summed over the layers since their start,
+    and ``dsa.<layer>.kept_mean`` the keys a query kept in that layer's
+    newest pass: a program that drops the selection counts every causal
+    pair (models/keye_vl.py ``dsa_counts``)."""
+    from ..models.keye_vl import dsa_counts
+    return dsa_counts()
+
+
 def default_registry() -> MetricsRegistry:
     """A fresh registry pre-loaded with the built-in process-wide
     sources (module docstring table)."""
@@ -300,6 +316,7 @@ def default_registry() -> MetricsRegistry:
     reg.register_source("lifecycle", _src_lifecycle)
     reg.register_source("moe", _src_moe)
     reg.register_source("mtp", _src_mtp)
+    reg.register_source("dsa", _src_dsa)
     return reg
 
 

@@ -22,3 +22,4 @@ from . import moe  # noqa: F401
 from . import random_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import pallas  # noqa: F401
+from . import dsa  # noqa: F401

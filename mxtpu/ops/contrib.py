@@ -223,6 +223,36 @@ def rope(data, base=10000.0, offset=0, scale=1.0):
     return out.astype(dt)
 
 
+@register_op("mrope", aliases=("_contrib_mrope",))
+def mrope(data, positions, sections=(16, 24, 24), base=10000.0):
+    """Rotary position embedding with several position streams
+    (Qwen2-VL's multimodal form) over the last dim of (B, H, T, D) or
+    (B, T, D), pairs as ``rope`` has them.  ``positions`` is
+    (streams, T) or (streams, B, T), absolute; of the D/2 frequencies the
+    first ``sections[0]`` turn by stream 0, the next ``sections[1]`` by
+    stream 1, and so on (``sum(sections) == D/2``).  With every stream
+    equal to 0..T-1 it is ``rope`` bit for bit."""
+    import numpy as onp
+
+    dt = data.dtype
+    x = data.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    if sum(sections) != half:
+        raise ValueError("sections %r do not add up to %d frequencies"
+                         % (tuple(sections), half))
+    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    stream = onp.repeat(onp.arange(len(sections)), sections)     # (D/2,)
+    pos = jnp.asarray(positions, jnp.float32)
+    ang = jnp.moveaxis(pos[stream], 0, -1) * freqs     # (.., T, D/2)
+    lead = (1,) if pos.ndim == 2 else (x.shape[0],)
+    shape = lead + (1,) * (x.ndim - 3) + ang.shape[-2:]
+    sin, cos = jnp.sin(ang).reshape(shape), jnp.cos(ang).reshape(shape)
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                          axis=-1)
+    return out.astype(dt)
+
+
 @register_op("masked_softmax", aliases=("_contrib_masked_softmax",))
 def masked_softmax(data, mask=None, axis=-1, temperature=1.0):
     """Softmax with additive/boolean mask (parity: masked_softmax in later

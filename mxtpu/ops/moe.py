@@ -224,7 +224,7 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 @register_op("moe_expert_share", num_outputs=2)
 def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
                      held_first=0, top_k=8, renormalize=True,
-                     scale=1.0):
+                     scale=1.0, score="sigmoid"):
     """The routed part of an expert layer that holds a share of the
     experts (expert parallelism: one rank's part of the result).
 
@@ -234,12 +234,13 @@ def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
     ``held_first .. held_first + held - 1`` this share holds.
 
     Every token chooses its ``top_k`` experts among all ``E`` by
-    ``sigmoid(x router_w^T) + select_bias`` and weighs them by the
-    sigmoid scores themselves, renormalised over all the chosen (held
-    here or not) and scaled by ``scale``.  The (token, expert) pairs that
-    fall to held experts are sorted by expert and go, a tile at a time
-    (``_tile_rows``: about ``PAIRS_PER_TILE``), through three grouped
-    products (``lax.ragged_dot``),
+    ``score(x router_w^T) + select_bias`` — ``score`` "sigmoid", each
+    expert's own, or "softmax" over all ``E``, in float32 — and weighs
+    them by the scores themselves, renormalised over all the chosen
+    (held here or not) and scaled by ``scale``.  The (token, expert)
+    pairs that fall to held experts are sorted by expert and go, a tile
+    at a time (``_tile_rows``: about ``PAIRS_PER_TILE``), through three
+    grouped products (``lax.ragged_dot``),
     ``w_down(silu(w_gate x) * w_up x)``; there is no capacity and no
     pair is dropped.  Pairs that fall to experts held elsewhere add
     nothing here: on one chip there is no exchange.
@@ -251,7 +252,8 @@ def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
     held, k = w_gate.shape[0], int(top_k)
     hi = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
     xf = x.reshape(-1, d)
-    scores = jax.nn.sigmoid(jnp.dot(
+    score_fn = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[score]
+    scores = score_fn(jnp.dot(
         xf.astype(jnp.float32), router_w.astype(jnp.float32).T,
         precision=jax.lax.Precision.HIGHEST))                 # (S, E)
     _, chosen = jax.lax.top_k(

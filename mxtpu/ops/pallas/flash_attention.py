@@ -32,7 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...base import register_op
-from ..remat import keep
+from .. import remat
 from . import counters
 from .partition import shard_attention
 
@@ -44,13 +44,14 @@ KERNEL_NAME = "flash_attention"
 
 
 def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
-                 backward=True, interpret=False, Dv=None):
+                 backward=True, interpret=False, Dv=None, kept=False):
     """KernelSpec descriptors (mxtpu.analysis.kernel_check) for the
     pallas_calls one flash_attention forward/backward issues at this
     workload geometry — same padding and block construction as
     _flash_fwd/_flash_bwd, so the static pass verdicts exactly the
     calls that would run.  ``Dv`` is the values' width where it is not
-    the keys' (``D``)."""
+    the keys' (``D``); ``kept`` describes the calls of
+    ``flash_attention(keep=...)``, with the selection's two inputs."""
     from ...analysis.kernel_check import (BlockOperand, KernelSpec,
                                           ScratchOperand)
 
@@ -76,6 +77,26 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
     full_im = lambda b, i: (b, 0, 0)   # noqa: E731
     tag = "[%s,T=%d,D=%d]" % (dtype, T, D) if Dv == D else \
         "[%s,T=%d,D=%d,Dv=%d]" % (dtype, T, D, Dv)
+    fwd_more = bwd_more = 0
+    if kept:
+        tag = tag[:-1] + ",kept]"
+        fwd_more = 2 * _padded(Tk, qb, "float32")
+        bwd_more = 2 * _padded(kb, Tq, "float32")
+
+    def selection(column):
+        """The sequence's scores — a Q tile's column (forward) or a K/V
+        block's row (backward) — and its thresholds."""
+        if column:
+            block, im = (1, Tk, qb), lambda b, i: (b // H, 0, i)
+            least = ((1, 1, 1, qb), lambda b, i: (b // H, i, 0, 0))
+        else:
+            block, im = (1, kb, Tq), lambda b, j: (b // H, j, 0)
+            least = ((1, nq, 1, qb), lambda b, j: (b // H, 0, 0, 0))
+        return [BlockOperand("scores", "in", block, (B, Tk, Tq), "float32",
+                             im),
+                BlockOperand("least", "in", least[0], (B, nq, 1, qb),
+                             "float32", least[1])]
+
     # lse lies along lanes, a row a Q tile: its block is the head's,
     # resident over the Q-tile axis (the grid's innermost: K006 holds)
     specs = [KernelSpec(
@@ -85,12 +106,14 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
             blk("q", "in", (1, qb, D), (BH, Tq, D), dtype, q_im),
             blk("k", "in", (1, Tk, D), (BH, Tk, D), dtype, full_im),
             blk("v", "in", (1, Tk, Dv), (BH, Tk, Dv), dtype, full_im),
+        ] + (selection(True) if kept else []) + [
             blk("o", "out", (1, qb, Dv), (BH, Tq, Dv), dtype, q_im),
             BlockOperand("lse", "out", (1, nq, qb), (BH, nq, qb),
                          "float32", full_im),
         ],
         interpret=interpret,
-        vmem_limit=_vmem_limit(_fwd_vmem(qb, kb, Tk, D, Dv, dtype)))]
+        vmem_limit=_vmem_limit(_fwd_vmem(qb, kb, Tk, D, Dv, dtype)
+                               + fwd_more))]
     if not backward:
         return specs
     kv_im = lambda b, j: (b, j, 0)     # noqa: E731 — mirrors _flash_bwd
@@ -109,13 +132,15 @@ def kernel_specs(B, H, T, D, dtype="float32", q_block=128, kv_block=128,
                          full_im),
             BlockOperand("delta", "in", (1, nq, qb), (BH, nq, qb),
                          "float32", full_im),
+        ] + (selection(False) if kept else []) + [
             blk("dq", "out", (1, Tq, D), (BH, Tq, D), dtype, full_im),
             blk("dk", "out", (1, kb, D), (BH, Tk, D), dtype, kv_im),
             blk("dv", "out", (1, kb, Dv), (BH, Tk, Dv), dtype, kv_im),
         ],
         scratch=[ScratchOperand("dq_acc", (Tq, D), "float32")],
         interpret=interpret,
-        vmem_limit=_vmem_limit(_bwd_vmem(Tq, kb, D, Dv, dtype))))
+        vmem_limit=_vmem_limit(_bwd_vmem(Tq, kb, D, Dv, dtype)
+                               + bwd_more)))
     return specs
 
 
@@ -195,15 +220,19 @@ def _tile(block, padded):
                        if n % m == 0 and (m == 1 or block * m <= TILE))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                q_block, kv_block, seq_len, valid_len, hi_prec):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, q_block, kv_block,
+                seq_len, valid_len, hi_prec):
     """One tile of queries against the head's keys, transposed as the
     backward's tiles are: S^T = K Q^T, so the running maximum, the
     denominator and the rescale are (1, Bq) rows on the lanes, and
     O^T = sum V^T P^T is turned once, when the tile leaves.  A causal
     call with square tiles walks the tiles below the diagonal and then
     the diagonal one in steps of FWD_DIAG keys, each against the queries
-    from its own first on."""
+    from its own first on.  A call with kept keys has two more inputs,
+    the tile's column of the sequence's selection scores (Tk, Bq) and
+    the thresholds as rows of lanes: a key is masked where its score
+    lies under its query's threshold."""
+    *kept, o_ref, lse_ref = rest
     # fp32 inputs keep true-fp32 dots; bf16 inputs use the fast MXU default
     # (jax>=0.9 interpret mode emulates TPU bf16 default precision, so the
     # fp32 contract must be explicit)
@@ -233,6 +262,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
             q_pos = qi * q_block + first + jax.lax.broadcasted_iota(
                 jnp.int32, st.shape, 1)
             st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+        if kept:
+            score_ref, least_ref = kept
+            st = jnp.where(score_ref[0, rows, first:]
+                           >= least_ref[0, 0, :, first:], st, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
         pt = jnp.exp(st - m_new)
         corr = jnp.exp(m - m_new)
@@ -279,7 +312,20 @@ def _pad_to(x, axis, multiple):
     return jnp.pad(x, widths), size
 
 
-def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
+def _kept_operands(keep, Tq, Tk, q_block):
+    """``keep``'s two arrays as the kernels read them: the scores
+    (B, Tk, Tq) padded as the keys and queries are, the thresholds as
+    (B, nq, 1, q_block): a tile's are a row of lanes."""
+    scores, least = keep
+    scores = jnp.pad(scores.astype(jnp.float32), (
+        (0, 0), (0, Tk - scores.shape[1]), (0, Tq - scores.shape[2])))
+    least = jnp.pad(least.astype(jnp.float32),
+                    ((0, 0), (0, Tq - least.shape[1])))
+    return scores, least.reshape(least.shape[0], Tq // q_block, 1, q_block)
+
+
+def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret,
+               keep=None):
     B, H, T, D = q.shape
     Dv = v.shape[-1]
     qp, t_orig = _pad_to(q, 2, q_block)
@@ -302,35 +348,50 @@ def _flash_fwd(q, k, v, scale, causal, q_block, kv_block, interpret):
                                hi_prec=q.dtype == jnp.float32)
     tile = lambda b, i: (b, i, 0)           # noqa: E731
     head = lambda b, i: (b, 0, 0)           # noqa: E731 — resident over i
+    operands = [qp, kp, vp]
+    in_specs = [
+        pl.BlockSpec((1, q_block, D), tile),
+        pl.BlockSpec((1, Tk, D), head),
+        pl.BlockSpec((1, Tk, Dv), head),
+    ]
+    vmem = _fwd_vmem(q_block, kv_block, Tk, D, Dv, q.dtype)
+    if keep is not None:
+        # the sequence's, shared by its heads: the tile's column of the
+        # scores and the thresholds' rows
+        operands += _kept_operands(keep, Tq, Tk, q_block)
+        in_specs += [
+            pl.BlockSpec((1, Tk, q_block), lambda b, i: (b // H, 0, i)),
+            pl.BlockSpec((1, 1, 1, q_block),
+                         lambda b, i: (b // H, i, 0, 0))]
+        vmem += 2 * _padded(Tk, q_block, "float32")
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((BH, Tq, Dv), q.dtype),
                    jax.ShapeDtypeStruct((BH, nq, q_block), jnp.float32)],
         grid=(BH, nq),
-        in_specs=[
-            pl.BlockSpec((1, q_block, D), tile),
-            pl.BlockSpec((1, Tk, D), head),
-            pl.BlockSpec((1, Tk, Dv), head),
-        ],
+        in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, q_block, Dv), tile),
                    pl.BlockSpec((1, nq, q_block), head)],
-        compiler_params=_compiler_params(
-            _fwd_vmem(q_block, kv_block, Tk, D, Dv, q.dtype)),
+        compiler_params=_compiler_params(vmem),
         interpret=interpret,
         name="flash_attention_fwd",
-    )(qp, kp, vp)
+    )(*operands)
     return out.reshape(B, H, Tq, Dv)[:, :, :t_orig], lse
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                dk_ref, dv_ref, dq_acc, *, scale, causal, q_block, kv_block,
-                seq_len, valid_len, hi_prec):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                scale, causal, q_block, kv_block, seq_len, valid_len,
+                hi_prec):
     """dq, dk and dv for one K/V block: stream Q/dO blocks (from the
     diagonal on for causal) and form every tile once, transposed —
     S^T = K Q^T, P^T = exp(S^T - lse), dS^T = P^T * (V dO^T - delta) —
     so that dv += P^T dO and dk += dS^T Q consume it as it lies and only
     dq += dS K contracts over its rows.  dq accumulates in ``dq_acc``
-    across the kv-block grid axis and leaves at its last step."""
+    across the kv-block grid axis and leaves at its last step.  A call
+    with kept keys has two more inputs, the block's row of the
+    sequence's selection scores (Bkv, Tq) and the thresholds, and masks
+    as the forward does."""
+    *kept, dq_ref, dk_ref, dv_ref, dq_acc = rest
     prec = jax.lax.Precision.HIGHEST if hi_prec else None
     dot = functools.partial(jax.lax.dot_general, precision=prec,
                             preferred_element_type=jnp.float32)
@@ -368,6 +429,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q_pos = i * q_block + jax.lax.broadcasted_iota(
                 jnp.int32, (bkv, q_block), 1)
             st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+        if kept:
+            score_ref, least_ref = kept
+            st = jnp.where(score_ref[0, :, pl.ds(pl.multiple_of(
+                i * q_block, q_block), q_block)]
+                >= least_ref[0, i], st, _NEG_INF)
         pt = jnp.exp(st - lse)                    # masked entries -> ~0
         dv = dv + dot(pt, do, a_b)
         dst = pt * (dot(v, do, a_bt) - delta)
@@ -387,7 +453,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
-               interpret):
+               interpret, keep=None):
     B, H, T, D = q.shape
     Dv = v.shape[-1]
     qp, t_orig = _pad_to(q, 2, q_block)
@@ -417,31 +483,40 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
         hi_prec=q.dtype == jnp.float32)
     head = lambda b, j: (b, 0, 0)           # noqa: E731 — resident over j
     kv = lambda b, j: (b, j, 0)             # noqa: E731
+    operands = [qp, kp, vp, gp, lse, delta]
+    in_specs = [
+        pl.BlockSpec((1, Tq, D), head),
+        pl.BlockSpec((1, kv_block, D), kv),
+        pl.BlockSpec((1, kv_block, Dv), kv),
+        pl.BlockSpec((1, Tq, Dv), head),
+        pl.BlockSpec((1, nq, q_block), head),
+        pl.BlockSpec((1, nq, q_block), head),
+    ]
+    vmem = _bwd_vmem(Tq, kv_block, D, Dv, q.dtype)
+    if keep is not None:
+        operands += _kept_operands(keep, Tq, Tk, q_block)
+        in_specs += [
+            pl.BlockSpec((1, kv_block, Tq), lambda b, j: (b // H, j, 0)),
+            pl.BlockSpec((1, nq, 1, q_block),
+                         lambda b, j: (b // H, 0, 0, 0))]
+        vmem += 2 * _padded(kv_block, Tq, "float32")
     dq, dk, dv = pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, Tk, Dv), v.dtype)],
         grid=(BH, Tk // kv_block),
-        in_specs=[
-            pl.BlockSpec((1, Tq, D), head),
-            pl.BlockSpec((1, kv_block, D), kv),
-            pl.BlockSpec((1, kv_block, Dv), kv),
-            pl.BlockSpec((1, Tq, Dv), head),
-            pl.BlockSpec((1, nq, q_block), head),
-            pl.BlockSpec((1, nq, q_block), head),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, Tq, D), head),
             pl.BlockSpec((1, kv_block, D), kv),
             pl.BlockSpec((1, kv_block, Dv), kv),
         ],
         scratch_shapes=[pltpu.VMEM((Tq, D), jnp.float32)],
-        compiler_params=_compiler_params(
-            _bwd_vmem(Tq, kv_block, D, Dv, q.dtype)),
+        compiler_params=_compiler_params(vmem),
         interpret=interpret,
         name="flash_attention_bwd",
-    )(qp, kp, vp, gp, lse, delta)
+    )(*operands)
 
     dq = dq.reshape(B, H, Tq, D)[:, :, :t_orig]
     dk = dk.reshape(B, H, Tk, D)[:, :, :t_orig]
@@ -449,8 +524,9 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, q_block, kv_block,
     return dq, dk, dv
 
 
-def _dense_attention(q, k, v, scale, causal):
-    """XLA reference path (shapes too small to tile; the tests' oracle)."""
+def _dense_attention(q, k, v, scale, causal, keep=None):
+    """XLA reference path (shapes too small to tile; the tests' oracle).
+    With ``keep`` it returns (o, lse) as ``flash_attention`` does."""
     prec = jax.lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
     qf = q.astype(jnp.float32)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, k.astype(jnp.float32),
@@ -459,10 +535,43 @@ def _dense_attention(q, k, v, scale, causal):
         Tq, Tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((Tq, Tk), jnp.bool_), Tk - Tq)
         s = jnp.where(mask, s, _NEG_INF)
+    if keep is not None:
+        scores, least = jax.lax.stop_gradient(keep)
+        kept = scores.swapaxes(1, 2) >= least[:, :, None]     # (B, Tq, Tk)
+        s = jnp.where(kept[:, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32),
-                   precision=prec)
-    return o.astype(q.dtype)
+                   precision=prec).astype(q.dtype)
+    if keep is None:
+        return o
+    return o, jax.lax.stop_gradient(jax.nn.logsumexp(s, axis=-1))
+
+
+@functools.lru_cache(maxsize=32)
+def _make_flash_kept(scale, causal, q_block, kv_block, interpret):
+    """The call with kept keys: (q, k, v, scores, least) -> (out, lse
+    (B, H, T)).  No gradient reaches the selection's two arrays, and
+    none is taken from lse."""
+    @jax.custom_vjp
+    def fa(q, k, v, scores, least):
+        return fa_fwd(q, k, v, scores, least)[0]
+
+    def fa_fwd(q, k, v, scores, least):
+        B, H, T, _ = q.shape
+        out, lse = _flash_fwd(q, k, v, scale, causal, q_block, kv_block,
+                              interpret, (scores, least))
+        out, lse = remat.keep(out), remat.keep(lse)
+        rows = lse.reshape(B, H, -1)[:, :, :T]
+        return (out, rows), (q, k, v, scores, least, out, lse)
+
+    def fa_bwd(res, g):
+        q, k, v, scores, least, o, lse = res
+        grads = _flash_bwd(q, k, v, o, lse, g[0], scale, causal, q_block,
+                           kv_block, interpret, (scores, least))
+        return grads + (jnp.zeros_like(scores), jnp.zeros_like(least))
+
+    fa.defvjp(fa_fwd, fa_bwd)
+    return fa
 
 
 @functools.lru_cache(maxsize=32)
@@ -479,7 +588,7 @@ def _make_flash(scale, causal, q_block, kv_block, interpret):
         # a unit of recomputation keeps these two and forms q, k, v again
         # from its projections: without the marks the forward kernel
         # would run a second time for them (ops/remat.py)
-        out, lse = keep(out), keep(lse)
+        out, lse = remat.keep(out), remat.keep(lse)
         return out, (q, k, v, out, lse)
 
     def fa_bwd(res, g):
@@ -492,9 +601,19 @@ def _make_flash(scale, causal, q_block, kv_block, interpret):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, q_block=128,
-                    kv_block=128):
+                    kv_block=128, keep=None):
     """Streaming-softmax attention over (B, H, T, D); ``v`` may have a
     width of its own, (B, H, T, Dv), which is then the output's.
+
+    ``keep = (scores, least)`` is a set of kept keys per query that the
+    heads of a sequence share, as data: scores (B, T, T) float32 with the
+    KEYS on axis 1 and the queries on axis 2, least (B, T); query t
+    attends key s only where ``scores[b, s, t] >= least[b, t]`` (and
+    where ``causal`` lets it).  The kernels compare a tile at a time, so
+    no mask is ever formed.  The call then returns (out, lse): lse
+    (B, H, T) float32, the logsumexp of each query's scaled scores over
+    its kept keys.  No gradient reaches ``keep`` and none is taken from
+    lse.  A query must keep at least one key.
 
     Pallas kernel on TPU; interpret-mode on CPU (slow — tests only).
     Falls back to the dense XLA path when shapes are too small to tile.
@@ -504,11 +623,14 @@ def flash_attention(q, k, v, causal=False, scale=None, q_block=128,
     B, H, T, D = q.shape
     scale = float(scale if scale is not None else 1.0 / math.sqrt(D))
     if T < 16 or D % 8 != 0 or v.shape[-1] % 8 != 0:
-        return _dense_attention(q, k, v, scale, causal)
+        return _dense_attention(q, k, v, scale, causal, keep)
     q_block = min(q_block, T)
     kv_block = min(kv_block, T)
     interpret = jax.default_backend() == "cpu"
     counters.bump(KERNEL_NAME)
+    if keep is not None:
+        return _make_flash_kept(scale, causal, q_block, kv_block,
+                                interpret)(q, k, v, *keep)
     fa = _make_flash(scale, causal, q_block, kv_block, interpret)
     # inside a sharded training step or tp>1 decoder program GSPMD
     # cannot partition the kernel: split it over batch and heads

@@ -208,6 +208,104 @@ def test_the_whole_glm_step_fits_the_chip(on_chip, record_property):
     assert bound < 15.9e9               # the chip gives 16.909 GB
 
 
+def test_indexed_attention_at_the_keye_cells_geometry(on_chip):
+    """Keye-VL-2.0's attention layer in its cell: one row of 32 query
+    and 4 key heads of 128 at 8,192 positions whose keys an indexer of
+    16 heads of 64 selects (the 2,048 best of each row), forward and
+    backward, float32.  The flash kernels take a Q tile's column
+    (8,192 x 512) or a K/V block's row (512 x 8,192) of the selection's
+    scores beside the head; the indexer's four kernels walk tiles of 512
+    with an output tile carried over the grid's innermost axes."""
+    dsa = importlib.import_module("mxtpu.ops.dsa")
+    T = 8192
+    shapes = [_shape(s, F32, on_chip) for s in (
+        (1, 32, T, 128), (1, 4, T, 128), (1, 4, T, 128), (1, 16, T, 64),
+        (1, T, 64), (1, 16, T))]
+
+    def loss(*a):
+        o, kl, _ = dsa.indexed_attention(*a, top_k=2048)
+        return o.sum() + kl.sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *shapes).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd",
+                 "indexer_scores_fwd", "indexer_scores_bwd_q",
+                 "indexer_scores_bwd_k", "indexer_probs"):
+        assert name in text, name
+    # the (T, T) arrays are whole — scores, mean probabilities, what the
+    # loss forms of them — and nothing with a heads axis is
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert fa._vmem_limit(fa._bwd_vmem(T, 512, 128, 128, "float32")
+                          + 2 * fa._padded(512, T, "float32")) \
+        < 128 * 2 ** 20
+
+
+def test_the_whole_keye_step_fits_the_chip(on_chip, record_property):
+    """``SPMDTrainer``'s step of the Keye-VL cell at its own sizes
+    (659.2 M trained parameters, six layers, one sequence of 8,192,
+    Adam, recomputation per unit, the cross-entropy through the head in
+    blocks of rows, the indexers' loss) compiles for the described chip
+    inside its memory."""
+    import mxtpu as mx
+    from chipbench import harness, models
+    from mxtpu.models.keye_vl import keye_vl_from_config
+    from mxtpu.ops import remat
+    from mxtpu.parallel import SPMDTrainer
+
+    cfg = harness.load_json(harness.HERE, "configs",
+                            "keye-vl-2.0-30b-a3b.json")
+    net = keye_vl_from_config(
+        cfg, held=(cfg["held_experts_first"], cfg["num_experts"]),
+        num_experts_total=cfg["num_experts_total"], return_logits=False)
+    net.initialize(mx.init.Zero())
+    trainer = SPMDTrainer(
+        net, net.loss(cfg["index_loss_weight"]), cfg["train"]["optimizer"],
+        models.one_chip_mesh(jax.devices()[:1]),
+        optimizer_params={"learning_rate": cfg["train"]["learning_rate"]},
+        remat=cfg["train"]["remat"])
+    trainer._stage_params()             # no eager forward: shapes are known
+    step = trainer._make_step_fns()[0]
+    like = lambda a: _shape(a.shape, a.dtype, on_chip)
+    scalar, tokens = _shape((), F32, on_chip), _shape((1, 8192), jnp.int32,
+                                                      on_chip)
+    args = [tuple(like(p.data()._data) for p in trainer._diff_params),
+            tuple(like(p.data()._data) for p in trainer._aux_params),
+            jax.tree_util.tree_map(like, tuple(trainer._opt_states)),
+            scalar, scalar, tokens, tokens,
+            _shape((2,), jnp.uint32, on_chip)]
+    assert sum(a.size for a in args[0]) == cfg["trained_parameters"]
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(*args).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd",
+                 "indexer_scores_fwd", "indexer_scores_bwd_q",
+                 "indexer_scores_bwd_k", "indexer_probs"):
+        assert text.count(name) >= 6, name
+    # a unit keeps its attention's output, logsumexp and thresholds, and
+    # the indexer's queries, key and weights, so that the scores it forms
+    # again are the first's to the bit; the (T, T) scores and mean
+    # probabilities are formed again (kept, the mean probabilities cost
+    # 3.06 GB of the bound and, on the chip, four times the gap of the
+    # indexer's gradient: PERF.md, PR 35)
+    kept = remat.counts()
+    assert kept["kept_outputs"] == 36
+    assert kept["kept_bytes"] == 6 * 4 * (
+        32 * 8192 * 128 + 32 * 8192 + 8192
+        + 16 * 8192 * 64 + 8192 * 64 + 16 * 8192)
+    m = compiled.memory_analysis()
+    bound = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    record_property("keye_step_code_bytes", m.generated_code_size_in_bytes)
+    record_property("keye_step_memory_bound_bytes", bound)
+    print("Keye step: code %.3f GB, arguments %.3f GB, temporaries %.3f GB, "
+          "bound %.3f GB" % (m.generated_code_size_in_bytes / 1e9,
+                             m.argument_size_in_bytes / 1e9,
+                             m.temp_size_in_bytes / 1e9, bound / 1e9))
+    assert 0 <= m.argument_size_in_bytes \
+        - 12 * cfg["trained_parameters"] < 2 ** 20
+    assert bound < 13e9                 # the chip gives 16.909 GB
+
+
 @pytest.mark.parametrize("T", [8192, 96], ids=["cell", "toy"])
 def test_kda_kernels(on_chip, T, heads=4, K=128):
     """KDA's kernels, forward and backward, at one call of the cell (4

@@ -641,3 +641,58 @@ def test_prefill_specs_in_the_merge_gate():
     assert "paged_attention[float32,W=1,bs=16,D=128,tp=4" in names \
         or ("paged_attention" in names and "tp=4" in names)
     assert "paged_prefill" in names and "tp=4" in names
+
+
+def test_indexed_attention_specs_describe_the_real_calls(monkeypatch):
+    """The six pallas_calls of one forward and backward of
+    ``ops/dsa.py``'s indexed attention — the flash kernels with kept keys
+    and the indexer's four — are the ones ``kernel_specs`` describe:
+    names, grids, block shapes, the VMEM asked for.  Traced only: the
+    same calls run against their equations in tests/test_keye_vl.py."""
+    import importlib
+    import jax
+
+    fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+    ix = importlib.import_module("mxtpu.ops.pallas.indexer")
+    dsa = importlib.import_module("mxtpu.ops.dsa")
+    B, H, G, D, Hi, d, T = 1, 4, 2, 16, 3, 8, 640
+    calls = []
+    real = fa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        calls.append(kw)
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)     # one pl for both
+    fa._make_flash_kept.cache_clear()
+    ones = lambda *s: jnp.ones(s, jnp.float32)
+    jax.make_jaxpr(jax.grad(
+        lambda *a: sum(x.sum() for x in dsa.indexed_attention(
+            *a, top_k=64)[:2]), argnums=tuple(range(6))))(
+        ones(B, H, T, D), ones(B, G, T, D), ones(B, G, T, D),
+        ones(B, Hi, T, d), ones(B, T, d), ones(B, Hi, T))
+    specs = {s.name.split("[")[0].replace(".", "_"): s for s in
+             fa.kernel_specs(B, H, T, D, interpret=True, kept=True)
+             + ix.kernel_specs(B, Hi, T, d, H, G, D, interpret=True)}
+    assert sorted(c["name"] for c in calls) == sorted(specs)
+    for call in calls:
+        spec = specs[call["name"]]
+        assert tuple(call["grid"]) == spec.grid, spec.name
+        for kind, key in (("in", "in_specs"), ("out", "out_specs")):
+            blocks = call[key] if isinstance(call[key], (list, tuple)) \
+                else [call[key]]
+            assert [tuple(b.block_shape) for b in blocks] == \
+                [op.block_shape for op in spec.operands
+                 if op.kind == kind], (spec.name, kind)
+        params = call.get("compiler_params")
+        limit = getattr(params, "vmem_limit_bytes", None)
+        assert limit == spec.vmem_limit, spec.name
+
+
+def test_indexed_attention_specs_in_the_merge_gate():
+    names = " ".join(s.name for s in default_kernel_specs())
+    for name in ("flash_attention.fwd[float32,T=8192,D=128,kept]",
+                 "flash_attention.bwd[float32,T=8192,D=128,kept]",
+                 "indexer_scores_fwd[", "indexer_probs[",
+                 "indexer_scores_bwd_q[", "indexer_scores_bwd_k["):
+        assert name in names, name

@@ -1,0 +1,369 @@
+"""KeyeVLTextLM through SPMDTrainer.step against its plain reference
+(chipbench/references/keye_vl.py: the indexer's scores, the selection by
+a row's top-k, attention over the kept keys and the indexer's loss a
+block of query rows at a time, every held expert on every token):
+logits, both loss terms, every leaf's first gradient and three Adam
+steps, float32, at toy widths on seeded weights.  And the parts one by
+one: the selection's prefix, the partition of the gradient, the rotation
+with three streams, ``flash_attention(keep=...)``, the k-th largest, the
+softmax-routed experts, the counters."""
+
+import importlib
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import mxtpu as mx
+from mxtpu import autograd
+from mxtpu.models import keye_vl
+from mxtpu.observability.metrics import get_registry
+from mxtpu.ops import dsa, moe
+from mxtpu.ops.pallas import counters, indexer
+
+from chipbench import harness, models, models_keye
+
+fa = importlib.import_module("mxtpu.ops.pallas.flash_attention")
+ref = importlib.import_module("chipbench.references.keye_vl")
+CFG = harness.load_json(harness.HERE, "tests", "configs", "keye-tiny.json")
+STEPS, LR, B, T = 3, 1e-3, 2, 40
+TOPK = CFG["sa_config"]["topk"]
+INDEXER = ("index_q", "index_k", "index_k_gain", "index_k_bias", "index_w")
+
+
+def _batch(seed=0):
+    rng = np.random.default_rng(seed)
+    return tuple(rng.integers(0, CFG["vocab_size"], (B, T), dtype=np.int32)
+                 for _ in range(2))
+
+
+def _as_nd(*arrays):
+    return tuple(mx.nd.array(a, dtype="int32") for a in arrays)
+
+
+def _weights(seed=5):
+    weights = ref.init_weights(CFG, seed)
+    return weights, {k: jnp.asarray(v) for k, v in weights.items()}
+
+
+def _is_indexer(name):
+    return name.split(".")[-1] in INDEXER
+
+
+def test_the_toy_configuration_has_every_part():
+    assert TOPK < T                                     # the selection binds
+    assert CFG["num_experts"] < CFG["num_experts_total"]        # a share
+    assert CFG["num_key_value_heads"] < CFG["num_attention_heads"]
+    assert len(set(CFG["rope_scaling"]["mrope_section"])) > 1
+    assert sum(CFG["rope_scaling"]["mrope_section"]) == CFG["head_dim"] // 2
+
+
+# -------------------------------------------------- the model, forward
+
+@pytest.fixture(scope="module")
+def forward():
+    tokens, _ = _batch()
+    weights, w = _weights()
+    net, _ = models_keye.keye_vl_lm(CFG, weights)
+    before = keye_vl.dsa_counts()
+    logits, index_loss = net(*_as_nd(tokens))
+    return ((logits.asnumpy(), index_loss.asnumpy()),
+            tuple(np.asarray(a) for a in ref.logits_of(CFG, w, tokens)),
+            before, keye_vl.dsa_counts(), net)
+
+
+def test_logits_match_the_reference(forward):
+    (got, _), (want, _, _), *_ = forward
+    assert got.shape == (B, T, CFG["vocab_size"])
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+
+
+def test_the_indexers_loss_matches_the_reference(forward):
+    (_, got), (_, want, _), *_ = forward
+    assert got.shape == (B,) and (want > 0.01).all()
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+
+
+def test_the_counters_count_the_kept_pairs_and_the_loss(forward):
+    (_, index_loss), (_, _, kept), before, after, net = forward
+    layers = CFG["num_hidden_layers"]
+    prefix = sum(min(t + 1, TOPK) for t in range(T))    # with no tie
+    assert layers * B * prefix <= kept.sum() < layers * B * prefix * 1.1
+    assert after["selected_pairs"] - before.get("selected_pairs", 0.0) \
+        == kept.sum()
+    np.testing.assert_allclose(
+        after["kl_sum"] - before.get("kl_sum", 0.0), index_loss.sum(),
+        rtol=1e-5)
+    means = [after[net.decoder_layer(i)[0].inner.prefix.rstrip("_")
+                   + ".kept_mean"] for i in range(layers)]
+    np.testing.assert_allclose(sum(means) * B * T, kept.sum(), rtol=1e-6)
+    snap = get_registry().snapshot()
+    assert snap["dsa.selected_pairs"] >= kept.sum()
+    for name in (indexer.SCORES_FWD_NAME, indexer.PROBS_NAME):
+        assert snap["kernel_invocations." + name] >= layers
+
+
+def test_three_position_streams_reach_the_rotation():
+    """Streams that differ give other logits than text's, and the
+    reference's: positions (3, T) go all the way down."""
+    tokens, _ = _batch()
+    weights, w = _weights()
+    net, _ = models_keye.keye_vl_lm(CFG, weights)
+    rng = np.random.default_rng(3)
+    positions = np.sort(rng.integers(0, 3 * T, (3, T)), axis=1).astype(
+        np.int32)
+    got, got_loss = net(*_as_nd(tokens, positions))
+    want, want_loss, _ = ref.logits_of(CFG, w, tokens,
+                                       jnp.asarray(positions))
+    text, _ = net(*_as_nd(tokens))
+    np.testing.assert_allclose(got.asnumpy(), np.asarray(want), rtol=0,
+                               atol=2e-6)
+    np.testing.assert_allclose(got_loss.asnumpy(), np.asarray(want_loss),
+                               rtol=2e-6)
+    assert np.abs(got.asnumpy() - text.asnumpy()).max() > 1e-3
+
+
+# ------------------------------------------ the model through the trainer
+
+@pytest.fixture(scope="module")
+def trained():
+    """Three steps of the trainer and of the reference on the same
+    batches: losses, the first gradient's leaf norms, the weights."""
+    weights, w = _weights()
+    train = dict(dtype="float32", optimizer="adam", learning_rate=LR,
+                 remat=True)
+    trainer, named = models_keye.keye_vl_trainer(CFG, train, weights,
+                                                 jax.devices()[:1])
+    state = tuple({k: jnp.zeros_like(v) for k, v in w.items()}
+                  for _ in range(2))
+    losses, ref_losses, grads = [], [], None
+    for n in range(STEPS):
+        tokens, labels = _batch(n)
+        losses.append(float(trainer.step(*_as_nd(tokens, labels))))
+        total, g = jax.value_and_grad(
+            lambda w_: ref.loss_sum(CFG, w_, tokens, labels))(w)
+        g = {k: v / (B * T) for k, v in g.items()}
+        ref_losses.append(float(total) / (B * T))
+        if n == 0:
+            _, mean = models.trainer_state(trainer, named)
+            grads = ({k: float(v) / (1 - ref.BETA1) for k, v in
+                      models.leaf_norms(mean).items()},
+                     {k: float(v) for k, v in models.leaf_norms(g).items()})
+        w, state = ref.adam_step(w, g, state, LR, n + 1)
+    params, _ = models.trainer_state(trainer, named)
+    return losses, ref_losses, grads, \
+        {k: np.asarray(v) for k, v in params.items()}, \
+        {k: np.asarray(v) for k, v in w.items()}, weights
+
+
+def test_the_two_term_loss_matches_the_reference(trained):
+    losses, ref_losses, *_ = trained
+    np.testing.assert_allclose(losses, ref_losses, rtol=2e-6)
+
+
+@pytest.mark.parametrize("name", sorted(ref.weight_shapes(CFG)))
+def test_every_leafs_first_gradient_matches_the_reference(trained, name):
+    got, want = trained[2]
+    assert want[name] > 0
+    np.testing.assert_allclose(got[name], want[name], rtol=2e-5)
+
+
+def test_three_adam_steps_match_the_reference(trained):
+    *_, params, w, start = trained
+    for name in sorted(w):
+        moved = np.abs(w[name] - start[name]).max()
+        assert moved > 0, name
+        np.testing.assert_allclose(params[name], w[name], rtol=0,
+                                   atol=0.02 * moved, err_msg=name)
+
+
+# --------------------------------------------- the gradient's partition
+
+@pytest.mark.parametrize("term", ["cross_entropy", "indexer"])
+def test_each_term_moves_its_own_leaves_alone(term):
+    """The cross-entropy's gradient is exactly zero on the indexer's
+    leaves and the indexer's loss's exactly zero on all the others."""
+    tokens, labels = _batch()
+    weights, _ = _weights()
+    net, named = models_keye.keye_vl_lm(CFG, weights)
+    loss = net.loss(index_weight=0.0 if term == "cross_entropy" else 1.0)
+    with autograd.record():
+        out = net(*_as_nd(tokens))
+        total = loss(out, _as_nd(labels)[0]) if term == "cross_entropy" \
+            else out[1]
+    total.backward()
+    for name, param in named.items():
+        size = float(np.abs(param.grad().asnumpy()).max())
+        if _is_indexer(name) == (term == "indexer"):
+            assert size > 0, name
+        else:
+            assert size == 0, name
+
+
+# ------------------------------------------------------------ the parts
+
+def _attention_inputs(T, D, heads=4, groups=2, idx_heads=3, d=8, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    n = lambda k, *s: jax.random.normal(k, s, jnp.float32)
+    return (n(ks[0], 1, heads, T, D), n(ks[1], 1, groups, T, D),
+            n(ks[2], 1, groups, T, D), n(ks[3], 1, idx_heads, T, d),
+            n(ks[4], 1, T, d), n(ks[5], 1, idx_heads, T))
+
+
+def test_rows_with_no_more_than_topk_predecessors_are_dense_attention():
+    """The selection's prefix: a query with at most ``top_k`` keys
+    before it keeps them all, and its output is causal flash attention's
+    bit for bit."""
+    q, k, v, *idx = _attention_inputs(T=160, D=16)
+    o, _, kept = dsa.indexed_attention(q, k, v, *idx, top_k=64)
+    dense = fa.flash_attention(q, jnp.repeat(k, 2, 1), jnp.repeat(v, 2, 1),
+                               causal=True)
+    np.testing.assert_array_equal(np.asarray(o[:, :, :64]),
+                                  np.asarray(dense[:, :, :64]))
+    assert np.abs(np.asarray(o[:, :, 64:] - dense[:, :, 64:])).max() > 1e-3
+    # ties at a row's threshold (exact zeros of the ReLU) are all kept
+    assert float(kept[0]) >= sum(min(t + 1, 64) for t in range(160))
+
+
+@pytest.mark.parametrize("k", [1, 5, 64])
+def test_kth_largest_is_the_sorted_rows_kth(k):
+    x = jax.random.normal(jax.random.PRNGKey(k), (3, 64, 50))
+    x = x.at[0, :9].set(0.0).at[1, ::2].multiply(-0.0)   # ties, both zeros
+    got = dsa.kth_largest(x, k, axis=1)
+    want = jnp.sort(x, axis=1)[:, -k]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("D", [64, 128, 256])
+def test_flash_attention_with_kept_keys_matches_the_dense_path(D):
+    """Forward and backward of ``flash_attention(keep=...)`` in interpret
+    mode against ``_dense_attention`` under the same mask, at a key count
+    the blocks have to pad (200 of 256)."""
+    T = 200
+    ks = jax.random.split(jax.random.PRNGKey(D), 5)
+    q, k, v, g = (jax.random.normal(key, (1, 2, T, D), jnp.float32)
+                  for key in ks[:4])
+    scores = jax.random.normal(ks[4], (1, T, T), jnp.float32)
+    at = jnp.arange(T)
+    causal = at[:, None] <= at[None, :]                 # keys first
+    least = jnp.sort(jnp.where(causal, scores, -jnp.inf), axis=1)[:, -24]
+    least = jnp.where(at >= 24, least, -jnp.inf)
+    scale = D ** -0.5
+
+    def through(attend):
+        (o, lse), back = jax.vjp(lambda q, k, v: attend(q, k, v), q, k, v)
+        return (o, lse) + back((g, jnp.zeros_like(lse)))
+
+    got = through(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, keep=(scores, least)))
+    want = through(lambda q, k, v: fa._dense_attention(
+        q, k, v, scale, True, (scores, least)))
+    for a, b, name in zip(got, want, ("o", "lse", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5, err_msg=name)
+    # rows past the 24th keep 24 keys: not what plain causal gives
+    plain = fa.flash_attention(q, k, v, causal=True)
+    assert np.abs(np.asarray(got[0] - plain)[:, :, 24:]).max() > 1e-2
+
+
+def test_the_indexers_kernels_match_their_equations():
+    """Scores forward and backward and the mean probabilities, at a
+    length that pads (200 of 256, tiles of 256), against plain XLA."""
+    T, H, d = 200, 3, 8
+    q, k, _, q_idx, k_idx, w_idx = _attention_inputs(T, 16, idx_heads=H, d=d)
+    at = jnp.arange(T)
+    causal = at[:, None] <= at[None, :]
+
+    def plain(q_idx, k_idx, w_idx):
+        z = jnp.einsum("bhtd,bsd->bhst", q_idx, k_idx,
+                       precision=jax.lax.Precision.HIGHEST)
+        return jnp.where(causal, jnp.sum(
+            w_idx[:, :, None, :] * jax.nn.relu(z), 1), indexer.MASKED)
+
+    g = jax.random.normal(jax.random.PRNGKey(9), (1, T, T))
+    got, back = jax.vjp(indexer.indexer_scores, q_idx, k_idx, w_idx)
+    want, ref_back = jax.vjp(plain, q_idx, k_idx, w_idx)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    for a, b, name in zip(back(g), ref_back(g), ("q_idx", "k_idx", "w")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
+    least = dsa._threshold(got, 24)
+    scale = 0.25
+    o, lse = fa.flash_attention(q, jnp.repeat(k, 2, 1), jnp.repeat(k, 2, 1),
+                                causal=True, scale=scale, keep=(got, least))
+    pbar = indexer.indexer_probs(q, k, lse, got, least, scale)
+    want = dsa._dense_probs(q, k, lse, got, least, scale)
+    np.testing.assert_allclose(np.asarray(pbar), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pbar.sum(1)), 1.0, rtol=1e-5)
+
+
+def test_mrope_with_three_streams_and_with_one():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 24, 16))
+    positions = np.sort(np.random.default_rng(1).integers(
+        0, 90, (3, 24)), axis=1).astype(np.int32)
+    got = mx.nd.mrope(mx.nd.array(x), mx.nd.array(positions, dtype="int32"),
+                      sections=(2, 3, 3), base=1e7).asnumpy()
+    want = ref.rotate(jnp.swapaxes(x, 1, 2), jnp.asarray(positions), 1e7,
+                      [2, 3, 3])
+    np.testing.assert_allclose(got, np.asarray(jnp.swapaxes(want, 1, 2)),
+                               rtol=1e-6, atol=1e-6)
+    # every stream the index: F.rope, bit for bit
+    text = np.broadcast_to(np.arange(24, dtype=np.int32), (3, 24))
+    one = mx.nd.mrope(mx.nd.array(x), mx.nd.array(text, dtype="int32"),
+                      sections=(2, 3, 3), base=1e7).asnumpy()
+    np.testing.assert_array_equal(one, mx.nd.rope(mx.nd.array(x),
+                                                  base=1e7).asnumpy())
+    with pytest.raises(ValueError, match="do not add up"):
+        mx.nd.mrope(mx.nd.array(x), mx.nd.array(text, dtype="int32"),
+                    sections=(2, 3), base=1e7)
+
+
+def test_softmax_routed_experts_match_the_reference():
+    """``moe_expert_share(score="softmax")`` with no selection bias
+    against the reference's layer, forward and the router's gradient."""
+    cfg = dict(CFG, num_experts=4, held_experts_first=4)
+    ks = jax.random.split(jax.random.PRNGKey(2), 5)
+    C, F, E = CFG["hidden_size"], CFG["moe_intermediate_size"], 16
+    n = lambda k, *s: 0.3 * jax.random.normal(k, s)
+    w = {"router": n(ks[0], E, C), "experts_gate": n(ks[1], 4, C, F),
+         "experts_up": n(ks[2], 4, C, F), "experts_down": n(ks[3], 4, F, C)}
+    x = jax.random.normal(ks[4], (2, 24, C))
+
+    def program(router):
+        return moe.moe_expert_share(
+            x, router, jnp.zeros((E,)), w["experts_gate"], w["experts_up"],
+            w["experts_down"], held_first=4, top_k=4, score="softmax")[0]
+
+    def reference(router):
+        return ref._expert_layer(cfg, dict(w, router=router), "", x,
+                                 "highest")
+
+    np.testing.assert_allclose(np.asarray(program(w["router"])),
+                               np.asarray(reference(w["router"])),
+                               rtol=1e-5, atol=2e-6)
+    got, want = (jax.grad(lambda r: jnp.sum(jnp.square(f(r))))(w["router"])
+                 for f in (program, reference))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4,
+                               atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    with pytest.raises(KeyError):
+        moe.moe_expert_share(x, w["router"], jnp.zeros((E,)),
+                             w["experts_gate"], w["experts_up"],
+                             w["experts_down"], score="tanh")
+
+
+def test_from_config_builds_the_share_and_refuses_dense_layers():
+    net = keye_vl.keye_vl_from_config(
+        CFG, held=(CFG["held_experts_first"], CFG["num_experts"]),
+        num_experts_total=CFG["num_experts_total"])
+    _, ffn = net.decoder_layer(0)
+    assert ffn.inner.experts_gate.shape[0] == CFG["num_experts"]
+    assert ffn.inner.router.weight.shape[0] == CFG["num_experts_total"]
+    assert ffn.inner.shared is None
+    with pytest.raises(ValueError, match="dense layers"):
+        keye_vl.keye_vl_from_config(dict(CFG, mlp_only_layers=[0]))
+    with pytest.raises(ValueError, match="one key head"):
+        keye_vl.keye_vl_from_config(dict(CFG, sa_config=dict(
+            CFG["sa_config"], indexer_num_kv_heads=2)))

@@ -79,6 +79,39 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch, shares, skew,
         assert load[3] == x.shape[0] * x.shape[1]          # every token
 
 
+@pytest.mark.parametrize("shares,total,k", [(8, 128, 8)],
+                         ids=["eight_shares_of_16_of_128"])
+def test_softmax_routed_shares_add_up_to_the_uncut_layer(shares, total, k):
+    """The softmax-scored layer with no shared expert and no selection
+    bias (Keye-VL's: 128 experts, 8 a token, renormalised): eight
+    ranks' shares of 16 experts sum to what the plain reference
+    (chipbench/references/keye_vl.py) computes with all 128 held."""
+    keye = importlib.import_module("chipbench.references.keye_vl")
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    n = lambda key, *s: 0.2 * jax.random.normal(key, s)
+    w = {"router": n(ks[0], total, D), "experts_gate": n(ks[1], total, D, F),
+         "experts_up": n(ks[2], total, D, F),
+         "experts_down": n(ks[3], total, F, D)}
+    x = jax.random.normal(ks[4], (2, 40, D))
+    held = total // shares
+    summed, pairs = 0.0, 0
+    for first in range(0, total, held):
+        sl = slice(first, first + held)
+        y, load = moe.moe_expert_share(
+            x, w["router"], jnp.zeros((total,)), w["experts_gate"][sl],
+            w["experts_up"][sl], w["experts_down"][sl], held_first=first,
+            top_k=k, score="softmax")
+        summed = summed + y
+        pairs += int(np.asarray(load)[:-1].sum())
+    assert pairs == x.shape[0] * x.shape[1] * k         # none dropped
+    cfg = dict(num_experts=total, held_experts_first=0,
+               num_experts_per_tok=k, norm_topk_prob=True)
+    np.testing.assert_allclose(
+        np.asarray(summed),
+        np.asarray(keye._expert_layer(cfg, w, "", x, "highest")),
+        rtol=1e-5, atol=2e-6)
+
+
 @pytest.mark.parametrize("tile", [4096, 48],
                          ids=["wide_tiles", "narrow_tiles"])
 def test_gradients_of_a_share_match_the_reference(monkeypatch, tile):

@@ -349,6 +349,8 @@ CASES.update({
         lambda: (A(5, 2, 16), A(4, 5, 5)), {"heads": 2}),
     "rms_norm": C(lambda: (A(3, 8), POS(8))),
     "rope": C(lambda: (A(2, 2, 4, 8),)),
+    "mrope": C(lambda: (A(2, 2, 4, 8), IDX(3, 4, n=9)),
+               {"sections": (1, 2, 1)}, grad_args=(0,)),
     "smooth_l1_dup": None,  # placeholder removed below
     # -- nn ops ----------------------------------------------------------
     "FullyConnected": C(lambda: (A(3, 4), A(5, 4), A(5)),
@@ -414,6 +416,11 @@ SKIP = {
                 "grad over the lattice is O(T*V) slow",
     "flash_attention": "covered by tests/test_flash_attention.py "
                        "(fwd parity + gradients)",
+    "indexed_attention": "a discrete selection (a row's k-th largest) "
+                         "and Pallas kernels: numeric gradients cross "
+                         "the selection's boundaries; value and every "
+                         "input's gradient covered by tests/test_keye_vl.py "
+                         "against the plain reference",
     "kda": "chunked recurrence with Pallas state kernels; covered by "
            "tests/test_kda.py (forward and every input's gradient "
            "against the step-by-step recurrence)",

@@ -433,3 +433,66 @@ def test_eager_gradients_survive_the_spmd_trainers_release(reader):
         want = [np.zeros_like(g) for g in want]
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
+def _toy_step_text(model):
+    """The StableHLO of ``SPMDTrainer``'s step over the toy configuration
+    of one of the benchmark's accepted models, built as its runner
+    builds it."""
+    import importlib
+    from chipbench import harness, models as bench_models, models_glm, \
+        models_lm
+
+    config, reference, seq = {
+        "bert": ("bert-tiny.json", "bert", 128),
+        "kimi_linear": ("kimi-linear-tiny.json", "kimi_linear", 40),
+        "glm4_moe_lite": ("glm-tiny.json", "glm4_moe_lite", 40)}[model]
+    cfg = harness.load_json(harness.HERE, "tests", "configs", config)
+    ref = importlib.import_module("chipbench.references." + reference)
+    tokens = mx.nd.array(np.random.default_rng(0).integers(
+        0, cfg["vocab_size"], (2, seq), dtype=np.int32), dtype="int32")
+    train = dict(dtype="float32", optimizer="adam", learning_rate=1e-3,
+                 remat=True, seq=seq)
+    weights, devices = ref.init_weights(cfg, 5), jax.devices()[:1]
+    if model == "bert":
+        trainer, _ = bench_models.bert_trainer(cfg, train, weights, devices)
+    else:
+        build = models_lm.kimi_linear_trainer if model == "kimi_linear" \
+            else models_glm.glm4_moe_lite_trainer
+        trainer, _ = build(cfg, train, weights, ref.selection_bias(cfg),
+                           devices)
+    return trainer.lower_step(tokens, tokens).as_text()
+
+
+@pytest.mark.parametrize("model,whiles,sorts,parent", [
+    ("bert", 8, 0,
+     "abbbdbbad2fd8722270ee4cc17e7195ca1721408b970548380c89a5530bd2286"),
+    ("kimi_linear", 40, 2,
+     "76a28a798b4d188d5af899ba18833729f506766c72ec7e65a39deef7fec8a29e"),
+    ("glm4_moe_lite", 26, 2,
+     "cf34519cf450ba52af90b33f02c408ec8e65435467912060cc3483f5181a3583"),
+])
+def test_the_accepted_models_steps_lower_as_before_the_kept_keys(
+        model, whiles, sorts, parent):
+    """A set of kept keys in ``flash_attention``, a softmax score in the
+    expert layer and the indexer are paths the three accepted cells'
+    models do not take: their toy steps lower to the StableHLO they
+    lowered to before those paths were there.
+
+    First what a reader can see: no unsigned-integer value (only the
+    selection's counting loop forms any), and the loops and sorts the
+    step had.  Then the whole text, by its sha256 at commit 85e0e7b; to
+    take it again: ``git archive 85e0e7b | tar -x -C <dir>``, copy this
+    function and ``_toy_step_text`` into a test there, print
+    ``hashlib.sha256(text.encode()).hexdigest()``.  The text is JAX's
+    as much as ours: a change that means to alter these programs, or a
+    new JAX, takes new digests at its own parent and says so."""
+    import hashlib
+    import re
+
+    text = _toy_step_text(model)
+    assert "ui32" not in text
+    assert len(re.findall(r"stablehlo\.while", text)) == whiles
+    assert len(re.findall(r"stablehlo\.sort", text)) == sorts
+    assert hashlib.sha256(text.encode()).hexdigest() == parent, \
+        "the step's StableHLO is not the parent's (%d characters)" % len(text)
