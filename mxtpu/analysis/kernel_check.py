@@ -621,8 +621,9 @@ def default_kernel_specs() -> List[KernelSpec]:
       heads in VMEM, asked for through ``vmem_limit``;
     - the Keye-VL cell's indexed attention (T=8192): the flash kernels
       with kept keys at 32 heads of 128, and the indexer's scores
-      forward, mean probabilities and two backward passes (16 heads of
-      64), an output tile carried over the grid's innermost axes;
+      forward, mean probabilities and one backward pass (16 heads of
+      64, a tile's heads a grid step), the query gradient carried over
+      the key tiles and the key gradient resident for the call;
     - KDA's kernels (the chunks' operands forward and backward, the
       state pass forward writing states and backward) at 8,192 positions
       and at a toy length, heads and chunks of 128 x 64;
@@ -673,7 +674,7 @@ def default_kernel_specs() -> List[KernelSpec]:
     # indexed sparse attention of the Keye-VL cell: the flash kernels
     # with a sequence's kept keys (32 heads of 128, a tile's column or a
     # block's row of the (T, T) selection scores beside the head), and
-    # the indexer's four kernels (16 heads of 64; 4 key heads)
+    # the indexer's three kernels (16 heads of 64; 4 key heads)
     specs.extend(flash_attention.kernel_specs(
         B=1, H=32, T=8192, D=128, dtype="float32", kept=True))
     specs.extend(indexer.kernel_specs(B=1, Hi=16, T=8192, d=64, H=32, G=4,

@@ -8,20 +8,27 @@ Every (T, T) array here lies KEYS FIRST, ``(B, Tk, Tq)`` — a tile is
 weight, threshold or logsumexp a row of lanes — and holds ``MASKED``
 where s > t.
 
-- ``indexer_scores_fwd`` writes I, a head of the indexer a grid step, the
-  sum carried in the output tile;
-- ``indexer_scores_bwd_q`` and ``indexer_scores_bwd_k`` turn I's
-  cotangent into those of qI and w, and of kI: two passes that each form
-  the heads' products again, because what they sum over differs (keys
-  for a query's, queries and heads for a key's) and a tile's output
-  block can be carried over the grid's innermost steps only;
-- ``indexer_probs`` re-forms the main attention's probabilities from q,
-  k and the logsumexp its kernel kept, a head a grid step, and writes
-  their mean over the heads at the kept keys (0 elsewhere): what the
-  indexer's loss is measured against.
+A grid step of every kernel is one (query tile, key tile) pair, the key
+tiles innermost, and walks that tile's heads itself: the tile's mask,
+its cotangent, its scores and thresholds are read once a step and the
+one key block once for all the heads that share it.
 
-Tiles above the diagonal are not computed.  float32 throughout, products
-at ``Precision.HIGHEST``; interpret mode on the CPU.
+- ``indexer_scores_fwd`` writes I: a step sums its heads' terms in the
+  heads' order and masks the tile once;
+- ``indexer_scores_bwd_q_k`` turns I's cotangent into those of qI, w and
+  kI in one pass that forms each head's product once: a query tile's
+  dqI and dw are carried over the key tiles, and kI's whole gradient —
+  one key head, (T, d) — stays in VMEM for the call.  dqI and dkI are
+  formed turned, ``(d, queries)`` and ``(d, keys)``: d rows stream
+  through the matrix unit where the row form streams a tile's;
+- ``indexer_probs`` re-forms the main attention's probabilities from q,
+  k and the logsumexp its kernel kept and writes their mean over the
+  heads at the kept keys (0 elsewhere): what the indexer's loss is
+  measured against.
+
+A step above the diagonal computes nothing and fetches nothing (its
+index maps name the blocks the step before it held).  float32
+throughout, products at ``Precision.HIGHEST``; interpret mode on the CPU.
 """
 
 from __future__ import annotations
@@ -35,7 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import counters
-from .flash_attention import _tile
+from .flash_attention import _padded, _tile, _vmem_limit
 
 __all__ = ["MASKED", "indexer_scores", "indexer_probs", "kernel_specs"]
 
@@ -43,8 +50,9 @@ __all__ = ["MASKED", "indexer_scores", "indexer_probs", "kernel_specs"]
 MASKED = -1e30
 
 SCORES_FWD_NAME = "indexer_scores_fwd"
-SCORES_BWD_Q_NAME = "indexer_scores_bwd_q"
-SCORES_BWD_K_NAME = "indexer_scores_bwd_k"
+#: the name the benchmark's pattern for the queries' pass finds, and the
+#: keys' pass's does not
+SCORES_BWD_NAME = "indexer_scores_bwd_q_k"
 PROBS_NAME = "indexer_probs"
 
 _HI = jax.lax.Precision.HIGHEST
@@ -52,13 +60,6 @@ _dot = functools.partial(jax.lax.dot_general, precision=_HI,
                          preferred_element_type=jnp.float32)
 _A_BT = (((1,), (1,)), ((), ()))               # a @ b.T
 _A_B = (((1,), (0,)), ((), ()))                # a @ b
-_AT_B = (((0,), (0,)), ((), ()))               # a.T @ b
-
-#: the grid's innermost axis (or two) carries an output tile
-_CARRY_1 = pltpu.CompilerParams(dimension_semantics=(
-    "parallel", "parallel", "parallel", "arbitrary"))
-_CARRY_2 = pltpu.CompilerParams(dimension_semantics=(
-    "parallel", "parallel", "arbitrary", "arbitrary"))
 
 
 def _geometry(T):
@@ -82,75 +83,121 @@ def _allowed(j, i, tile, valid):
     return (k_pos <= q_pos) & (k_pos < valid)
 
 
+# Every grid is (batch, query tile i, key tile j).  An input's key tile
+# is min(j, i): a step above the diagonal names the diagonal step's
+# blocks, which are there already.
+
+def _keys(b, i, j):                            # a block of (B, Tp, d)
+    return b, jnp.minimum(j, i), 0
+
+
+def _square(b, i, j):                          # a tile of a (B, Tp, Tp) input
+    return b, jnp.minimum(j, i), i
+
+
+def _square_out(b, i, j):
+    return b, j, i
+
+
+def _queries(b, i, j):                         # a tile's heads, (B, H, Tp, d)
+    return b, 0, i, 0
+
+
+def _queries_turned(b, i, j):                  # (B, H, d, Tp), (B, H, 1, Tp)
+    return b, 0, 0, i
+
+
+def _vmem(specs, tile):
+    """What a call with these blocks asks the compiler for: the blocks,
+    twice buffered, and eight (tile, tile) float32 for what a head's
+    trip forms (None while the compiler's own limit holds them)."""
+    blocks = sum(math.prod(s.block_shape[:-2])
+                 * _padded(*s.block_shape[-2:], "float32") for s in specs)
+    return _vmem_limit(2 * blocks + 32 * tile * tile)
+
+
+def _params(specs, tile, carried):
+    """CompilerParams of a call with these blocks whose grid's last
+    ``carried`` axes carry an output block."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (3 - carried)
+        + ("arbitrary",) * carried,
+        vmem_limit_bytes=_vmem(specs, tile))
+
+
 # ------------------------------------------------------------------ scores
 
 def _scores_fwd_kernel(k_ref, q_ref, w_ref, out_ref, *, tile, valid):
-    j, i, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-
-    @pl.when(h == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(j <= i)
-    def _():
-        z = _dot(k_ref[0], q_ref[0, 0], _A_BT)              # (keys, queries)
-        out_ref[0] += w_ref[0, 0] * jnp.maximum(z, 0.0)
-
-    @pl.when(h == pl.num_programs(3) - 1)
-    def _():
-        out_ref[0] = jnp.where(_allowed(j, i, tile, valid), out_ref[0],
-                               MASKED)
-
-
-def _scores_bwd_q_kernel(k_ref, q_ref, w_ref, g_ref, dq_ref, dw_ref, *, tile,
-                         valid):
-    i, j = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _():
-        dq_ref[...] = jnp.zeros_like(dq_ref)
-        dw_ref[...] = jnp.zeros_like(dw_ref)
+    i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j <= i)
     def _():
         k = k_ref[0]
-        z = _dot(k, q_ref[0, 0], _A_BT)
-        g = jnp.where(_allowed(j, i, tile, valid), g_ref[0], 0.0)
-        dw_ref[0, 0] += jnp.sum(g * jnp.maximum(z, 0.0), axis=0,
-                                keepdims=True)
-        gz = jnp.where(z > 0.0, g * w_ref[0, 0], 0.0)
-        dq_ref[0, 0] += _dot(gz, k, _AT_B)                  # (queries, d)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
+        def head(h, _):
+            z = _dot(k, q_ref[0, h], _A_BT)                 # (keys, queries)
+            out_ref[0] += w_ref[0, h] * jnp.maximum(z, 0.0)
 
-def _scores_bwd_k_kernel(k_ref, q_ref, w_ref, g_ref, dk_ref, *, tile, valid):
-    j, i, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+        jax.lax.fori_loop(0, q_ref.shape[1], head, None)
+        out_ref[0] = jnp.where(_allowed(j, i, tile, valid), out_ref[0],
+                               MASKED)
 
-    @pl.when((i == 0) & (h == 0))
+    @pl.when(j > i)
     def _():
-        dk_ref[...] = jnp.zeros_like(dk_ref)
+        out_ref[...] = jnp.full_like(out_ref, MASKED)
+
+
+def _scores_bwd_kernel(k_ref, kt_ref, qt_ref, w_ref, g_ref, dqt_ref, dw_ref,
+                       dkt_ref, *, tile, valid):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        dkt_ref[...] = jnp.zeros_like(dkt_ref)
+
+    @pl.when(j == 0)
+    def _():
+        dqt_ref[...] = jnp.zeros_like(dqt_ref)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
 
     @pl.when(j <= i)
     def _():
-        q = q_ref[0, 0]
-        z = _dot(k_ref[0], q, _A_BT)
+        k, kt = k_ref[0], kt_ref[0]
         g = jnp.where(_allowed(j, i, tile, valid), g_ref[0], 0.0)
-        gz = jnp.where(z > 0.0, g * w_ref[0, 0], 0.0)
-        dk_ref[0] += _dot(gz, q, _A_B)                      # (keys, d)
+
+        def head(h, dkt):
+            qt = qt_ref[0, h]                               # (d, queries)
+            z = _dot(k, qt, _A_B)                           # (keys, queries)
+            dw_ref[0, h] += jnp.sum(g * jnp.maximum(z, 0.0), axis=0,
+                                    keepdims=True)
+            gz = jnp.where(z > 0.0, g * w_ref[0, h], 0.0)
+            dqt_ref[0, h] += _dot(kt, gz, _A_B)             # (d, queries)
+            return dkt + _dot(qt, gz, _A_BT)                # (d, keys)
+
+        dkt = jax.lax.fori_loop(0, qt_ref.shape[1], head,
+                                jnp.zeros(kt.shape, jnp.float32))
+        dkt_ref[0, :, pl.ds(pl.multiple_of(j * tile, tile), tile)] += dkt
 
 
-def _scores_specs(tile, d, order):
-    """BlockSpecs of (kI, qI, w, a (T, T) array) for a grid whose axes
-    after the batch are ``order``, a permutation of "jih" (key tile,
-    query tile, head)."""
-    def at(f):
-        return lambda b, *axes: f(b, **dict(zip(order, axes)))
+def _scores_fwd_specs(tile, H, d):
+    """BlockSpecs of (kI, qI, w, the scores)."""
+    return [pl.BlockSpec((1, tile, d), _keys),
+            pl.BlockSpec((1, H, tile, d), _queries),
+            pl.BlockSpec((1, H, 1, tile), _queries_turned),
+            pl.BlockSpec((1, tile, tile), _square_out)]
 
-    return [pl.BlockSpec((1, tile, d), at(lambda b, j, i, h: (b, j, 0))),
-            pl.BlockSpec((1, 1, tile, d),
-                         at(lambda b, j, i, h: (b, h, i, 0))),
-            pl.BlockSpec((1, 1, 1, tile),
-                         at(lambda b, j, i, h: (b, h, 0, i))),
-            pl.BlockSpec((1, tile, tile), at(lambda b, j, i, h: (b, j, i)))]
+
+def _scores_bwd_specs(tile, H, d, Tp):
+    """BlockSpecs of (kI, kI and qI turned, w, the scores' cotangent;
+    dqI turned, dw, and dkI turned: whole, whatever the step)."""
+    turned = pl.BlockSpec((1, H, d, tile), _queries_turned)
+    w = pl.BlockSpec((1, H, 1, tile), _queries_turned)
+    return [pl.BlockSpec((1, tile, d), _keys),
+            pl.BlockSpec((1, d, tile),
+                         lambda b, i, j: (b, 0, jnp.minimum(j, i))),
+            turned, w, pl.BlockSpec((1, tile, tile), _square),
+            turned, w, pl.BlockSpec((1, d, Tp), lambda b, i, j: (b, 0, 0))]
 
 
 def _scores_operands(q_idx, k_idx, w):
@@ -165,14 +212,14 @@ def _scores_fwd(q_idx, k_idx, w):
     B, H, T, d = q_idx.shape
     Tp, tile = _geometry(T)
     n = Tp // tile
-    interpret = jax.default_backend() == "cpu"
     counters.bump(SCORES_FWD_NAME)
-    *ins, out = _scores_specs(tile, d, "jih")
+    specs = _scores_fwd_specs(tile, H, d)
     scores = pl.pallas_call(
         functools.partial(_scores_fwd_kernel, tile=tile, valid=T),
         out_shape=jax.ShapeDtypeStruct((B, Tp, Tp), jnp.float32),
-        grid=(B, n, n, H), in_specs=ins, out_specs=out,
-        compiler_params=_CARRY_1, interpret=interpret,
+        grid=(B, n, n), in_specs=specs[:3], out_specs=specs[3],
+        compiler_params=_params(specs, tile, 0),
+        interpret=jax.default_backend() == "cpu",
         name=SCORES_FWD_NAME)(*_scores_operands(q_idx, k_idx, w))
     return scores[:, :T, :T]
 
@@ -181,27 +228,21 @@ def _scores_bwd(q_idx, k_idx, w, g):
     B, H, T, d = q_idx.shape
     Tp, tile = _geometry(T)
     n = Tp // tile
-    interpret = jax.default_backend() == "cpu"
-    operands = _scores_operands(q_idx, k_idx, w) + (
-        _pad(_pad(g.astype(jnp.float32), 1, Tp), 2, Tp),)
-    counters.bump(SCORES_BWD_Q_NAME)
-    specs = _scores_specs(tile, d, "hij")
-    dq, dw = pl.pallas_call(
-        functools.partial(_scores_bwd_q_kernel, tile=tile, valid=T),
-        out_shape=[jax.ShapeDtypeStruct((B, H, Tp, d), jnp.float32),
-                   jax.ShapeDtypeStruct((B, H, 1, Tp), jnp.float32)],
-        grid=(B, H, n, n), in_specs=specs, out_specs=[specs[1], specs[2]],
-        compiler_params=_CARRY_1, interpret=interpret,
-        name=SCORES_BWD_Q_NAME)(*operands)
-    counters.bump(SCORES_BWD_K_NAME)
-    specs = _scores_specs(tile, d, "jih")
-    dk = pl.pallas_call(
-        functools.partial(_scores_bwd_k_kernel, tile=tile, valid=T),
-        out_shape=jax.ShapeDtypeStruct((B, Tp, d), jnp.float32),
-        grid=(B, n, n, H), in_specs=specs, out_specs=specs[0],
-        compiler_params=_CARRY_2, interpret=interpret,
-        name=SCORES_BWD_K_NAME)(*operands)
-    return (dq[:, :, :T].astype(q_idx.dtype), dk[:, :T].astype(k_idx.dtype),
+    k, q, w_rows = _scores_operands(q_idx, k_idx, w)
+    counters.bump(SCORES_BWD_NAME)
+    specs = _scores_bwd_specs(tile, H, d, Tp)
+    dqt, dw, dkt = pl.pallas_call(
+        functools.partial(_scores_bwd_kernel, tile=tile, valid=T),
+        out_shape=[jax.ShapeDtypeStruct((B, H, d, Tp), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, 1, Tp), jnp.float32),
+                   jax.ShapeDtypeStruct((B, d, Tp), jnp.float32)],
+        grid=(B, n, n), in_specs=specs[:5], out_specs=specs[5:],
+        compiler_params=_params(specs, tile, 2),
+        interpret=jax.default_backend() == "cpu", name=SCORES_BWD_NAME)(
+            k, jnp.swapaxes(k, 1, 2), jnp.swapaxes(q, 2, 3), w_rows,
+            _pad(_pad(g.astype(jnp.float32), 1, Tp), 2, Tp))
+    return (jnp.swapaxes(dqt, 2, 3)[:, :, :T].astype(q_idx.dtype),
+            jnp.swapaxes(dkt, 1, 2)[:, :T].astype(k_idx.dtype),
             dw[:, :, 0, :T].astype(w.dtype))
 
 
@@ -229,33 +270,40 @@ indexer_scores.defvjp(_indexer_scores_fwd, _indexer_scores_bwd)
 # ----------------------------------------------------------- probabilities
 
 def _probs_kernel(q_ref, k_ref, lse_ref, score_ref, least_ref, out_ref, *,
-                  scale, heads, tile, valid):
-    j, i, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-
-    @pl.when(h == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
+                  scale, tile, valid):
+    i, j = pl.program_id(1), pl.program_id(2)
+    heads = q_ref.shape[1]
+    group = heads // k_ref.shape[1]
 
     @pl.when(j <= i)
     def _():
-        st = scale * _dot(k_ref[0, 0], q_ref[0, 0], _A_BT)
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+        def head(h, _):
+            st = scale * _dot(k_ref[0, h // group], q_ref[0, h], _A_BT)
+            out_ref[0] += jnp.exp(st - lse_ref[0, h])
+
+        jax.lax.fori_loop(0, heads, head, None)
+        # a key that was not kept may have overflowed the sum: it is
+        # selected away, not multiplied
         kept = _allowed(j, i, tile, valid) & (score_ref[0] >= least_ref[0])
-        out_ref[0] += jnp.where(kept, jnp.exp(st - lse_ref[0, 0]), 0.0) \
-            * (1.0 / heads)
+        out_ref[0] = jnp.where(kept, out_ref[0] * (1.0 / heads), 0.0)
+
+    @pl.when(j > i)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
-def _probs_specs(tile, D, group):
-    """BlockSpecs of (q, k, lse, scores, least, the mean probabilities)
-    for the grid (batch, key tile, query tile, head)."""
-    square = pl.BlockSpec((1, tile, tile), lambda b, j, i, h: (b, j, i))
+def _probs_specs(tile, H, G, D):
+    """BlockSpecs of (q, k, lse, scores, least, the mean probabilities)."""
     return [
-        pl.BlockSpec((1, 1, tile, D), lambda b, j, i, h: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, tile, D),
-                     lambda b, j, i, h: (b, h // group, j, 0)),
-        pl.BlockSpec((1, 1, 1, tile), lambda b, j, i, h: (b, h, 0, i)),
-        square,
-        pl.BlockSpec((1, 1, tile), lambda b, j, i, h: (b, 0, i)),
-        square]
+        pl.BlockSpec((1, H, tile, D), _queries),
+        pl.BlockSpec((1, G, tile, D),
+                     lambda b, i, j: (b, 0, jnp.minimum(j, i), 0)),
+        pl.BlockSpec((1, H, 1, tile), _queries_turned),
+        pl.BlockSpec((1, tile, tile), _square),
+        pl.BlockSpec((1, 1, tile), lambda b, i, j: (b, 0, i)),
+        pl.BlockSpec((1, tile, tile), _square_out)]
 
 
 def indexer_probs(q, k, lse, scores, least, scale):
@@ -272,13 +320,13 @@ def indexer_probs(q, k, lse, scores, least, scale):
     n = Tp // tile
     f32 = jnp.float32
     counters.bump(PROBS_NAME)
-    *ins, out = _probs_specs(tile, D, H // k.shape[1])
+    specs = _probs_specs(tile, H, k.shape[1], D)
     probs = pl.pallas_call(
-        functools.partial(_probs_kernel, scale=float(scale), heads=H,
-                          tile=tile, valid=T),
+        functools.partial(_probs_kernel, scale=float(scale), tile=tile,
+                          valid=T),
         out_shape=jax.ShapeDtypeStruct((B, Tp, Tp), f32),
-        grid=(B, n, n, H), in_specs=ins, out_specs=out,
-        compiler_params=_CARRY_1,
+        grid=(B, n, n), in_specs=specs[:5], out_specs=specs[5],
+        compiler_params=_params(specs, tile, 0),
         interpret=jax.default_backend() == "cpu", name=PROBS_NAME,
     )(_pad(q.astype(f32), 2, Tp), _pad(k.astype(f32), 2, Tp),
       _pad(lse.astype(f32), 2, Tp)[:, :, None, :],
@@ -288,13 +336,13 @@ def indexer_probs(q, k, lse, scores, least, scale):
 
 
 def kernel_specs(B, Hi, T, d, H, G, D, interpret=False):
-    """KernelSpec descriptors (mxtpu.analysis.kernel_check) of the four
+    """KernelSpec descriptors (mxtpu.analysis.kernel_check) of the three
     pallas_calls one forward and backward of ``ops/dsa.py``'s indexed
     attention issues beside the flash kernels, in this order: the
-    scores forward, the mean probabilities, the scores' backward for the
-    queries and weights, and for the keys — built from the BlockSpecs
-    the calls themselves use.  ``Hi`` heads of ``d`` in the indexer;
-    ``H`` query and ``G`` key heads of ``D`` in the main attention."""
+    scores forward, the mean probabilities, the scores' backward — built
+    from the BlockSpecs the calls themselves use.  ``Hi`` heads of ``d``
+    in the indexer; ``H`` query and ``G`` key heads of ``D`` in the main
+    attention."""
     from ...analysis.kernel_check import BlockOperand, KernelSpec
 
     Tp, tile = _geometry(T)
@@ -302,39 +350,28 @@ def kernel_specs(B, Hi, T, d, H, G, D, interpret=False):
     k_idx, q_idx, w, square = ((B, Tp, d), (B, Hi, Tp, d), (B, Hi, 1, Tp),
                                (B, Tp, Tp))
     arrays = dict(k_idx=k_idx, q_idx=q_idx, w=w, scores=square, g=square,
-                  pbar=square, dq_idx=q_idx, dw=w, dk_idx=k_idx,
+                  pbar=square, k_idx_t=(B, d, Tp), q_idx_t=(B, Hi, d, Tp),
+                  dq_idx_t=(B, Hi, d, Tp), dw=w, dk_idx_t=(B, d, Tp),
                   q=(B, H, Tp, D), k=(B, G, Tp, D), lse=(B, H, 1, Tp),
                   least=(B, 1, Tp))
 
-    def operands(specs, names, kinds):
-        return [BlockOperand(name, kind, spec.block_shape, arrays[name],
-                             "float32", spec.index_map, strict_dims=())
-                for spec, name, kind in zip(specs, names, kinds)]
+    def spec(name, specs, operands, outs):
+        kinds = ("in",) * (len(specs) - outs) + ("out",) * outs
+        return KernelSpec(
+            name, grid=(B, n, n), interpret=interpret,
+            operands=[BlockOperand(operand, kind, s.block_shape,
+                                   arrays[operand], "float32", s.index_map,
+                                   strict_dims=())
+                      for s, operand, kind in zip(specs, operands, kinds)],
+            vmem_limit=_vmem(specs, tile))
 
     tag = "[float32,T=%d,Hi=%d,d=%d]" % (T, Hi, d)
-    jih, hij = _scores_specs(tile, d, "jih"), _scores_specs(tile, d, "hij")
     return [
-        KernelSpec(SCORES_FWD_NAME + tag, grid=(B, n, n, Hi),
-                   operands=operands(jih, ("k_idx", "q_idx", "w", "scores"),
-                                     ("in", "in", "in", "out")),
-                   interpret=interpret),
-        KernelSpec(
-            PROBS_NAME + "[float32,T=%d,H=%d,G=%d,D=%d]" % (T, H, G, D),
-            grid=(B, n, n, H),
-            operands=operands(
-                _probs_specs(tile, D, H // G),
-                ("q", "k", "lse", "scores", "least", "pbar"),
-                ("in",) * 5 + ("out",)),
-            interpret=interpret),
-        KernelSpec(SCORES_BWD_Q_NAME + tag, grid=(B, Hi, n, n),
-                   operands=operands(
-                       hij + [hij[1], hij[2]],
-                       ("k_idx", "q_idx", "w", "g", "dq_idx", "dw"),
-                       ("in",) * 4 + ("out", "out")),
-                   interpret=interpret),
-        KernelSpec(SCORES_BWD_K_NAME + tag, grid=(B, n, n, Hi),
-                   operands=operands(
-                       jih + [jih[0]],
-                       ("k_idx", "q_idx", "w", "g", "dk_idx"),
-                       ("in",) * 4 + ("out",)),
-                   interpret=interpret)]
+        spec(SCORES_FWD_NAME + tag, _scores_fwd_specs(tile, Hi, d),
+             ("k_idx", "q_idx", "w", "scores"), 1),
+        spec(PROBS_NAME + "[float32,T=%d,H=%d,G=%d,D=%d]" % (T, H, G, D),
+             _probs_specs(tile, H, G, D),
+             ("q", "k", "lse", "scores", "least", "pbar"), 1),
+        spec(SCORES_BWD_NAME + tag, _scores_bwd_specs(tile, Hi, d, Tp),
+             ("k_idx", "k_idx_t", "q_idx_t", "w", "g", "dq_idx_t", "dw",
+              "dk_idx_t"), 3)]

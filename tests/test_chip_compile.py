@@ -214,8 +214,9 @@ def test_indexed_attention_at_the_keye_cells_geometry(on_chip):
     16 heads of 64 selects (the 2,048 best of each row), forward and
     backward, float32.  The flash kernels take a Q tile's column
     (8,192 x 512) or a K/V block's row (512 x 8,192) of the selection's
-    scores beside the head; the indexer's four kernels walk tiles of 512
-    with an output tile carried over the grid's innermost axes."""
+    scores beside the head; the indexer's three kernels walk tiles of
+    512, a tile's heads a grid step, the backward's key gradient
+    (64 x 8,192) resident for the call."""
     dsa = importlib.import_module("mxtpu.ops.dsa")
     T = 8192
     shapes = [_shape(s, F32, on_chip) for s in (
@@ -230,8 +231,8 @@ def test_indexed_attention_at_the_keye_cells_geometry(on_chip):
         *shapes).compile()
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_bwd",
-                 "indexer_scores_fwd", "indexer_scores_bwd_q",
-                 "indexer_scores_bwd_k", "indexer_probs"):
+                 "indexer_scores_fwd", "indexer_scores_bwd_q_k",
+                 "indexer_probs"):
         assert name in text, name
     # the (T, T) arrays are whole — scores, mean probabilities, what the
     # loss forms of them — and nothing with a heads axis is
@@ -278,8 +279,8 @@ def test_the_whole_keye_step_fits_the_chip(on_chip, record_property):
     compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(*args).compile()
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_bwd",
-                 "indexer_scores_fwd", "indexer_scores_bwd_q",
-                 "indexer_scores_bwd_k", "indexer_probs"):
+                 "indexer_scores_fwd", "indexer_scores_bwd_q_k",
+                 "indexer_probs"):
         assert text.count(name) >= 6, name
     # a unit keeps its attention's output, logsumexp and thresholds, and
     # the indexer's queries, key and weights, so that the scores it forms

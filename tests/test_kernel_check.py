@@ -644,11 +644,12 @@ def test_prefill_specs_in_the_merge_gate():
 
 
 def test_indexed_attention_specs_describe_the_real_calls(monkeypatch):
-    """The six pallas_calls of one forward and backward of
+    """The five pallas_calls of one forward and backward of
     ``ops/dsa.py``'s indexed attention — the flash kernels with kept keys
-    and the indexer's four — are the ones ``kernel_specs`` describe:
-    names, grids, block shapes, the VMEM asked for.  Traced only: the
-    same calls run against their equations in tests/test_keye_vl.py."""
+    and the indexer's three — are the ones ``kernel_specs`` describe:
+    names, grids, block shapes (the key gradient's resident block among
+    the backward's), the VMEM asked for.  Traced only: the same calls
+    run against their equations in tests/test_keye_vl.py."""
     import importlib
     import jax
 
@@ -675,6 +676,13 @@ def test_indexed_attention_specs_describe_the_real_calls(monkeypatch):
              fa.kernel_specs(B, H, T, D, interpret=True, kept=True)
              + ix.kernel_specs(B, Hi, T, d, H, G, D, interpret=True)}
     assert sorted(c["name"] for c in calls) == sorted(specs)
+    assert len(calls) == 5
+    Tp = 640                            # five tiles of 128 a side
+    bwd = specs[ix.SCORES_BWD_NAME]
+    assert bwd.grid == (B, 5, 5)
+    assert [op.block_shape for op in bwd.operands if op.kind == "out"] \
+        == [(1, Hi, d, 128), (1, Hi, 1, 128), (1, d, Tp)]
+    assert specs[ix.PROBS_NAME].operands[0].block_shape == (1, H, 128, D)
     for call in calls:
         spec = specs[call["name"]]
         assert tuple(call["grid"]) == spec.grid, spec.name
@@ -694,5 +702,6 @@ def test_indexed_attention_specs_in_the_merge_gate():
     for name in ("flash_attention.fwd[float32,T=8192,D=128,kept]",
                  "flash_attention.bwd[float32,T=8192,D=128,kept]",
                  "indexer_scores_fwd[", "indexer_probs[",
-                 "indexer_scores_bwd_q[", "indexer_scores_bwd_k["):
+                 "indexer_scores_bwd_q_k["):
         assert name in names, name
+    assert "indexer_scores_bwd_k[" not in names

@@ -267,11 +267,21 @@ def test_flash_attention_with_kept_keys_matches_the_dense_path(D):
     assert np.abs(np.asarray(got[0] - plain)[:, :, 24:]).max() > 1e-2
 
 
-def test_the_indexers_kernels_match_their_equations():
-    """Scores forward and backward and the mean probabilities, at a
-    length that pads (200 of 256, tiles of 256), against plain XLA."""
-    T, H, d = 200, 3, 8
-    q, k, _, q_idx, k_idx, w_idx = _attention_inputs(T, 16, idx_heads=H, d=d)
+@pytest.mark.parametrize("T, tiles, heads, groups, top_k", [
+    (200, 1, 4, 2, 24), (600, 5, 8, 2, 64)], ids=["one-tile", "five-tiles"])
+def test_the_indexers_kernels_match_their_equations(T, tiles, heads, groups,
+                                                    top_k):
+    """Scores forward and backward and the mean probabilities against
+    plain XLA, at lengths that pad: 200 of 256 is one tile, and 600 of
+    640 five tiles of 128 a side, so that the key gradient's sum over
+    query tiles and heads in its resident block, the query gradient's
+    carry over key tiles, the steps above the diagonal and key heads
+    that four query heads share are all in it."""
+    H, d = 3, 8
+    q, k, _, q_idx, k_idx, w_idx = _attention_inputs(
+        T, 16, heads=heads, groups=groups, idx_heads=H, d=d)
+    padded, tile = indexer._geometry(T)
+    assert padded // tile == tiles
     at = jnp.arange(T)
     causal = at[:, None] <= at[None, :]
 
@@ -287,17 +297,39 @@ def test_the_indexers_kernels_match_their_equations():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)
     for a, b, name in zip(back(g), ref_back(g), ("q_idx", "k_idx", "w")):
+        assert a.shape == b.shape, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
                                    atol=1e-4, err_msg=name)
-    least = dsa._threshold(got, 24)
-    scale = 0.25
-    o, lse = fa.flash_attention(q, jnp.repeat(k, 2, 1), jnp.repeat(k, 2, 1),
-                                causal=True, scale=scale, keep=(got, least))
+    least = dsa._threshold(got, top_k)
+    scale, group = 0.25, heads // groups
+    o, lse = fa.flash_attention(q, jnp.repeat(k, group, 1),
+                                jnp.repeat(k, group, 1), causal=True,
+                                scale=scale, keep=(got, least))
     pbar = indexer.indexer_probs(q, k, lse, got, least, scale)
     want = dsa._dense_probs(q, k, lse, got, least, scale)
     np.testing.assert_allclose(np.asarray(pbar), np.asarray(want), rtol=1e-5,
                                atol=1e-6)
     np.testing.assert_allclose(np.asarray(pbar.sum(1)), 1.0, rtol=1e-5)
+
+
+def test_the_scores_backward_is_one_traced_kernel():
+    """A traced forward and backward of the scores bumps the forward's
+    counter and ONE backward counter, once each; the keys' pass has no
+    kernel, no name and no counter of its own any more."""
+    *_, q_idx, k_idx, w_idx = _attention_inputs(160, 16)
+    before = counters.counts()
+    jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(jnp.maximum(indexer.indexer_scores(*a), 0.0)),
+        argnums=(0, 1, 2)))(q_idx, k_idx, w_idx)
+    after = counters.counts()
+    moved = {name: after[name] - before.get(name, 0) for name in after
+             if after[name] != before.get(name, 0)}
+    assert moved == {indexer.SCORES_FWD_NAME: 1, indexer.SCORES_BWD_NAME: 1}
+    assert indexer.SCORES_BWD_NAME == "indexer_scores_bwd_q_k"
+    snap = get_registry().snapshot()
+    assert snap["kernel_invocations." + indexer.SCORES_BWD_NAME] >= 1
+    assert "kernel_invocations.indexer_scores_bwd_k" not in snap
+    assert "kernel_invocations.indexer_scores_bwd_q" not in snap
 
 
 def test_mrope_with_three_streams_and_with_one():
