@@ -13,6 +13,13 @@ Import surface mirrors ``import mxnet as mx``:
 See SURVEY.md for the architecture map against the reference.
 """
 
+from time import perf_counter_ns as _now
+_t0 = _now()    # before the first import: where ``process.start`` ends
+import sys as _sys
+_jax_imported = "jax" in _sys.modules
+
+from .observability import trace as _trace
+_import = _trace.package_import(_t0, _jax_imported)     # mxtpu.import, open
 from . import base
 from .context import Context, cpu, cpu_pinned, gpu, tpu, num_gpus, num_tpus, current_context
 from . import engine
@@ -21,10 +28,8 @@ from . import ndarray
 from . import ndarray as nd
 from . import autograd
 from .ndarray import NDArray
-from .observability.trace import attach_jax
 
-attach_jax()    # jax is imported by now: annotations and xla.compile spans
-del attach_jax
+_trace.attach_jax()     # jax is imported by now: annotations, xla.compile
 
 __version__ = "0.1.0"
 
@@ -75,10 +80,15 @@ def __getattr__(name):
     target = _LAZY.get(name)
     if target is None:
         raise AttributeError(f"module 'mxtpu' has no attribute {name!r}")
-    mod = importlib.import_module(target, __name__)
+    with _trace.importing(__name__ + target):
+        mod = importlib.import_module(target, __name__)
     globals()[name] = mod
     return mod
 
 
 def __dir__():
     return sorted(list(globals()) + list(_LAZY))
+
+
+_import.__exit__(None, None, None)      # the package's mxtpu.import ends
+del _now, _t0, _sys, _jax_imported, _import

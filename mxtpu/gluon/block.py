@@ -34,7 +34,7 @@ from ..context import Context, current_context
 from ..ndarray import NDArray
 from ..ops import remat as _kept
 from .parameter import (Parameter, ParameterDict, DeferredInitializationError,
-                        Constant)
+                        Constant, initialize_span)
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "remat_scope"]
 
@@ -349,6 +349,7 @@ class Block:
         fn(self)
         return self
 
+    @initialize_span
     def initialize(self, init=None, ctx=None, verbose=False,
                    force_reinit=False):
         from .. import initializer as _init

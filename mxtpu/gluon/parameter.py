@@ -12,6 +12,8 @@ leaves.
 
 from __future__ import annotations
 
+import functools
+import threading
 from collections import OrderedDict
 from typing import List, Optional
 
@@ -21,6 +23,7 @@ import numpy as onp
 from ..base import MXTPUError
 from ..context import Context, current_context, cpu
 from ..ndarray import NDArray
+from ..observability.trace import get_tracer
 from .. import autograd, initializer
 
 __all__ = ["DeferredInitializationError", "Parameter", "Constant",
@@ -35,6 +38,33 @@ class DeferredInitializationError(MXTPUError):
 
 def _shape_known(shape) -> bool:
     return shape is not None and all(s > 0 for s in shape)
+
+
+#: ``.count``: [parameters, bytes] materialised so far by the
+#: ``initialize`` call open on this thread
+_initializing = threading.local()
+
+
+def initialize_span(method):
+    """For ``Block``, ``ParameterDict`` and ``Parameter.initialize``: the
+    outermost such call on a thread is one ``block.initialize`` boundary
+    span whose end carries the ``params`` it materialised (a parameter
+    whose shape is deferred is not among them) and their ``bytes``; the
+    calls inside it open nothing."""
+    @functools.wraps(method)
+    def initialize(self, *args, **kwargs):
+        if getattr(_initializing, "count", None) is not None:
+            return method(self, *args, **kwargs)
+        count = _initializing.count = [0, 0]
+        try:
+            with get_tracer().span("block.initialize") as span:
+                try:
+                    return method(self, *args, **kwargs)
+                finally:
+                    span.set(params=count[0], bytes=count[1])
+        finally:
+            _initializing.count = None
+    return initialize
 
 
 class Parameter:
@@ -125,6 +155,7 @@ class Parameter:
         self._shape = tuple(new_shape)
 
     # -- init -------------------------------------------------------------
+    @initialize_span
     def initialize(self, init=None, ctx=None, default_init=None,
                    force_reinit=False):
         """Materialize (or defer) this parameter on the given context(s)."""
@@ -168,6 +199,10 @@ class Parameter:
                 # before the deferred init resolved
                 data = NDArray(data.data.astype(jnp.dtype(self.dtype)))
             self._init_impl(data, ctx)
+        count = getattr(_initializing, "count", None)
+        if count is not None:
+            count[0] += 1
+            count[1] += sum(d.data.nbytes for d in self._data)
 
     def _init_impl(self, data, ctx_list):
         self._ctx_list = list(ctx_list)
@@ -501,6 +536,7 @@ class ParameterDict:
             else:
                 self._params[k] = v
 
+    @initialize_span
     def initialize(self, init=None, ctx=None, verbose=False,
                    force_reinit=False):
         if init is None:

@@ -23,7 +23,11 @@ ALWAYS, tracer enabled or not, in a bounded ring of finished spans
 (:meth:`Tracer.boundary_spans`), and open a
 ``jax.profiler.TraceAnnotation("mxtpu.<type>")`` whenever a profiler
 session runs, however it was started.  XLA compilations land in the
-same ring as ``xla.compile`` spans (:func:`attach_jax`).  ``perf_counter_ns`` is the clock a
+same ring as ``xla.compile`` spans (:func:`attach_jax`), and so does what
+the process did before its first step: ``process.start`` and
+``mxtpu.import`` (:func:`package_import`, :func:`importing`; they
+describe the process, so :meth:`Tracer.reset` keeps them) and
+``block.initialize``.  ``perf_counter_ns`` is the clock a
 benchmark's own host spans use, so a reader lays the ring on a device
 trace by shifting with one span both sides hold.
 
@@ -56,6 +60,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 from collections import deque
 from time import perf_counter_ns
@@ -63,7 +68,8 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 __all__ = ["TraceEvent", "Span", "Tracer", "get_tracer", "tracing",
            "gateway_rid", "EVENT_TYPES", "BOUNDARY_TYPES",
-           "export_chrome_trace", "attach_jax"]
+           "export_chrome_trace", "attach_jax", "package_import",
+           "importing"]
 
 
 #: alias entries (engine-rid -> gateway-rid) kept for at most this many
@@ -77,6 +83,10 @@ MAX_ALIASES = 8192
 #: finished boundary spans kept (oldest evicted past it): at a hundred
 #: engine iterations a second and seven spans an iteration, a minute
 MAX_BOUNDARY_SPANS = 65536
+
+#: events a tracer keeps in memory (further ones are counted in
+#: ``dropped_events``); no knob, as the ring has none
+MAX_EVENTS = 200000
 
 #: the registered span/event taxonomy: type -> one-line description
 #: (docs/observability.md mirrors this table).  ``fault.<site>`` types
@@ -174,17 +184,34 @@ EVENT_TYPES: Dict[str, str] = {
     "guardian.checkpoint": "verified checkpoint written",
     "guardian.window": "one fused N-step window dispatched (the "
                        "once-per-N host sync)",
+    # -- start-up (mxtpu/__init__.py, mxtpu.gluon) ----------------------
+    "process.start": "from the kernel's record of the process's start "
+                     "to the first line of mxtpu/__init__.py: the "
+                     "interpreter and whatever the caller did before it "
+                     "imported the package (ring only, once, kept by a "
+                     "reset: jax_imported, backend_up)",
+    "mxtpu.import": "the import of the package, or of a lazy subpackage "
+                    "through mxtpu.__getattr__ (ring only, kept by a "
+                    "reset: module; an import inside an import is its "
+                    "child)",
+    "block.initialize": "the outermost Block / ParameterDict / "
+                        "Parameter.initialize call on a thread "
+                        "(boundary span; params and bytes materialised "
+                        "on the end)",
     # -- SPMDTrainer (mxtpu.parallel.trainer) ---------------------------
     "trainer.stage": "the eager shape-resolving forward and the staging "
                      "of parameters and optimizer state onto the mesh "
-                     "(boundary span, once per trainer)",
+                     "(boundary span, once per trainer; the device's "
+                     "bytes_in_use, peak_bytes_in_use on the end)",
     "trainer.step": "one SPMDTrainer.step / step_window call as the "
                     "host sees it: dispatch, not device time (boundary "
-                    "span; step, first, tokens on the end)",
+                    "span; step, first, tokens on the end, and where "
+                    "first the device's bytes_in_use, peak_bytes_in_use)",
     # -- XLA (jax.monitoring, attach_jax) -------------------------------
     "xla.compile": "one trace / lower / compile / cache_fetch of a "
                    "program, eager ones included (ring only: seconds, "
-                   "kind; parent = the span open on that thread)",
+                   "kind, name, on a compile fetched; parent = the span "
+                   "open on that thread)",
     # -- profiler parity API (mxtpu.profiler) ---------------------------
     "profiler.counter": "profiler.Counter value change",
     "profiler.marker": "profiler.Marker instant",
@@ -230,11 +257,15 @@ EVENT_TYPES: Dict[str, str] = {
 #: docstring).  Few per second by construction; anything per token or
 #: per request stays an instant behind ``Tracer.active``.
 BOUNDARY_TYPES = frozenset((
+    "block.initialize",
     "trainer.stage", "trainer.step",
     "engine.iteration", "engine.schedule", "engine.prefill",
     "engine.decode_step", "engine.host_read",
     "gateway.pump",
 ))
+
+#: ring spans that describe the process, not a run: a reset keeps them
+PROCESS_TYPES = frozenset(("process.start", "mxtpu.import"))
 
 _COMPILE_KINDS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
@@ -314,8 +345,8 @@ class _Span:
     events too while the tracer is active; any other span exists only
     while the tracer is active."""
 
-    __slots__ = ("_tr", "_etype", "_rid", "_fields", "_end", "_ann",
-                 "_tick", "_parent", "_t0")
+    __slots__ = ("_tr", "_etype", "_rid", "_fields", "_end", "_noise",
+                 "_ann", "_tick", "_parent", "_t0")
 
     def __init__(self, tracer, etype, rid, fields):
         self._tr = tracer
@@ -323,6 +354,7 @@ class _Span:
         self._rid = rid
         self._fields = fields
         self._end = None
+        self._noise = None
         self._ann = None
         self._tick = None       # set when the span is recorded at all
 
@@ -333,6 +365,13 @@ class _Span:
             self._end = fields
         else:
             self._end.update(fields)
+        return self
+
+    def set_noise(self, **fields):
+        """Fields that two runs of one seed do not share (what the
+        device's allocator counts): in the ring like any other, on the
+        end event under ``noise``, out of the deterministic bytes."""
+        self._noise = dict(self._noise or {}, **fields)
         return self
 
     def __enter__(self):
@@ -370,11 +409,14 @@ class _Span:
             stack.pop()
         etype, rid, fields = self._etype, self._rid, self._fields
         if tr._enabled or tr._sinks:
-            tr._record(etype, rid, "E", self._end or {}, None, end,
+            tr._record(etype, rid, "E", self._end or {}, self._noise, end,
                        self._parent)
         if etype in BOUNDARY_TYPES:
-            if self._end:
-                fields = dict(fields, **self._end) if fields else self._end
+            ended = self._end
+            if self._noise:
+                ended = dict(ended or {}, **self._noise)
+            if ended:
+                fields = dict(fields, **ended) if fields else ended
             if rid is not None:
                 rid = tr._alias.get(rid, rid)
             tr._ring.append((etype, tick, self._parent, self._t0, end, rid,
@@ -407,13 +449,8 @@ class Tracer:
         self._lock = threading.RLock()
         self._enabled = (_env_truthy("MXTPU_TRACE") if enabled is None
                          else bool(enabled))
-        if max_events is None:
-            try:
-                max_events = int(os.environ.get("MXTPU_TRACE_EVENTS",
-                                                200000))
-            except ValueError:
-                max_events = 200000
-        self._max_events = int(max_events)
+        self._max_events = int(MAX_EVENTS if max_events is None
+                               else max_events)
         self._events: List[TraceEvent] = []
         self._ring: deque = deque(maxlen=MAX_BOUNDARY_SPANS)
         self._local = threading.local()
@@ -450,10 +487,14 @@ class Tracer:
     def reset(self) -> None:
         """Clear events, the boundary ring, the tick clock, aliases, and
         the profiler channel — the start-of-run point the determinism
-        contract is relative to."""
+        contract is relative to.  The ring keeps its
+        :data:`PROCESS_TYPES` spans: they tell of the process, which a
+        new run does not start again."""
         with self._lock:
             self._events = []
+            kept = [s for s in self._ring if s[0] in PROCESS_TYPES]
             self._ring.clear()
+            self._ring.extend(kept)
             self._profiler_events = []
             self._alias = {}
             self._tick = 0
@@ -538,14 +579,19 @@ class Tracer:
             stack = self._local.stack = []
             return stack
 
-    def compile_seen(self, event: str, seconds: float, **_) -> None:
+    def compile_seen(self, event: str, seconds: float, fun_name=None,
+                     **_) -> None:
         """The ``jax.monitoring`` duration listener: a trace, lowering,
         back-end compilation or cache fetch that just ended on this
         thread becomes an ``xla.compile`` span of the ring, its parent
-        the span open here.  A cache fetch lies inside the back-end
-        compilation that asked for it (a ``compile`` span that holds a
-        ``cache_fetch`` compiled nothing), so a reader takes the union
-        of these spans, not the sum of their seconds."""
+        the span open here and its ``name`` jax's name of the program (a
+        trace's is the function's qualified name, a lowering's and a
+        compilation's the module's: ``jit(step)``).  A cache fetch lies
+        inside the back-end compilation that asked for it and jax hands
+        it no name: the ``compile`` span that ends next on its thread
+        names it, and says of itself ``fetched`` — true where a fetch
+        ended inside it, so it compiled nothing.  A reader takes the
+        union of these spans, not the sum of their seconds."""
         kind = _COMPILE_KINDS.get(event)
         if kind is None:
             return
@@ -563,9 +609,19 @@ class Tracer:
                     break
                 if ring.pop() is not last:      # another thread's append
                     break                       # slipped in: leave it be
+        fields = {"kind": kind, "seconds": seconds, "name": fun_name}
+        local = self._local
+        if kind == "cache_fetch":
+            local.fetch = (end, fields)
+        elif kind == "compile":
+            fetch = getattr(local, "fetch", None)
+            local.fetch = None      # a fetch is one compilation's
+            fields["fetched"] = fetch is not None and fetch[0] >= start
+            if fields["fetched"]:
+                fetch[1]["name"] = fun_name
         stack = self._stack()
         ring.append(("xla.compile", None, stack[-1] if stack else None,
-                     start, end, None, {"kind": kind, "seconds": seconds}))
+                     start, end, None, fields))
 
     def boundary_spans(self, types=None) -> List[Span]:
         """The finished boundary spans still in the ring, oldest first
@@ -695,6 +751,78 @@ _TRACER = Tracer()
 def get_tracer() -> Tracer:
     """The process-wide tracer instance."""
     return _TRACER
+
+
+# -- the process's own spans: before the first step -----------------------
+
+_IMPORT_IDS = itertools.count(-1, -1).__next__  # never reset: spans stay
+
+
+def _since_process_start_ns() -> Optional[int]:
+    """Nanoseconds since the kernel's record of this process's start
+    (``/proc/self/stat`` field 22 against ``/proc/uptime``: the
+    arithmetic of chipbench's ``seconds_since_process_start``, so both
+    ends agree); None where ``/proc`` is not there."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return int((uptime - ticks / os.sysconf("SC_CLK_TCK")) * 1e9)
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+class importing:
+    """``with importing("mxtpu.gluon"):`` — one ``mxtpu.import`` span of
+    the process-wide ring, from ``start_ns`` (now, if not given) to the
+    block's end.  Its id is below zero and not a tick; spans and
+    compilations inside it name it as ``parent``."""
+
+    __slots__ = ("_module", "_t0", "_id", "_parent")
+
+    def __init__(self, module: str, start_ns: Optional[int] = None):
+        self._module = module
+        self._t0 = start_ns
+
+    def __enter__(self):
+        stack = _TRACER._stack()
+        self._parent = stack[-1] if stack else None
+        if self._t0 is None:
+            self._t0 = perf_counter_ns()
+        self._id = _IMPORT_IDS()
+        stack.append(self._id)
+        return self
+
+    def __exit__(self, *exc):
+        end = perf_counter_ns()
+        stack = _TRACER._stack()
+        if stack and stack[-1] == self._id:
+            stack.pop()
+        _TRACER._ring.append(("mxtpu.import", self._id, self._parent,
+                              self._t0, end, None,
+                              {"module": self._module}))
+        return False
+
+
+def package_import(t0_ns: int, jax_imported: bool) -> importing:
+    """What ``mxtpu/__init__.py`` calls once it has this module:
+    ``t0_ns`` is ``perf_counter_ns()`` at its first line, ``jax_imported``
+    whether jax was in ``sys.modules`` then.  Records ``process.start``
+    (the kernel's record of the process's start laid on
+    ``perf_counter_ns``, to ``t0_ns``; ``backend_up``: a jax backend
+    existed already, i.e. the caller opened the device's client before it
+    imported the program) and returns the package's own ``mxtpu.import``
+    span, open since ``t0_ns``, for the last line to close."""
+    since = _since_process_start_ns()
+    if since is not None:
+        bridge = sys.modules.get("jax._src.xla_bridge")
+        up = getattr(bridge, "backends_are_initialized", None)
+        _TRACER._ring.append((
+            "process.start", None, None, perf_counter_ns() - since, t0_ns,
+            None, {"jax_imported": jax_imported,
+                   "backend_up": bool(up is not None and up())}))
+    return importing("mxtpu", t0_ns).__enter__()
 
 
 def attach_jax() -> None:

@@ -465,13 +465,24 @@ class SPMDTrainer:
         of a cold start at 8,192 tokens through five Kimi-Linear layers,
         PERF.md PR 29)."""
         if not self._params_sharded:
-            with _tracer().span("trainer.stage"):
+            with _tracer().span("trainer.stage") as span:
                 if any(p._deferred_init for p in
                        self._block.collect_params().values()):
                     with autograd.pause(train_mode=False):
                         self._block(data if isinstance(data, NDArray)
                                     else nd.array(data))
                 self._stage_params()
+                span.set_noise(**self._device_memory())
+
+    def _device_memory(self):
+        """``bytes_in_use`` and ``peak_bytes_in_use`` of the mesh's first
+        device in this process as its allocator counts them now, for the
+        end of a set-up span (``trainer.stage``, a ``trainer.step`` that
+        is ``first``); nothing where the platform gives no statistics
+        (the CPU)."""
+        stats = self._mesh.jax_mesh.local_devices[0].memory_stats() or {}
+        return {k: stats[k] for k in ("bytes_in_use", "peak_bytes_in_use")
+                if k in stats}
 
     def step(self, data, label):
         """One optimization step on a global batch. Returns the (device)
@@ -481,7 +492,9 @@ class SPMDTrainer:
         The call is one ``trainer.step`` boundary span (host dispatch,
         not device time): ``step`` is the update count it made, ``first``
         whether the batch signature was new (the call traced, lowered
-        and compiled or fetched), ``tokens`` the batch's elements."""
+        and compiled or fetched), ``tokens`` the batch's elements.  A
+        ``first`` call also reads the device's memory at its end
+        (:meth:`_device_memory`); no other call does."""
         with _tracer().span("trainer.step") as span:
             return self._step(data, label, span)
 
@@ -514,8 +527,9 @@ class SPMDTrainer:
                 shapes=(sig[0], sig[2]), dtypes=(sig[1], sig[3]),
                 weak=(), static=(self._guard, self._dyn_scale)),
                 hit=jitted is not None)
-        span.set(first=jitted is None, tokens=int(batch.size))
-        if jitted is None:
+        first = jitted is None
+        span.set(first=first, tokens=int(batch.size))
+        if first:
             jitted = self._build_step(*sig)
             self._jit_cache[sig] = jitted
 
@@ -561,6 +575,8 @@ class SPMDTrainer:
             p.data()._rebind(leaf)
         self._opt_states = list(new_states)
         span.set(step=self._num_update)
+        if first:
+            span.set_noise(**self._device_memory())
         return NDArray(loss)
 
     def step_program(self, data, label):
@@ -673,8 +689,9 @@ class SPMDTrainer:
                 shapes=(sig[2], sig[4]), dtypes=(sig[3], sig[5]),
                 weak=(), static=(n, self._guard, self._dyn_scale)),
                 hit=jitted is not None)
-        span.set(first=jitted is None, tokens=int(batch.size), steps=n)
-        if jitted is None:
+        first = jitted is None
+        span.set(first=first, tokens=int(batch.size), steps=n)
+        if first:
             jitted = self._build_multi_step(n, *sig[2:])
             self._jit_cache[sig] = jitted
 
@@ -726,6 +743,8 @@ class SPMDTrainer:
 
         self._num_update += num_good
         span.set(step=self._num_update)
+        if first:
+            span.set_noise(**self._device_memory())
         iuc = self._optimizer._index_update_count
         for i in range(len(self._diff_params)):
             iuc[i] = self._num_update

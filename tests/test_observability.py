@@ -1002,3 +1002,251 @@ def test_paged_kernels_pass_their_names(module, name):
     src = inspect.getsource(importlib.import_module(
         "mxtpu.ops.pallas." + module))
     assert 'name="%s"' % name in src
+
+
+# ------------------------------------------- set-up, told from inside (PR 37)
+
+_FRESH = """
+import json, sys
+{before}
+import mxtpu as mx
+from mxtpu.observability import get_tracer
+tr = get_tracer()
+mx.gluon
+first = [[s.etype, s.tick, s.parent, s.start_ns, s.end_ns, s.fields]
+         for s in tr.boundary_spans()]
+tr.reset()
+kept = [[s.etype, s.fields] for s in tr.boundary_spans()]
+print(json.dumps({{"first": first, "kept": kept, "ticks": tr.ticks}}))
+"""
+
+
+@pytest.mark.parametrize("before, jax_imported, backend_up", [
+    ("", False, False),
+    ("import jax; jax.devices()", True, True),
+])
+def test_process_start_and_imports_in_a_fresh_interpreter(
+        before, jax_imported, backend_up):
+    """One ``process.start`` that ends where the package's
+    ``mxtpu.import`` begins (the first line of ``mxtpu/__init__.py``),
+    saying what the caller had done by then; a lazy subpackage's import
+    is a span with its ``module``, and what it imports lazily itself is
+    its child; none takes a tick, and a reset keeps them all."""
+    import subprocess
+    import sys as _sys
+
+    r = subprocess.run(
+        [_sys.executable, "-c", _FRESH.format(before=before)],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=240)
+    assert r.returncode == 0, r.stderr[-2000:]
+    said = json.loads(r.stdout.splitlines()[-1])
+    spans = [s for s in said["first"] if s[0] != "xla.compile"]
+    assert [s[0] for s in spans] == ["process.start"] + ["mxtpu.import"] * (
+        len(spans) - 1)
+    start, package = spans[0], spans[1]
+    assert start[5] == {"jax_imported": jax_imported,
+                        "backend_up": backend_up}
+    assert package[5] == {"module": "mxtpu"}
+    assert start[1] is None and start[2] is None and package[2] is None
+    assert start[4] == package[3]               # one ends, the other begins
+    assert start[3] < start[4] and package[3] < package[4]
+    by_module = {s[5]["module"]: s for s in spans[1:]}
+    gluon = by_module["mxtpu.gluon"]
+    assert gluon[3] >= package[4] and gluon[4] > gluon[3]
+    ids = [s[1] for s in spans[1:]]
+    assert all(i < 0 for i in ids) and len(set(ids)) == len(ids)
+    inner = [s for s in spans[1:] if s[2] == gluon[1]]
+    assert inner and all(gluon[3] <= s[3] and s[4] <= gluon[4]
+                         for s in inner)
+    assert said["ticks"] == 0
+    assert said["kept"] == [[s[0], s[5]] for s in spans]
+
+
+def test_reset_keeps_the_spans_of_the_process_and_no_others():
+    tr = get_tracer()
+    with tr.span("trainer.step"):
+        pass
+    tr.reset()
+    kinds = [s.etype for s in tr.boundary_spans()]
+    assert kinds[0] == "process.start" and set(kinds[1:]) == {"mxtpu.import"}
+    assert tr.boundary_spans("mxtpu.import")[0].fields == {"module": "mxtpu"}
+
+
+def test_initialize_of_a_nested_block_is_one_span():
+    from mxtpu.gluon import nn
+
+    tr = get_tracer()
+    tr.reset()
+    net = nn.HybridSequential()
+    inner = nn.HybridSequential()
+    inner.add(nn.Dense(8, in_units=4), nn.Dense(2, in_units=8))
+    net.add(inner, nn.Dense(3))         # the last one's weight is deferred
+    net.initialize()
+    spans = tr.boundary_spans("block.initialize")
+    assert len(spans) == 1 and spans[0].parent is None
+    # 4 x 8 + 8, 8 x 2 + 2 and the last layer's bias of 3, in float32
+    assert spans[0].fields == {"params": 5, "bytes": 4 * (40 + 18 + 3)}
+    # the eager programs it ran are its children
+    assert any(s.parent == spans[0].tick
+               for s in tr.boundary_spans("xla.compile"))
+    assert tr.events() == []
+    # a parameter at a time (the serving builder's way): a span each
+    for p in net.collect_params().values():
+        p.initialize(force_reinit=True)
+    later = tr.boundary_spans("block.initialize")[1:]
+    assert [s.fields["params"] for s in later] == [1, 1, 1, 1, 0, 1]
+    # nothing open is left behind on this thread
+    net.collect_params().initialize(force_reinit=True)
+    assert tr.boundary_spans("block.initialize")[-1].fields["params"] == 5
+
+
+def test_compile_spans_carry_the_programs_name_and_whether_fetched():
+    import jax
+    import jax.numpy as jnp
+
+    from mxtpu.observability.trace import Tracer
+
+    tr = get_tracer()
+    tr.reset()
+
+    def pr37_probe(v):
+        return jnp.cos(v) * 5.0 - 37.0
+
+    jax.jit(pr37_probe)(jnp.ones((7, 3)))
+    mine = [s for s in tr.boundary_spans("xla.compile")
+            if "pr37_probe" in (s.fields["name"] or "")]
+    assert {s.fields["kind"] for s in mine} == {"trace", "lower", "compile"}
+    compiled = [s for s in mine if s.fields["kind"] == "compile"]
+    assert [s.fields["name"] for s in compiled] == ["jit(pr37_probe)"]
+    assert [s.fields["name"] for s in mine if s.fields["kind"] == "lower"] \
+        == ["jit(pr37_probe)"]
+    assert compiled[0].fields["fetched"] is False
+    assert all("name" in s.fields
+               for s in tr.boundary_spans("xla.compile"))
+    assert all("fetched" not in s.fields for s in mine
+               if s.fields["kind"] != "compile")
+    # the CPU tests keep no persistent cache: jax's own events, made up
+    tr = Tracer(enabled=False)
+    fetch = "/jax/compilation_cache/cache_retrieval_time_sec"
+    compile_ = "/jax/core/compile/backend_compile_duration"
+    tr.compile_seen(fetch, 0.001)               # jax hands a fetch no name
+    tr.compile_seen(compile_, 0.002, fun_name="jit(step)")
+    tr.compile_seen(compile_, 0.0005, fun_name="jit(other)")
+    tr.compile_seen("/jax/compilation_cache/compile_time_saved_sec", 9.0)
+    got = [(s.fields["kind"], s.fields["name"], s.fields.get("fetched"))
+           for s in tr.boundary_spans()]
+    assert got == [("cache_fetch", "jit(step)", None),
+                   ("compile", "jit(step)", True),
+                   ("compile", "jit(other)", False)]
+    # another thread's fetch is not this thread's
+    import threading
+    t = threading.Thread(target=tr.compile_seen, args=(fetch, 0.001))
+    t.start()
+    t.join()
+    tr.compile_seen(compile_, 10.0, fun_name="jit(mine)")
+    assert tr.boundary_spans()[-1].fields["fetched"] is False
+
+
+class _CountedMemory:
+    """In ``SPMDTrainer._device_memory``'s place: counts its calls and
+    answers as a chip's allocator would."""
+
+    def __init__(self):
+        self.calls = 0
+
+    def __call__(self):
+        self.calls += 1
+        return {"bytes_in_use": 1000 + self.calls,
+                "peak_bytes_in_use": 2000 + self.calls}
+
+
+def test_memory_fields_after_staging_and_first_steps_only(monkeypatch):
+    from mxtpu.parallel import SPMDTrainer
+
+    # what the platform gives: two fields of a chip's, nothing of the CPU's
+    class Chip:
+        def memory_stats(self):
+            return {"bytes_in_use": 5, "peak_bytes_in_use": 7,
+                    "bytes_limit": 9}
+
+    def on(device):
+        mesh = type("M", (), {})()
+        mesh.jax_mesh = type("J", (), {})()
+        mesh.jax_mesh.local_devices = [device]
+        return type("T", (), {"_mesh": mesh})()
+
+    assert SPMDTrainer._device_memory(on(Chip())) == {
+        "bytes_in_use": 5, "peak_bytes_in_use": 7}
+    tr = get_tracer()
+    tr.reset()
+    trainer = _toy_trainer()
+    for rows in (8, 8, 4):
+        trainer.step(*_toy_batch(rows))
+    spans = tr.boundary_spans(("trainer.stage", "trainer.step"))
+    assert spans and not any("bytes_in_use" in s.fields for s in spans)
+
+    memory = _CountedMemory()
+    monkeypatch.setattr(SPMDTrainer, "_device_memory", lambda self: memory())
+    with tracing() as tr:
+        trainer = _toy_trainer()
+        for rows in (8, 8, 4, 8):
+            trainer.step(*_toy_batch(rows))
+        steps = tr.boundary_spans("trainer.step")
+        stage = tr.boundary_spans("trainer.stage")[0]
+        assert memory.calls == 3            # staging and two first steps
+        assert stage.fields == {"bytes_in_use": 1001,
+                                "peak_bytes_in_use": 2001}
+        assert [s.fields.get("bytes_in_use") for s in steps] == [
+            1002, None, 1003, None]
+        # noise by nature: on the end events beside the fields, and out
+        # of the deterministic bytes
+        ends = [e for e in tr.events(types="trainer.step")
+                if e.phase == "E"]
+        assert [e.noise.get("peak_bytes_in_use") for e in ends] == [
+            2002, None, 2003, None]
+        assert all("bytes_in_use" not in e.fields for e in ends)
+        assert "bytes_in_use" not in tr.to_json()
+        assert "bytes_in_use" in tr.to_json(include_noise=True)
+
+
+def test_a_later_step_records_what_it_recorded_before(monkeypatch):
+    """A ``trainer.step`` that is not ``first`` carries ``step``,
+    ``first``, ``tokens`` (and ``steps`` of a window) and nothing else,
+    and reads no memory."""
+    from mxtpu.parallel import SPMDTrainer
+
+    memory = _CountedMemory()
+    monkeypatch.setattr(SPMDTrainer, "_device_memory", lambda self: memory())
+    tr = get_tracer()
+    trainer = _toy_trainer()
+    x, y = _toy_batch(8)
+    xs = mx.nd.array(np.stack([x.asnumpy()] * 3))
+    ys = mx.nd.array(np.stack([y.asnumpy()] * 3))
+    trainer.step(x, y)
+    trainer.step_window(xs, ys)
+    tr.reset()
+    before = memory.calls
+    trainer.step(x, y)
+    trainer.step_window(xs, ys)
+    trainer.step(x, y)
+    assert memory.calls == before
+    assert [s.fields for s in tr.boundary_spans("trainer.step")] == [
+        {"first": False, "tokens": 64, "step": 5},
+        {"first": False, "tokens": 192, "steps": 3, "step": 8},
+        {"first": False, "tokens": 64, "step": 9}]
+
+
+def test_the_event_cap_is_a_constant_and_no_knob(monkeypatch):
+    from mxtpu.observability.trace import MAX_EVENTS, Tracer
+
+    monkeypatch.setenv("MXTPU_TRACE_EVENTS", "3")
+    tr = Tracer(enabled=True)
+    for _ in range(5):
+        tr.emit("engine.decode")
+    assert len(tr.events()) == 5 and tr.dropped_events == 0
+    assert tr._max_events == MAX_EVENTS == 200000
+    tr = Tracer(max_events=3, enabled=True)
+    for _ in range(5):
+        tr.emit("engine.decode")
+    assert len(tr.events()) == 3 and tr.dropped_events == 2
