@@ -22,6 +22,14 @@ probabilities come a tile at a time from Pallas kernels
 of scores with its queries' thresholds (``flash_attention(keep=...)``).
 What is whole is (T, T) float32 per sequence, keys first: the scores,
 the mean probabilities and what the loss forms of them.
+
+A row's threshold is its ``top_k``-th largest score, found without a
+sort by 32 counts (``kth_largest``).  Where the indexer's kernels tile,
+one of them counts, ``indexer_threshold``: a query tile's whole column
+of keys in VMEM, so the scores are read once; ``kth_largest`` itself,
+32 passes of XLA over the whole array, serves the shapes too small to
+tile or too long for a column to fit, and is what the kernel is held to,
+bit for bit.
 """
 
 from __future__ import annotations
@@ -34,7 +42,8 @@ from jax import lax
 
 from ..base import register_op
 from .pallas.flash_attention import flash_attention
-from .pallas.indexer import MASKED, indexer_probs, indexer_scores
+from .pallas.indexer import (MASKED, indexer_probs, indexer_scores,
+                             indexer_threshold, threshold_width)
 from .remat import keep
 
 __all__ = ["kth_largest", "indexed_attention"]
@@ -46,7 +55,9 @@ def kth_largest(x, k, axis=-1):
     a float32 order as the floats do once the negatives' are turned, so
     the answer's 32 bits are found from the highest down, each by one
     count of the values at or above a candidate — 32 passes that a
-    reduction fuses, where a sort of 8,192 takes some 90."""
+    reduction fuses, where a sort of 8,192 takes some 90.  Each pass
+    reads all of ``x``: the plain form, which ``indexed_attention`` takes
+    only where ``indexer_threshold`` does not tile."""
     bits = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
     turned = jnp.where(bits < 0, bits ^ jnp.int32(0x7fffffff), bits)
     ordered = lax.bitcast_convert_type(turned, jnp.uint32) \
@@ -75,6 +86,8 @@ def _threshold(scores, top_k):
     T = scores.shape[1]
     if T <= top_k:
         return jnp.full(scores.shape[::2], -jnp.inf, jnp.float32)
+    if threshold_width(T) is not None:
+        return indexer_threshold(scores, top_k)
     least = kth_largest(scores, top_k, axis=1)
     return jnp.where(jnp.arange(T) >= top_k, least, -jnp.inf)
 
@@ -111,7 +124,7 @@ def indexed_attention(q, k, v, q_idx, k_idx, w_idx, top_k=2048, scale=None):
     q_idx, k_idx, w_idx = keep(q_idx), keep(k_idx), keep(w_idx)
     scores = indexer_scores(q_idx, k_idx, w_idx)         # (B, Tk, Tq)
     fixed = sg(scores)
-    # the thresholds are 4 T bytes a sequence and 32 passes over the
+    # the thresholds are 4 T bytes a sequence and 32 counts over the
     # scores to find: a unit of recomputation keeps them
     least = keep(_threshold(fixed, top_k))
     o, lse = flash_attention(q, jnp.repeat(k, group, axis=1),

@@ -7,4 +7,4 @@ from .flash_attention import flash_attention
 from .paged_attention import paged_decode_attention
 from .prefill_attention import paged_prefill_attention
 from .kda import kda, kda_mixer
-from .indexer import indexer_probs, indexer_scores
+from .indexer import indexer_probs, indexer_scores, indexer_threshold

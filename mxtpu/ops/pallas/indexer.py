@@ -27,8 +27,16 @@ one key block once for all the heads that share it.
   measured against.
 
 A step above the diagonal computes nothing and fetches nothing (its
-index maps name the blocks the step before it held).  float32
-throughout, products at ``Precision.HIGHEST``; interpret mode on the CPU.
+index maps name the blocks the step before it held).
+
+- ``indexer_threshold`` finds each query's ``top_k``-th largest score,
+  the selection's threshold, from ONE read of I: its grid is (batch,
+  query tile), a step holds its queries' whole column of keys in VMEM
+  and counts there, 32 times, what ``ops/dsa.py`` ``kth_largest`` counts
+  in 32 passes over HBM.
+
+float32 throughout, products at ``Precision.HIGHEST``; interpret mode on
+the CPU.
 """
 
 from __future__ import annotations
@@ -38,13 +46,15 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import counters
 from .flash_attention import _padded, _tile, _vmem_limit
 
-__all__ = ["MASKED", "indexer_scores", "indexer_probs", "kernel_specs"]
+__all__ = ["MASKED", "indexer_scores", "indexer_probs", "indexer_threshold",
+           "threshold_width", "kernel_specs"]
 
 #: what a (T, T) array of this module holds above the diagonal
 MASKED = -1e30
@@ -54,6 +64,7 @@ SCORES_FWD_NAME = "indexer_scores_fwd"
 #: keys' pass's does not
 SCORES_BWD_NAME = "indexer_scores_bwd_q_k"
 PROBS_NAME = "indexer_probs"
+THRESHOLD_NAME = "indexer_threshold"
 
 _HI = jax.lax.Precision.HIGHEST
 _dot = functools.partial(jax.lax.dot_general, precision=_HI,
@@ -335,18 +346,166 @@ def indexer_probs(q, k, lse, scores, least, scale):
     return probs[:, :T, :T]
 
 
-def kernel_specs(B, Hi, T, d, H, G, D, interpret=False):
-    """KernelSpec descriptors (mxtpu.analysis.kernel_check) of the three
+# --------------------------------------------------------------- threshold
+
+#: the queries a grid step of ``indexer_threshold`` takes, widest first,
+#: and the keys one trip of its loops walks at most.  On a v5e, 8,192 x
+#: 8,192 and the 2,048th largest, ms a call at trips of 32 / 64 / 128 /
+#: 256 keys (PERF.md, PR 38): width 512 0.96 / 0.80 / 0.76 / 0.64, 256
+#: 1.37 / 1.02 / 0.80 / 0.76, 128 2.11 / 1.44 / 1.05; the 32 passes of
+#: ``kth_largest`` in XLA 14.39
+THRESHOLD_WIDTHS, THRESHOLD_SLAB = (512, 256, 128), 256
+#: a v5e core's VMEM: what a call may ask the compiler for at most
+#: (``analysis.kernel_check.PHYSICAL_VMEM`` holds the specs to the same)
+_PHYSICAL_VMEM = 128 * (1 << 20)
+_LOWEST = -2 ** 31                   # under every candidate: never counted
+#: ``MASKED`` as ``_turned`` turns it (it is negative)
+_MASKED_TURNED = int(np.float32(MASKED).view(np.int32)) ^ 0x7fffffff
+
+
+def _turned(bits):
+    """The int32 that order, signed, as the float32 of these bits do
+    (``kth_largest``'s ``ordered`` before its sign is flipped for an
+    unsigned compare), and the bits again of such an int32."""
+    return jnp.where(bits < 0, bits ^ 0x7fffffff, bits)
+
+
+def _threshold_kernel(score_ref, out_ref, turned_ref, *, width, slab, top_k,
+                      valid):
+    """A query tile's thresholds from its whole column of scores (all
+    the keys, ``width`` queries on the lanes): ``kth_largest``'s 32
+    refinements, from the highest bit down, each a count down the rows
+    of the column's values at or above a candidate.  Only the keys up to
+    the tile's last query are walked — the others hold ``MASKED`` and
+    are counted by their number — and a tile none of whose queries has
+    more than ``top_k`` keys counts nothing."""
+    i = pl.program_id(1)
+    reach = (i + 1) * width
+
+    @pl.when(reach <= top_k)
+    def _():
+        out_ref[...] = jnp.full_like(out_ref, -jnp.inf)
+
+    @pl.when(reach > top_k)
+    def _():
+        each = slab // 8
+        trips = reach // slab
+
+        def turn(s, _):
+            rows = pl.ds(pl.multiple_of(s * slab, slab), slab)
+            turned = _turned(jax.lax.bitcast_convert_type(
+                score_ref[0, rows, :], jnp.int32))
+            if valid < score_ref.shape[1]:              # padded keys
+                k_pos = s * slab + jax.lax.broadcasted_iota(
+                    jnp.int32, turned.shape, 0)
+                turned = jnp.where(k_pos < valid, turned, _LOWEST)
+            turned_ref[pl.ds(s * each, each)] = turned.reshape(
+                each, 8, width)
+
+        jax.lax.fori_loop(0, trips, turn, None)
+        beyond = jnp.maximum(valid - reach, 0)
+
+        def refine(_, carry):
+            found, bit = carry
+            candidate = found ^ bit
+            wide = jnp.broadcast_to(candidate, (8, width))
+
+            def count(s, acc):
+                at = turned_ref[pl.ds(s * each, each)] >= wide
+                return acc + jnp.sum(jnp.where(at, 1, 0), axis=0)
+
+            acc = jax.lax.fori_loop(0, trips, count,
+                                    jnp.zeros((8, width), jnp.int32))
+            n = jnp.sum(acc, axis=0, keepdims=True) \
+                + jnp.where(_MASKED_TURNED >= candidate, beyond, 0)
+            return (jnp.where(n >= top_k, candidate, found),
+                    jax.lax.shift_right_logical(bit, 1))
+
+        found, _ = jax.lax.fori_loop(
+            0, 32, refine, (jnp.full((1, width), _LOWEST, jnp.int32),
+                            jnp.int32(_LOWEST)))
+        q_pos = i * width + jax.lax.broadcasted_iota(
+            jnp.int32, (1, width), 1)
+        out_ref[0] = jnp.where(
+            q_pos >= top_k,
+            jax.lax.bitcast_convert_type(_turned(found), jnp.float32),
+            -jnp.inf)
+
+
+def threshold_width(T):
+    """The queries a grid step of ``indexer_threshold`` takes at ``T``
+    positions: the widest of ``THRESHOLD_WIDTHS`` that tiles the padded
+    length and whose column of all the keys VMEM holds.  None where the
+    kernels of this file do not tile (under 16 positions) or no column
+    fits."""
+    if T < 16:
+        return None
+    Tp, _ = _geometry(T)
+    for width in THRESHOLD_WIDTHS:
+        limit = _threshold_vmem(Tp, width)
+        if Tp % width == 0 and (limit is None or limit <= _PHYSICAL_VMEM):
+            return width
+    return None
+
+
+def _threshold_vmem(Tp, width):
+    """What the call asks the compiler for: the column twice buffered,
+    its turned copy, the thresholds' rows."""
+    return _vmem_limit(3 * _padded(Tp, width, "float32")
+                       + 2 * _padded(8, width, "float32"))
+
+
+def _threshold_specs(Tp, width, top_k):
+    """BlockSpecs of (the scores, the thresholds).  Grid (batch, query
+    tile i); a tile that counts nothing names the first counting tile's
+    column, so nothing is fetched for it."""
+    first = min(top_k, Tp - 1) // width
+    return [pl.BlockSpec((1, Tp, width),
+                         lambda b, i: (b, 0, jnp.maximum(i, first))),
+            pl.BlockSpec((1, 1, width), lambda b, i: (b, 0, i))]
+
+
+def indexer_threshold(scores, top_k):
+    """(B, T) float32: the ``top_k``-th largest score of each query's
+    column, ``ops/dsa.py`` ``kth_largest``'s to the bit, and -inf for a
+    query with no more than ``top_k`` causal keys (it keeps them all).
+    scores (B, T, T) float32, keys first, ``MASKED`` above the diagonal;
+    ``threshold_width(T)`` must not be None.  Passes no gradient."""
+    B, T = scores.shape[:2]
+    Tp, _ = _geometry(T)
+    width = threshold_width(T)
+    counters.bump(THRESHOLD_NAME)
+    specs = _threshold_specs(Tp, width, top_k)
+    scores = jax.lax.stop_gradient(scores).astype(jnp.float32)
+    least = pl.pallas_call(
+        functools.partial(_threshold_kernel, width=width,
+                          slab=min(THRESHOLD_SLAB, width), top_k=int(top_k),
+                          valid=T),
+        out_shape=jax.ShapeDtypeStruct((B, 1, Tp), jnp.float32),
+        grid=(B, Tp // width), in_specs=specs[:1], out_specs=specs[1],
+        scratch_shapes=[pltpu.VMEM((Tp // 8, 8, width), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_threshold_vmem(Tp, width)),
+        interpret=jax.default_backend() == "cpu", name=THRESHOLD_NAME,
+    )(_pad(_pad(scores, 1, Tp), 2, Tp))
+    return least[:, 0, :T]
+
+
+def kernel_specs(B, Hi, T, d, H, G, D, top_k=2048, interpret=False):
+    """KernelSpec descriptors (mxtpu.analysis.kernel_check) of the four
     pallas_calls one forward and backward of ``ops/dsa.py``'s indexed
     attention issues beside the flash kernels, in this order: the
-    scores forward, the mean probabilities, the scores' backward — built
-    from the BlockSpecs the calls themselves use.  ``Hi`` heads of ``d``
-    in the indexer; ``H`` query and ``G`` key heads of ``D`` in the main
-    attention."""
-    from ...analysis.kernel_check import BlockOperand, KernelSpec
+    scores forward, the mean probabilities, the scores' backward, the
+    thresholds — built from the BlockSpecs the calls themselves use.
+    ``Hi`` heads of ``d`` in the indexer; ``H`` query and ``G`` key
+    heads of ``D`` in the main attention; the ``top_k`` best kept."""
+    from ...analysis.kernel_check import (BlockOperand, KernelSpec,
+                                          ScratchOperand)
 
     Tp, tile = _geometry(T)
     n = Tp // tile
+    width = threshold_width(T)
     k_idx, q_idx, w, square = ((B, Tp, d), (B, Hi, Tp, d), (B, Hi, 1, Tp),
                                (B, Tp, Tp))
     arrays = dict(k_idx=k_idx, q_idx=q_idx, w=w, scores=square, g=square,
@@ -355,15 +514,16 @@ def kernel_specs(B, Hi, T, d, H, G, D, interpret=False):
                   q=(B, H, Tp, D), k=(B, G, Tp, D), lse=(B, H, 1, Tp),
                   least=(B, 1, Tp))
 
-    def spec(name, specs, operands, outs):
+    def spec(name, specs, operands, outs, grid=(B, n, n), scratch=(),
+             limit=lambda specs: _vmem(specs, tile)):
         kinds = ("in",) * (len(specs) - outs) + ("out",) * outs
         return KernelSpec(
-            name, grid=(B, n, n), interpret=interpret,
+            name, grid=grid, interpret=interpret, scratch=scratch,
             operands=[BlockOperand(operand, kind, s.block_shape,
                                    arrays[operand], "float32", s.index_map,
                                    strict_dims=())
                       for s, operand, kind in zip(specs, operands, kinds)],
-            vmem_limit=_vmem(specs, tile))
+            vmem_limit=limit(specs))
 
     tag = "[float32,T=%d,Hi=%d,d=%d]" % (T, Hi, d)
     return [
@@ -374,4 +534,9 @@ def kernel_specs(B, Hi, T, d, H, G, D, interpret=False):
              ("q", "k", "lse", "scores", "least", "pbar"), 1),
         spec(SCORES_BWD_NAME + tag, _scores_bwd_specs(tile, Hi, d, Tp),
              ("k_idx", "k_idx_t", "q_idx_t", "w", "g", "dq_idx_t", "dw",
-              "dk_idx_t"), 3)]
+              "dk_idx_t"), 3),
+        spec(THRESHOLD_NAME + "[float32,T=%d,top_k=%d]" % (T, top_k),
+             _threshold_specs(Tp, width, top_k), ("scores", "least"), 1,
+             grid=(B, Tp // width),
+             scratch=[ScratchOperand("turned", (Tp // 8, 8, width), "int32")],
+             limit=lambda _: _threshold_vmem(Tp, width))]

@@ -216,7 +216,8 @@ def test_indexed_attention_at_the_keye_cells_geometry(on_chip):
     (8,192 x 512) or a K/V block's row (512 x 8,192) of the selection's
     scores beside the head; the indexer's three kernels walk tiles of
     512, a tile's heads a grid step, the backward's key gradient
-    (64 x 8,192) resident for the call."""
+    (64 x 8,192) resident for the call; the thresholds' kernel holds a
+    query tile's whole column of keys."""
     dsa = importlib.import_module("mxtpu.ops.dsa")
     T = 8192
     shapes = [_shape(s, F32, on_chip) for s in (
@@ -232,8 +233,12 @@ def test_indexed_attention_at_the_keye_cells_geometry(on_chip):
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_bwd",
                  "indexer_scores_fwd", "indexer_scores_bwd_q_k",
-                 "indexer_probs"):
+                 "indexer_probs", "indexer_threshold"):
         assert name in text, name
+    # the thresholds come from the kernel (a tile's column of 8,192 x 512
+    # scores twice buffered and its turned copy: 76 MiB asked for): no
+    # loop of XLA's carries the scores' unsigned bits
+    assert "u32[1,8192,8192]" not in text
     # the (T, T) arrays are whole — scores, mean probabilities, what the
     # loss forms of them — and nothing with a heads axis is
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
@@ -280,8 +285,9 @@ def test_the_whole_keye_step_fits_the_chip(on_chip, record_property):
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_bwd",
                  "indexer_scores_fwd", "indexer_scores_bwd_q_k",
-                 "indexer_probs"):
+                 "indexer_probs", "indexer_threshold"):
         assert text.count(name) >= 6, name
+    assert "u32[1,8192,8192]" not in text
     # a unit keeps its attention's output, logsumexp and thresholds, and
     # the indexer's queries, key and weights, so that the scores it forms
     # again are the first's to the bit; the (T, T) scores and mean

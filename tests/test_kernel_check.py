@@ -644,11 +644,12 @@ def test_prefill_specs_in_the_merge_gate():
 
 
 def test_indexed_attention_specs_describe_the_real_calls(monkeypatch):
-    """The five pallas_calls of one forward and backward of
+    """The six pallas_calls of one forward and backward of
     ``ops/dsa.py``'s indexed attention — the flash kernels with kept keys
-    and the indexer's three — are the ones ``kernel_specs`` describe:
+    and the indexer's four — are the ones ``kernel_specs`` describe:
     names, grids, block shapes (the key gradient's resident block among
-    the backward's), the VMEM asked for.  Traced only: the same calls
+    the backward's, the thresholds' whole column of keys), the VMEM
+    asked for.  Traced only: the same calls
     run against their equations in tests/test_keye_vl.py."""
     import importlib
     import jax
@@ -674,10 +675,15 @@ def test_indexed_attention_specs_describe_the_real_calls(monkeypatch):
         ones(B, Hi, T, d), ones(B, T, d), ones(B, Hi, T))
     specs = {s.name.split("[")[0].replace(".", "_"): s for s in
              fa.kernel_specs(B, H, T, D, interpret=True, kept=True)
-             + ix.kernel_specs(B, Hi, T, d, H, G, D, interpret=True)}
+             + ix.kernel_specs(B, Hi, T, d, H, G, D, top_k=64,
+                               interpret=True)}
     assert sorted(c["name"] for c in calls) == sorted(specs)
-    assert len(calls) == 5
+    assert len(calls) == 6
     Tp = 640                            # five tiles of 128 a side
+    least = specs[ix.THRESHOLD_NAME]
+    assert least.grid == (B, 5)
+    assert least.operands[0].block_shape == (1, Tp, 128)
+    assert [s.shape for s in least.scratch] == [(Tp // 8, 8, 128)]
     bwd = specs[ix.SCORES_BWD_NAME]
     assert bwd.grid == (B, 5, 5)
     assert [op.block_shape for op in bwd.operands if op.kind == "out"] \
@@ -702,6 +708,7 @@ def test_indexed_attention_specs_in_the_merge_gate():
     for name in ("flash_attention.fwd[float32,T=8192,D=128,kept]",
                  "flash_attention.bwd[float32,T=8192,D=128,kept]",
                  "indexer_scores_fwd[", "indexer_probs[",
-                 "indexer_scores_bwd_q_k["):
+                 "indexer_scores_bwd_q_k[",
+                 "indexer_threshold[float32,T=8192,top_k=2048]"):
         assert name in names, name
     assert "indexer_scores_bwd_k[" not in names

@@ -5,10 +5,14 @@ block of query rows at a time, every held expert on every token):
 logits, both loss terms, every leaf's first gradient and three Adam
 steps, float32, at toy widths on seeded weights.  And the parts one by
 one: the selection's prefix, the partition of the gradient, the rotation
-with three streams, ``flash_attention(keep=...)``, the k-th largest, the
-softmax-routed experts, the counters."""
+with three streams, ``flash_attention(keep=...)``, the k-th largest and
+the kernel that counts it from one read of the scores, the softmax-routed
+experts, the counters, the benchmark's patterns for the kernels' events."""
 
 import importlib
+import json
+import os
+import re
 
 import numpy as np
 import pytest
@@ -330,6 +334,193 @@ def test_the_scores_backward_is_one_traced_kernel():
     assert snap["kernel_invocations." + indexer.SCORES_BWD_NAME] >= 1
     assert "kernel_invocations.indexer_scores_bwd_k" not in snap
     assert "kernel_invocations.indexer_scores_bwd_q" not in snap
+
+
+# ------------------------------------------- the selection's thresholds
+
+def _selection_scores(T, batch=2):
+    """(batch, T, T) scores as the selection meets them, keys first:
+    negative values, runs of ties (sequence 0 in quarters, so that every
+    threshold is shared), exact zeros of both signs (sequence 1),
+    ``MASKED`` above the diagonal."""
+    x = jax.random.normal(jax.random.PRNGKey(T), (batch, T, T))
+    x = x.at[0].set(jnp.round(4.0 * x[0]) / 4.0)
+    x = x.at[1, ::2].set(jnp.maximum(x[1, ::2], 0.0)).at[1, ::4].multiply(-1.0)
+    at = jnp.arange(T)
+    return jnp.where(at[:, None] <= at[None, :], x, indexer.MASKED)
+
+
+@pytest.mark.parametrize("top_k", [
+    lambda T: 1, lambda T: 64, lambda T: T - 1, lambda T: T,
+    lambda T: T + 5], ids=["1", "64", "T-1", "T", "T+5"])
+@pytest.mark.parametrize("T", [200, 256, 1024])
+def test_the_thresholds_kernel_is_kth_largest_to_the_bit(T, top_k):
+    """``indexer_threshold`` (interpret mode) against the plain form it
+    replaces and against a sort, at a length that pads (200 of 256), one
+    tile and two: the same bits on every row that has more than
+    ``top_k`` keys, -inf on the others, and nothing counted at all where
+    no row has."""
+    k = top_k(T)
+    scores = _selection_scores(T)
+    held = np.asarray(scores)
+    assert np.signbit(held[1][held[1] == 0.0]).any()    # both zeros
+    before = counters.count(indexer.THRESHOLD_NAME)
+    got = np.asarray(dsa._threshold(scores, k))
+    assert got.shape == (2, T) and got.dtype == np.float32
+    assert (got[:, :k] == -np.inf).all()
+    assert counters.count(indexer.THRESHOLD_NAME) - before == (k < T)
+    if k >= T:
+        return
+    plain = np.asarray(dsa.kth_largest(scores, k, axis=1))
+    np.testing.assert_array_equal(got[:, k:].view(np.uint32),
+                                  plain[:, k:].view(np.uint32))
+    np.testing.assert_array_equal(got[:, k:],
+                                  np.sort(held, axis=1)[:, -k][:, k:])
+    # ties at the threshold: a row keeps more than top_k keys
+    kept = (held[0] >= got[0][None, :]).sum(0)
+    assert (kept[k:] >= k).all() and (T - k < 2 or (kept[k:] > k).any())
+
+
+def test_the_thresholds_pass_no_gradient():
+    scores = _selection_scores(256, batch=1)
+
+    def total(s):
+        least = dsa._threshold(s, 64)
+        return jnp.sum(jnp.where(jnp.isfinite(least), least, 0.0))
+
+    assert float(total(scores)) != 0.0
+    assert not np.asarray(jax.grad(total)(scores)).any()
+
+
+def test_a_traced_threshold_bumps_its_counter_once():
+    before = counters.count(indexer.THRESHOLD_NAME)
+    jax.make_jaxpr(lambda s: dsa._threshold(s, 64))(
+        jnp.zeros((1, 256, 256), jnp.float32))
+    assert indexer.THRESHOLD_NAME == "indexer_threshold"
+    assert counters.count(indexer.THRESHOLD_NAME) == before + 1
+    assert get_registry().snapshot()[
+        "kernel_invocations.indexer_threshold"] == before + 1
+    # the plain form's shapes trace no kernel
+    jax.make_jaxpr(lambda s: dsa._threshold(s, 2))(
+        jnp.zeros((1, 8, 8), jnp.float32))
+    assert counters.count(indexer.THRESHOLD_NAME) == before + 1
+
+
+def _metric(name):
+    return harness.load_json(harness.HERE, "metrics", name + ".json")
+
+
+@pytest.mark.parametrize("T, loops", [(256, 0), (8, 1)],
+                         ids=["kernel", "plain"])
+def test_the_lowered_attention_loops_over_the_scores_only_in_the_plain_form(
+        T, loops):
+    """What ``indexer_time_share.keye`` told the selection by, a
+    ``while`` that carries the (batch, T, T) unsigned bits of the
+    scores: gone from the lowered op where the kernels tile, still there
+    at the plain form's shapes."""
+    hlo = jax.jit(lambda *a: dsa.indexed_attention(*a, top_k=T // 4)).lower(
+        *_attention_inputs(T, 16)).compiler_ir("hlo").as_hlo_text()
+    counted = re.compile(
+        _metric("indexer_time_share.keye")["args"]["counted"]["pattern"])
+    found = [line for line in map(str.strip, hlo.splitlines())
+             if " while(" in line and counted.search(line)]
+    assert len(found) == loops, found
+    assert ("u32[1,%d,%d]" % (T, T) in hlo) == bool(loops)
+
+
+# heads of event names as the traced runs have them (an event's name is
+# its whole HLO instruction, cut at 120 characters;
+# chiprun_out/pr36/final/C.traced.json), and what the threshold kernel's
+# events and a fusion that reads its result will read
+EVENTS = [
+    "%while.110 = (s32[]{:T(128)}, u32[1,8192]{1,0:T(1,128)S(1)}, "
+    "u32[1,8192,8192]{2,1,0:T(8,128)}, s32[]{:T(128)}, s32[]{...",
+    "%while.103 = (s32[]{:T(128)}, f32[8192,2048]{1,0:T(8,128)}, "
+    "f32[65536]{0:T(1024)}, f32[16,2048,768]{2,1,0:T(8,128)}, ...",
+    "%convert_reduce_fusion.32 = s32[8192]{0:T(1024)S(1)} fusion("
+    "u32[1,8192,8192]{2,1,0:T(8,128)} %get-tuple-element.6301,...",
+    "%flash_attention_bwd.6 = (f32[32,8192,128]{2,1,0:T(8,128)}, "
+    "f32[32,8192,128]{2,1,0:T(8,128)}, f32[32,8192,128]",
+    "%jvp_flash_attention_fwd_.9 = (f32[32,8192,128]{2,1,0:T(8,128)}, "
+    "f32[32,16,512]{2,1,0:T(8,128)S(1)}) custom-ca",
+    "%indexer_scores_bwd_q_k.8 = (f32[1,16,64,8192]{3,2,1,0:T(8,128)}, "
+    "f32[1,16,1,8192]{3,2,1,0:T(1,128)S(1)}, f32[",
+    "%indexer_probs.8 = f32[1,8192,8192]{2,1,0:T(8,128)} custom-call("
+    "f32[1,32,8192,128]{3,2,1,0:T(8,128)} %pad_maxi",
+    "%jvp_indexer_probs_.7 = f32[1,8192,8192]{2,1,0:T(8,128)} custom-call("
+    "f32[1,32,8192,128]{3,2,1,0:T(8,128)} %pad",
+    "%jvp_indexer_scores_fwd_.9 = f32[1,8192,8192]{2,1,0:T(8,128)} "
+    "custom-call(f32[1,8192,64]{2,1,0:T(8,128)S(1)} %",
+    "%indexer_scores_fwd.6 = f32[1,8192,8192]{2,1,0:T(8,128)} custom-call("
+    "f32[1,8192,64]{2,1,0:T(8,128)S(1)} %copy-",
+    "%add_select_fusion.31 = f32[1,8192,8192]{2,1,0:T(8,128)} fusion("
+    "f32[1,8192,8192]{2,1,0:T(8,128)} %indexer_scor",
+    "%exponential_reduce_fusion.6 = f32[8192]{0:T(1024)S(1)} fusion("
+    "f32[1,8192,8192]{2,1,0:T(8,128)} %jvp_indexer_s",
+    "%reshape_select_fusion.16 = u32[1,8192]{1,0:T(1,128)S(1)} fusion("
+    "pred[8192]{0:T(1024)(128)(4,1)S(1)} %broadcas",
+    "%ragged-dot-none.69 = f32[16,2048,768]{2,1,0:T(8,128)} custom-call("
+    "s32[1]{0:T(128)} %get-tuple-element.6131, s"]
+THRESHOLD_EVENTS = [
+    "%indexer_threshold.6 = f32[1,8192]{1,0:T(1,128)} custom-call("
+    "f32[1,8192,8192]{2,1,0:T(8,128)} %indexer_scores_fwd.6)",
+    "%jvp_indexer_threshold_.7 = f32[1,1,8192]{2,1,0:T(1,128)} custom-call("
+    "f32[1,8192,8192]{2,1,0:T(8,128)} %jvp_indexer_s"]
+READS_IT = ("%broadcast_compare_fusion.14 = pred[1,8192,8192]{2,1,0} fusion("
+            "f32[1,1,8192]{2,1,0:T(1,128)} %indexer_threshold.6, f32[1,8192")
+
+
+def _recorded_events():
+    """Every event name of PR 36's traced window, where the builder's
+    records are at hand (they are not committed)."""
+    path = os.path.join(harness.ROOT, "chiprun_out", "pr36", "final",
+                        "C.traced.json")
+    if not os.path.exists(path):
+        return []
+    with open(path) as f:
+        return [name for name, _ in
+                json.loads(f.read().strip().splitlines()[-1])
+                ["breakdown"]["device_ops"]]
+
+
+def test_the_selections_share_reads_the_threshold_kernel_and_nothing_else():
+    spec = _metric("selection_time_share.keye")
+    assert spec["reader"] == "kernel_time_share"
+    assert spec["workloads"] == ["keye-vl.pretrain-seq8192"]
+    patterns = [re.compile(p) for p in spec["args"]["patterns"]]
+    hit = lambda name: any(p.search(name) for p in patterns)
+    assert all(map(hit, THRESHOLD_EVENTS))
+    assert not any(map(hit, EVENTS + [READS_IT] + _recorded_events()))
+    # the limits chipbench/tests/test_benchmark_json.py holds a name, a
+    # unit and a line to
+    assert re.match(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$", spec["name"])
+    assert re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", spec["unit"])
+    for text in (spec["layer"], spec["args"]["note"]):
+        assert 1 <= len(text) <= 200 and "\n" not in text \
+            and "\t" not in text
+    bench = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    assert bench["per_layer"][-1] == {
+        key: spec[key] for key in ("name", "unit", "better", "source",
+                                   "layer", "moves", "workloads")}
+
+
+@pytest.mark.parametrize("name", [
+    "indexer_roofline.keye", "indexer_time_share.keye",
+    "sparse_attn_roofline.keye", "attn_time_share.keye"])
+def test_no_accepted_pattern_prices_the_threshold_kernel(name):
+    """The kernel forms no product: a roofline share that matched its
+    events would price them as one.  (The time share it belongs to
+    cannot be edited by this PR; ``selection_time_share.keye`` reads
+    it.)"""
+    args = _metric(name)["args"]
+    patterns = list(args.get("patterns", [])) \
+        + list(args.get("kernels", {}).values())
+    assert patterns
+    if "counted" in args:
+        patterns.append(args["counted"]["pattern"])
+    patterns = [re.compile(p) for p in patterns]
+    for event in THRESHOLD_EVENTS + [READS_IT]:
+        assert not any(p.search(event) for p in patterns), event
 
 
 def test_mrope_with_three_streams_and_with_one():
