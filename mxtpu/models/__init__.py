@@ -23,6 +23,8 @@ from . import glm4_moe_lite
 from .glm4_moe_lite import Glm4MoeLiteLM, glm4_moe_lite_from_config
 from . import keye_vl
 from .keye_vl import KeyeVLTextLM, keye_vl_from_config
+from . import lfm2_moe
+from .lfm2_moe import Lfm2MoeLM, lfm2_moe_from_config
 from . import sampler
 from .sampler import (BeamSearchSampler, NGramDrafter, SequenceSampler,
                       beam_search)

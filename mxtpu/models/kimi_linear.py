@@ -155,9 +155,10 @@ class ExpertShare(HybridBlock):
     ``num_experts_total`` gated experts (one expert-parallel rank's
     share; the whole layer when ``held`` is None) and ``num_shared``
     shared experts.  The router scores all experts, each by a sigmoid of
-    its own or (``score="softmax"``) by a softmax over them all; what the
-    experts held elsewhere would add is not in the result (on one chip
-    there is no exchange).
+    its own or (``score="softmax"``) by a softmax over them all, and
+    ``renorm_eps`` is added to the chosen scores' sum where they are
+    renormalised; what the experts held elsewhere would add is not in
+    the result (on one chip there is no exchange).
 
     ``select_bias`` (added to the scores for the choice only) is frozen:
     the family moves it by a rule outside the gradient, which is not
@@ -168,7 +169,7 @@ class ExpertShare(HybridBlock):
 
     def __init__(self, units, hidden_size, num_experts_total, top_k,
                  held=None, routed_scale=1.0, renormalize=True,
-                 num_shared=1, score="sigmoid", **kwargs):
+                 num_shared=1, score="sigmoid", renorm_eps=0.0, **kwargs):
         super().__init__(**kwargs)
         first, count = held if held is not None else (0, num_experts_total)
         if not 0 <= first <= first + count <= num_experts_total:
@@ -176,7 +177,7 @@ class ExpertShare(HybridBlock):
                              % (held, num_experts_total))
         self._first, self._k = first, top_k
         self._scale, self._renorm = routed_scale, renormalize
-        self._score = score
+        self._score, self._renorm_eps = score, renorm_eps
         with self.name_scope():
             self.router = _dense(num_experts_total, units, "router_")
             self.select_bias = self.params.get(
@@ -214,7 +215,7 @@ class ExpertShare(HybridBlock):
             x, self.router.weight.data(x.context), select_bias,
             experts_gate, experts_up, experts_down, held_first=self._first,
             top_k=self._k, renormalize=self._renorm, scale=self._scale,
-            score=self._score)
+            score=self._score, renorm_eps=self._renorm_eps)
         with autograd.pause():
             self.load.data(None)._rebind(now.data)
             self.load_sum.data(None)._rebind(

@@ -55,6 +55,10 @@ source              pulls
                     ``dsa.<layer>.kept_mean`` — models/keye_vl.py
                     ``dsa_counts``; three numbers a layer from the
                     device)
+``conv``            the gated short convolutions' counter
+                    (``conv.positions`` — models/lfm2_moe.py
+                    ``conv_counts``; one number a layer from the
+                    device)
 ==================  ====================================================
 
 Live objects (engines, gateways, supervisors, routers) register with
@@ -301,6 +305,15 @@ def _src_dsa() -> dict:
     return dsa_counts()
 
 
+def _src_conv() -> dict:
+    """The gated short convolutions' counter: ``conv.positions`` that
+    went through a ``ShortConv`` mixer, summed over the layers since
+    their start: a program that drops the convolution counts none
+    (models/lfm2_moe.py ``conv_counts``)."""
+    from ..models.lfm2_moe import conv_counts
+    return conv_counts()
+
+
 def default_registry() -> MetricsRegistry:
     """A fresh registry pre-loaded with the built-in process-wide
     sources (module docstring table)."""
@@ -317,6 +330,7 @@ def default_registry() -> MetricsRegistry:
     reg.register_source("moe", _src_moe)
     reg.register_source("mtp", _src_mtp)
     reg.register_source("dsa", _src_dsa)
+    reg.register_source("conv", _src_conv)
     return reg
 
 

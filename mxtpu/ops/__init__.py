@@ -23,3 +23,4 @@ from . import random_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import pallas  # noqa: F401
 from . import dsa  # noqa: F401
+from . import short_conv  # noqa: F401

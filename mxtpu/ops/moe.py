@@ -224,7 +224,7 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 @register_op("moe_expert_share", num_outputs=2)
 def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
                      held_first=0, top_k=8, renormalize=True,
-                     scale=1.0, score="sigmoid"):
+                     scale=1.0, score="sigmoid", renorm_eps=0.0):
     """The routed part of an expert layer that holds a share of the
     experts (expert parallelism: one rank's part of the result).
 
@@ -237,7 +237,8 @@ def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
     ``score(x router_w^T) + select_bias`` — ``score`` "sigmoid", each
     expert's own, or "softmax" over all ``E``, in float32 — and weighs
     them by the scores themselves, renormalised over all the chosen
-    (held here or not) and scaled by ``scale``.  The (token, expert)
+    (held here or not; ``renorm_eps``, 0 by default, is added to their
+    sum) and scaled by ``scale``.  The (token, expert)
     pairs that fall to held experts are sorted by expert and go, a tile
     at a time (``_tile_rows``: about ``PAIRS_PER_TILE``), through three
     grouped products (``lax.ragged_dot``),
@@ -260,7 +261,9 @@ def moe_expert_share(x, router_w, select_bias, w_gate, w_up, w_down,
         scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), k)
     weight = jnp.take_along_axis(scores, chosen, axis=-1)     # (S, k)
     if renormalize:
-        weight = weight / jnp.sum(weight, -1, keepdims=True)
+        total = jnp.sum(weight, -1, keepdims=True)
+        # no ``+ 0.0`` in the program of a caller that passes none
+        weight = weight / (total + renorm_eps if renorm_eps else total)
     weight = weight * scale
 
     local = (chosen - held_first).reshape(-1)                 # (S * k,)

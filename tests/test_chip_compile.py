@@ -11,7 +11,8 @@ of 64, seq 128 in bfloat16 and the benchmark cell's seq 512 in float32),
 Kimi-Linear's cell (32 heads at 8,192 positions: latent attention with
 keys of 192 and values of 128, KDA's state kernels at 128),
 GLM-4.7-Flash's cell (20 heads at 8,192 positions, keys and values of
-256, and its whole training step, for the compiler's memory bound) and
+256, and its whole training step, for the compiler's memory bound),
+LFM2's cell (32 heads of 64 at 8,192 positions, and its whole step) and
 Llama-3-8B serving (32 Q / 8 KV heads of 128).
 Nothing runs, so results are covered by the interpret-mode suites
 (test_flash_attention / test_paged_attention_pallas /
@@ -305,6 +306,83 @@ def test_the_whole_keye_step_fits_the_chip(on_chip, record_property):
     record_property("keye_step_code_bytes", m.generated_code_size_in_bytes)
     record_property("keye_step_memory_bound_bytes", bound)
     print("Keye step: code %.3f GB, arguments %.3f GB, temporaries %.3f GB, "
+          "bound %.3f GB" % (m.generated_code_size_in_bytes / 1e9,
+                             m.argument_size_in_bytes / 1e9,
+                             m.temp_size_in_bytes / 1e9, bound / 1e9))
+    assert 0 <= m.argument_size_in_bytes \
+        - 12 * cfg["trained_parameters"] < 2 ** 20
+    assert bound < 13e9                 # the chip gives 16.909 GB
+
+
+def test_flash_attention_at_narrow_heads_at_8k(on_chip):
+    """Grouped-query attention of the LFM2 cell as the kernel sees it:
+    one row of 32 query heads (the 8 key heads repeated to them), 8,192
+    positions, heads of 64, float32, causal — BERT's head width at the
+    8k cells' length, a geometry no other cell has."""
+    q = _shape((1, 32, 8192, 64), F32, on_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "f32[32,16,512]" in text         # lse: 16 tiles of 512 a head
+    assert fa._vmem_limit(fa._bwd_vmem(8192, 512, 64, 64, "float32")) \
+        < 128 * 2 ** 20
+
+
+def test_the_whole_lfm2_step_fits_the_chip(on_chip, record_property):
+    """``SPMDTrainer``'s step of the LFM2 cell at its own sizes (507.8 M
+    trained parameters, four convolution layers and one of attention,
+    four expert layers, one sequence of 8,192, Adam, recomputation per
+    unit, the cross-entropy through the shared embedding in blocks of
+    rows) compiles for the described chip inside its memory."""
+    import mxtpu as mx
+    from chipbench import harness, models
+    from mxtpu.models.lfm2_moe import lfm2_moe_from_config
+    from mxtpu.ops import remat
+    from mxtpu.parallel import SPMDTrainer
+
+    cfg = harness.load_json(harness.HERE, "configs", "lfm2-8b-a1b.json")
+    net = lfm2_moe_from_config(
+        cfg, held=(cfg["held_experts_first"], cfg["num_experts"]),
+        num_experts_total=cfg["num_experts_total"], return_logits=False)
+    net.initialize(mx.init.Zero())
+    trainer = SPMDTrainer(
+        net, net.loss(), cfg["train"]["optimizer"],
+        models.one_chip_mesh(jax.devices()[:1]),
+        optimizer_params={"learning_rate": cfg["train"]["learning_rate"]},
+        remat=cfg["train"]["remat"])
+    trainer._stage_params()             # no eager forward: shapes are known
+    step = trainer._make_step_fns()[0]
+    like = lambda a: _shape(a.shape, a.dtype, on_chip)
+    scalar, tokens = _shape((), F32, on_chip), _shape((1, 8192), jnp.int32,
+                                                      on_chip)
+    args = [tuple(like(p.data()._data) for p in trainer._diff_params),
+            tuple(like(p.data()._data) for p in trainer._aux_params),
+            jax.tree_util.tree_map(like, tuple(trainer._opt_states)),
+            scalar, scalar, tokens, tokens,
+            _shape((2,), jnp.uint32, on_chip)]
+    assert sum(a.size for a in args[0]) == cfg["trained_parameters"]
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "ragged-dot" in text
+    # by ``_tile_rows`` an even load of 8,192 held pairs takes two tiles
+    # of 5,120 rows a layer
+    assert "f32[5120,1792]" in text and "f32[5120,2048]" in text
+    # the attention unit keeps flash's output and logsumexp, the other
+    # nine units their input alone
+    kept = remat.counts()
+    assert kept["kept_outputs"] == 2
+    assert kept["kept_bytes"] == 4 * (32 * 8192 * 64 + 32 * 8192)
+    m = compiled.memory_analysis()
+    bound = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    record_property("lfm2_step_code_bytes", m.generated_code_size_in_bytes)
+    record_property("lfm2_step_memory_bound_bytes", bound)
+    print("LFM2 step: code %.3f GB, arguments %.3f GB, temporaries %.3f GB, "
           "bound %.3f GB" % (m.generated_code_size_in_bytes / 1e9,
                              m.argument_size_in_bytes / 1e9,
                              m.temp_size_in_bytes / 1e9, bound / 1e9))

@@ -112,6 +112,54 @@ def test_softmax_routed_shares_add_up_to_the_uncut_layer(shares, total, k):
         rtol=1e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("eps", [1e-6, 0.25], ids=["published", "coarse"])
+def test_four_shares_of_8_sigmoid_experts_add_up_to_the_uncut_layer(eps):
+    """LFM2's layer (32 experts, 4 a token, sigmoid scores, a selection
+    bias for the choice only, renormalised with ``renorm_eps`` added to
+    the chosen scores' sum, scale 1, no shared expert): four ranks'
+    shares of 8 experts sum to what the plain reference
+    (chipbench/references/lfm2_moe.py) computes with all 32 held.  The
+    coarse case shows the argument is read: at 0.25 it moves the result
+    by a tenth."""
+    lfm2 = importlib.import_module("chipbench.references.lfm2_moe")
+    total, shares, k = 32, 4, 4
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    n = lambda key, *s: 0.2 * jax.random.normal(key, s)
+    w = {"router": n(ks[0], total, D), "experts_gate": n(ks[1], total, D, F),
+         "experts_up": n(ks[2], total, D, F),
+         "experts_down": n(ks[3], total, F, D)}
+    bias = 0.3 * jax.random.normal(ks[5], (total,))
+    x = jax.random.normal(ks[4], (2, 40, D))
+    held = total // shares
+
+    def summed(eps):
+        out, pairs = 0.0, 0
+        for first in range(0, total, held):
+            sl = slice(first, first + held)
+            y, load = moe.moe_expert_share(
+                x, w["router"], bias, w["experts_gate"][sl],
+                w["experts_up"][sl], w["experts_down"][sl],
+                held_first=first, top_k=k, scale=1, renorm_eps=eps)
+            out = out + y
+            pairs += int(np.asarray(load)[:-1].sum())
+        assert pairs == x.shape[0] * x.shape[1] * k         # none dropped
+        return np.asarray(out)
+
+    cfg = dict(num_experts=total, held_experts_first=0,
+               num_experts_per_tok=k, norm_topk_prob=True,
+               routed_scaling_factor=1)
+    saved = lfm2.RENORM_EPS
+    lfm2.RENORM_EPS = eps
+    try:
+        want = np.asarray(lfm2._expert_layer(cfg, w, "", x, bias, "highest"))
+    finally:
+        lfm2.RENORM_EPS = saved
+    np.testing.assert_allclose(summed(eps), want, rtol=1e-5, atol=2e-6)
+    if eps > 1e-3:
+        plain = summed(0.0)
+        assert 0.05 < np.abs(plain - want).max() / np.abs(want).max() < 0.3
+
+
 @pytest.mark.parametrize("tile", [4096, 48],
                          ids=["wide_tiles", "narrow_tiles"])
 def test_gradients_of_a_share_match_the_reference(monkeypatch, tile):

@@ -351,6 +351,7 @@ CASES.update({
     "rope": C(lambda: (A(2, 2, 4, 8),)),
     "mrope": C(lambda: (A(2, 2, 4, 8), IDX(3, 4, n=9)),
                {"sections": (1, 2, 1)}, grad_args=(0,)),
+    "gated_short_conv": C(lambda: (A(2, 5, 12), A(4, 3))),
     "smooth_l1_dup": None,  # placeholder removed below
     # -- nn ops ----------------------------------------------------------
     "FullyConnected": C(lambda: (A(3, 4), A(5, 4), A(5)),
