@@ -16,7 +16,8 @@ from .block import HybridBlock
 __all__ = ["Loss", "L2Loss", "L1Loss",
            "SigmoidBinaryCrossEntropyLoss", "SigmoidBCELoss",
            "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
-           "MultiTokenLoss", "IndexedAttentionLoss", "KLDivLoss", "CTCLoss",
+           "NextTokenLoss", "MultiTokenLoss", "IndexedAttentionLoss",
+           "KLDivLoss", "CTCLoss",
            "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
            "PoissonNLLLoss", "CosineEmbeddingLoss"]
@@ -150,6 +151,23 @@ def _head_cross_entropy(F, head, out, label):
         ce = -F.pick(F.log_softmax(out, axis=-1), label, axis=-1)
         return F.where(label >= 0, ce, F.zeros_like(ce))
     return F.linear_cross_entropy(out, head.weight.data(out.context), label)
+
+
+class NextTokenLoss(Loss):
+    """The next token's cross-entropy, a mean over the positions, of
+    logits (B, T, V) — or, with ``head`` (the ``Embedding`` the logits
+    come from, or an untied ``Dense`` head without a bias), of the head's
+    input (B, T, C), taken through it in blocks of rows
+    (``F.linear_cross_entropy``): the logits are never whole.  The
+    head's parameter stays the model's."""
+
+    def __init__(self, head=None, batch_axis=0, **kwargs):
+        super().__init__(None, batch_axis, **kwargs)
+        self._head = head
+
+    def hybrid_forward(self, F, out, label):
+        return F.mean(_head_cross_entropy(F, self._head, out, label),
+                      axis=self._batch_axis, exclude=True)
 
 
 class MultiTokenLoss(Loss):

@@ -17,6 +17,7 @@ from .transformer import (MultiHeadAttention, TransformerEncoderLayer,
                           bert_sharding_rules)
 from . import moe
 from .moe import SwitchMoE, MoEDecoderLayer, moe_sharding_rules
+from . import parts
 from . import kimi_linear
 from .kimi_linear import KimiLinearLM, kimi_linear_from_config
 from . import glm4_moe_lite

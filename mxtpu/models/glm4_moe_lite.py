@@ -32,20 +32,15 @@ the module's halves among them, is a unit of recomputation.
 
 from __future__ import annotations
 
-import weakref
-
-from ..base import MXTPUError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..gluon.loss import MultiTokenLoss
-from .kimi_linear import (ExpertShare, GatedMLP, LatentAttention, _dense,
-                          _Residual)
+from .parts import (ExpertShare, GatedMLP, LatentAttention, Residual,
+                    count_under, dense, read_counters)
 from .transformer import RMSNorm
 
 __all__ = ["PredictionModule", "Glm4MoeLiteLM", "glm4_moe_lite_from_config",
            "mtp_counts"]
-
-_MODULES = weakref.WeakSet()
 
 
 class PredictionModule(HybridBlock):
@@ -66,9 +61,9 @@ class PredictionModule(HybridBlock):
         with self.name_scope():
             self.enorm = RMSNorm(units, eps=eps, prefix="enorm_")
             self.hnorm = RMSNorm(units, eps=eps, prefix="hnorm_")
-            self.eh_proj = _dense(units, 2 * units, "eh_proj_")
-            self.mix = _Residual(units, mix, eps, prefix="mix_")
-            self.ffn = _Residual(units, ffn, eps, prefix="ffn_")
+            self.eh_proj = dense(units, 2 * units, "eh_proj_")
+            self.mix = Residual(units, mix, eps, prefix="mix_")
+            self.ffn = Residual(units, ffn, eps, prefix="ffn_")
             self.norm = RMSNorm(units, eps=eps, prefix="norm_")
             self.positions = self.params.get(
                 "positions", shape=(1,), init="zeros", grad_req="null",
@@ -77,7 +72,7 @@ class PredictionModule(HybridBlock):
                 "loss_sum", shape=(1,), init="zeros", grad_req="null")
             self.main_loss_sum = self.params.get(
                 "main_loss_sum", shape=(1,), init="zeros", grad_req="null")
-        _MODULES.add(self)
+        count_under("mtp", self)
 
     def cast(self, dtype):
         super().cast(dtype)
@@ -90,6 +85,13 @@ class PredictionModule(HybridBlock):
         x = self.eh_proj(F.concat(self.enorm(embedded_next),
                                   self.hnorm(hidden), dim=-1))
         return self.norm(self.ffn(self.mix(x)))
+
+    def counters(self):
+        """The three counters, to be summed over the modules."""
+        read = {name: getattr(self, name).data()._data.reshape(())
+                for name in ("positions", "loss_sum", "main_loss_sum")}
+        return {name: int(value) if name == "positions" else float(value)
+                for name, value in read.items()}, {}
 
     def count(self, positions, main_sum, mtp_sum):
         """Add one pass's positions and the two terms' sums."""
@@ -106,19 +108,8 @@ class PredictionModule(HybridBlock):
 
 def mtp_counts():
     """{"positions", "loss_sum", "main_loss_sum"} summed over every live
-    prediction module ({} when there is none): the ``mtp`` source of the
-    MetricsRegistry.  Reads three numbers a module from the device."""
-    out = {}
-    for module in list(_MODULES):
-        try:
-            read = {name: getattr(module, name).data()._data.reshape(())
-                    for name in ("positions", "loss_sum", "main_loss_sum")}
-        except MXTPUError:      # not initialised yet: nothing to report
-            continue
-        for name, value in read.items():
-            value = int(value) if name == "positions" else float(value)
-            out[name] = out.get(name, 0) + value
-    return out
+    prediction module ({} when there is none): the ``mtp`` counters."""
+    return read_counters("mtp")
 
 
 class Glm4MoeLiteLM(HybridBlock):
@@ -162,11 +153,11 @@ class Glm4MoeLiteLM(HybridBlock):
                 p = "layer%d_" % i
                 ff = GatedMLP(units, hidden_size, prefix=p + "mlp_") \
                     if i < num_dense else experts(p + "moe_")
-                self.layers.add(_Residual(units, attention(p + "mla_"), eps,
-                                          prefix=p + "mix_"))
-                self.layers.add(_Residual(units, ff, eps, prefix=p + "ffn_"))
+                self.layers.add(Residual(units, attention(p + "mla_"), eps,
+                                         prefix=p + "mix_"))
+                self.layers.add(Residual(units, ff, eps, prefix=p + "ffn_"))
             self.norm = RMSNorm(units, eps=eps, prefix="norm_")
-            self.lm_head = _dense(vocab_size, units, "lm_head_")
+            self.lm_head = dense(vocab_size, units, "lm_head_")
             self.mtp = PredictionModule(
                 units, attention("mtp_mla_"), experts("mtp_moe_"), eps,
                 prefix="mtp_") if num_nextn else None

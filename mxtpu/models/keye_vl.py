@@ -31,19 +31,15 @@ residual half of a layer is a unit of recomputation.
 from __future__ import annotations
 
 import math
-import weakref
 
-from ..base import MXTPUError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..gluon.loss import IndexedAttentionLoss
-from .kimi_linear import ExpertShare, _dense, _Residual
+from .parts import ExpertShare, Residual, count_under, dense, read_counters
 from .transformer import RMSNorm
 
 __all__ = ["IndexedAttention", "KeyeVLTextLM", "keye_vl_from_config",
            "dsa_counts"]
-
-_MIXERS = weakref.WeakSet()
 
 
 class IndexedAttention(HybridBlock):
@@ -66,27 +62,37 @@ class IndexedAttention(HybridBlock):
         self._top_k, self._base = top_k, rope_base
         self._sections = tuple(sections)
         with self.name_scope():
-            self.q_proj = _dense(num_heads * head_dim, units, "q_")
-            self.k_proj = _dense(num_kv_heads * head_dim, units, "k_")
-            self.v_proj = _dense(num_kv_heads * head_dim, units, "v_")
+            self.q_proj = dense(num_heads * head_dim, units, "q_")
+            self.k_proj = dense(num_kv_heads * head_dim, units, "k_")
+            self.v_proj = dense(num_kv_heads * head_dim, units, "v_")
             self.q_norm = RMSNorm(head_dim, eps=eps, prefix="q_norm_")
             self.k_norm = RMSNorm(head_dim, eps=eps, prefix="k_norm_")
-            self.out_proj = _dense(units, num_heads * head_dim, "out_")
-            self.index_q = _dense(index_heads * index_dim, units, "index_q_")
-            self.index_k = _dense(index_dim, units, "index_k_")
+            self.out_proj = dense(units, num_heads * head_dim, "out_")
+            self.index_q = dense(index_heads * index_dim, units, "index_q_")
+            self.index_k = dense(index_dim, units, "index_k_")
             self.index_k_norm = nn.LayerNorm(epsilon=eps,
                                              in_channels=index_dim,
                                              prefix="index_k_norm_")
-            self.index_w = _dense(index_heads, units, "index_w_")
+            self.index_w = dense(index_heads, units, "index_w_")
             for name in ("kept_mean", "kept_sum", "kl_sum"):
                 setattr(self, name, self.params.get(
                     name, shape=(1,), init="zeros", grad_req="null"))
-        _MIXERS.add(self)
+        count_under("dsa", self)
 
     def cast(self, dtype):
         super().cast(dtype)
         for name in ("kept_mean", "kept_sum", "kl_sum"):
             getattr(self, name).cast("float32")
+
+    def counters(self):
+        """The pairs kept and the indexer's loss since the start, to be
+        summed over the layers, and this layer's kept keys per query in
+        the newest pass."""
+        read = {name: float(getattr(self, name).data()._data.reshape(()))
+                for name in ("kept_mean", "kept_sum", "kl_sum")}
+        return ({"selected_pairs": read["kept_sum"],
+                 "kl_sum": read["kl_sum"]},
+                {self.prefix.rstrip("_") + ".kept_mean": read["kept_mean"]})
 
     def hybrid_forward(self, F, h, positions, kept_mean, kept_sum, kl_sum):
         from .. import autograd
@@ -130,40 +136,9 @@ class IndexedAttention(HybridBlock):
 
 def dsa_counts():
     """{"selected_pairs", "kl_sum", "<layer>.kept_mean"...} of every live
-    indexed-attention layer ({} when there is none): the pairs kept and
-    the indexers' loss summed since the start, and each layer's kept keys
-    per query in the newest pass.  The ``dsa`` source of the
-    MetricsRegistry; reads three numbers a layer from the device."""
-    out = {}
-    for mixer in list(_MIXERS):
-        try:
-            read = {name: float(getattr(mixer, name).data()._data.reshape(()))
-                    for name in ("kept_mean", "kept_sum", "kl_sum")}
-        except MXTPUError:      # not initialised yet: nothing to report
-            continue
-        out["selected_pairs"] = out.get("selected_pairs", 0.0) \
-            + read["kept_sum"]
-        out["kl_sum"] = out.get("kl_sum", 0.0) + read["kl_sum"]
-        out[mixer.prefix.rstrip("_") + ".kept_mean"] = read["kept_mean"]
-    return out
-
-
-class _IndexedResidual(HybridBlock):
-    """``x + y`` with (y, loss) = inner(norm(x), positions): the
-    attention half of a layer, and the unit of recomputation.  Returns
-    (x + y, loss)."""
-
-    remat_unit = True
-
-    def __init__(self, units, inner, eps, **kwargs):
-        super().__init__(**kwargs)
-        with self.name_scope():
-            self.norm = RMSNorm(units, eps=eps, prefix="norm_")
-            self.inner = inner
-
-    def hybrid_forward(self, F, x, positions):
-        y, loss = self.inner(self.norm(x), positions)
-        return x + y, loss
+    indexed-attention layer ({} when there is none): the ``dsa``
+    counters."""
+    return read_counters("dsa")
 
 
 class KeyeVLTextLM(HybridBlock):
@@ -199,12 +174,10 @@ class KeyeVLTextLM(HybridBlock):
                                   num_experts_total, experts_per_token, held,
                                   1.0, renormalize, 0, score="softmax",
                                   prefix=p + "moe_")
-                self.layers.add(_IndexedResidual(units, mix, eps,
-                                                 prefix=p + "mix_"))
-                self.layers.add(_Residual(units, ffn, eps,
-                                          prefix=p + "ffn_"))
+                self.layers.add(Residual(units, mix, eps, prefix=p + "mix_"))
+                self.layers.add(Residual(units, ffn, eps, prefix=p + "ffn_"))
             self.norm = RMSNorm(units, eps=eps, prefix="norm_")
-            self.lm_head = _dense(vocab_size, units, "lm_head_")
+            self.lm_head = dense(vocab_size, units, "lm_head_")
 
     def hybrid_forward(self, F, token_ids, positions=None):
         x = self.embed(token_ids)

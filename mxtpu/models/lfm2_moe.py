@@ -29,22 +29,17 @@ half of a layer is a unit of recomputation.
 
 from __future__ import annotations
 
-import weakref
-
-from ..base import MXTPUError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
-from ..gluon.loss import Loss, _head_cross_entropy
-from .kimi_linear import ExpertShare, GatedMLP, _dense, _Residual
+from ..gluon.loss import NextTokenLoss
+from .parts import (ExpertShare, GatedMLP, GroupedQueryAttention, Residual,
+                    count_under, dense, read_counters)
 from .transformer import RMSNorm
 
-__all__ = ["ShortConv", "GroupedQueryAttention", "TiedHeadLoss",
-           "Lfm2MoeLM", "lfm2_moe_from_config", "conv_counts"]
+__all__ = ["ShortConv", "Lfm2MoeLM", "lfm2_moe_from_config", "conv_counts"]
 
 #: added to the chosen experts' summed scores where they are renormalised
 RENORM_EPS = 1e-6
-
-_MIXERS = weakref.WeakSet()
 
 
 class ShortConv(HybridBlock):
@@ -59,18 +54,23 @@ class ShortConv(HybridBlock):
     def __init__(self, units, conv_size=3, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
-            self.in_proj = _dense(3 * units, units, "in_")
+            self.in_proj = dense(3 * units, units, "in_")
             self.conv = self.params.get("conv", shape=(units, conv_size),
                                         init="xavier")
-            self.out_proj = _dense(units, units, "out_")
+            self.out_proj = dense(units, units, "out_")
             self.positions = self.params.get(
                 "positions", shape=(1,), init="zeros", grad_req="null",
                 dtype="int32")
-        _MIXERS.add(self)
+        count_under("conv", self)
 
     def cast(self, dtype):
         super().cast(dtype)
         self.positions.cast("int32")            # a count stays a count
+
+    def counters(self):
+        """The positions counted since the start, to be summed over the
+        layers."""
+        return {"positions": int(self.positions.data()._data.reshape(()))}, {}
 
     def hybrid_forward(self, F, u, conv, positions):
         from .. import autograd
@@ -85,88 +85,8 @@ class ShortConv(HybridBlock):
 def conv_counts():
     """{"positions": the positions that went through a short
     convolution, summed over every live ``ShortConv`` and since the
-    start} ({} when there is none): the ``conv`` source of the
-    MetricsRegistry.  Reads one number a layer from the device."""
-    out = {}
-    for mixer in list(_MIXERS):
-        try:
-            read = int(mixer.positions.data()._data.reshape(()))
-        except MXTPUError:      # not initialised yet: nothing to report
-            continue
-        out["positions"] = out.get("positions", 0) + read
-    return out
-
-
-class GroupedQueryAttention(HybridBlock):
-    """Causal attention in which each of ``num_kv_heads`` key heads
-    serves ``num_heads / num_kv_heads`` query heads.  With
-    ``head_norms`` (LFM2's) q and k pass an RMSNorm over a head's
-    columns, with a gain; with a ``rope_base`` they are then rotated by
-    their position over the whole head (``F.rope``: a column pairs with
-    the one half a head on), without one they carry no position (NoPE).
-    With a ``window`` query t attends only the keys s with t - window <
-    s <= t (``F.flash_attention(window=)``).  No bias.  The flash kernel
-    takes the keys and values repeated to the query heads (as
-    ``ops/dsa.py`` does)."""
-
-    def __init__(self, units, num_heads, num_kv_heads, head_dim, *,
-                 rope_base=10000.0, eps=1e-5, head_norms=True, window=None,
-                 **kwargs):
-        super().__init__(**kwargs)
-        if num_heads % num_kv_heads:
-            raise ValueError("%d query heads are no multiple of %d key "
-                             "heads" % (num_heads, num_kv_heads))
-        self._heads, self._kv_heads, self._dim = (num_heads, num_kv_heads,
-                                                  head_dim)
-        self._base, self._window = rope_base, window
-        with self.name_scope():
-            self.q_proj = _dense(num_heads * head_dim, units, "q_")
-            self.k_proj = _dense(num_kv_heads * head_dim, units, "k_")
-            self.v_proj = _dense(num_kv_heads * head_dim, units, "v_")
-            if head_norms:
-                self.q_norm = RMSNorm(head_dim, eps=eps, prefix="q_norm_")
-                self.k_norm = RMSNorm(head_dim, eps=eps, prefix="k_norm_")
-            else:
-                self.q_norm = self.k_norm = None
-            self.out_proj = _dense(units, num_heads * head_dim, "out_")
-
-    def hybrid_forward(self, F, u):
-        B, T, _ = u.shape
-        H, G, D = self._heads, self._kv_heads, self._dim
-
-        def heads(x, n, norm=None):
-            x = x.reshape((B, T, n, D))
-            if norm is not None:
-                x = norm(x)
-            return x.transpose((0, 2, 1, 3))
-
-        def turned(x):
-            return x if self._base is None else F.rope(x, base=self._base)
-
-        q = turned(heads(self.q_proj(u), H, self.q_norm))
-        k = turned(heads(self.k_proj(u), G, self.k_norm))
-        v = heads(self.v_proj(u), G)
-        if H != G:
-            k, v = (F.repeat(a, repeats=H // G, axis=1) for a in (k, v))
-        o = F.flash_attention(q, k, v, causal=True, window=self._window)
-        return self.out_proj(o.transpose((0, 2, 1, 3)).reshape((B, T, -1)))
-
-
-class TiedHeadLoss(Loss):
-    """The next token's cross-entropy, a mean over the positions, of
-    logits (B, T, V) — or, with ``head`` (the ``Embedding`` the logits
-    come from, or an untied ``Dense`` head without a bias), of the head's
-    input (B, T, C), taken through it in blocks of rows
-    (``F.linear_cross_entropy``): the logits are never whole.  The
-    head's parameter stays the model's."""
-
-    def __init__(self, head=None, batch_axis=0, **kwargs):
-        super().__init__(None, batch_axis, **kwargs)
-        self._head = head
-
-    def hybrid_forward(self, F, out, label):
-        return F.mean(_head_cross_entropy(F, self._head, out, label),
-                      axis=self._batch_axis, exclude=True)
+    start} ({} when there is none): the ``conv`` counters."""
+    return read_counters("conv")
 
 
 class Lfm2MoeLM(HybridBlock):
@@ -207,9 +127,8 @@ class Lfm2MoeLM(HybridBlock):
                         units, expert_hidden_size, num_experts_total, top_k,
                         held, routed_scale, renormalize, 0, score="sigmoid",
                         renorm_eps=RENORM_EPS, prefix=p + "moe_")
-                self.layers.add(_Residual(units, mix, eps,
-                                          prefix=p + "mix_"))
-                self.layers.add(_Residual(units, ff, eps, prefix=p + "ffn_"))
+                self.layers.add(Residual(units, mix, eps, prefix=p + "mix_"))
+                self.layers.add(Residual(units, ff, eps, prefix=p + "ffn_"))
             self.norm = RMSNorm(units, eps=eps, prefix="norm_")
 
     def hybrid_forward(self, F, token_ids):
@@ -230,7 +149,7 @@ class Lfm2MoeLM(HybridBlock):
     def loss(self):
         """The next token's cross-entropy of whichever this model
         returns (logits, or the head's input)."""
-        return TiedHeadLoss(None if self._return_logits else self.embed)
+        return NextTokenLoss(None if self._return_logits else self.embed)
 
 
 def lfm2_moe_from_config(cfg, held=None, num_experts_total=None, **kwargs):

@@ -35,8 +35,8 @@ from __future__ import annotations
 
 from ..gluon import nn
 from ..gluon.block import HybridBlock
-from .kimi_linear import ExpertShare, _dense
-from .lfm2_moe import GroupedQueryAttention, TiedHeadLoss
+from ..gluon.loss import NextTokenLoss
+from .parts import ExpertShare, GroupedQueryAttention, dense
 from .transformer import RMSNorm
 
 __all__ = ["SmallThinkerLayer", "SmallThinkerLM", "smallthinker_from_config",
@@ -110,7 +110,7 @@ class SmallThinkerLM(HybridBlock):
                 self.layers.add(SmallThinkerLayer(units, attn, moe, eps,
                                                   prefix=p))
             self.norm = RMSNorm(units, eps=eps, prefix="norm_")
-            self.lm_head = _dense(vocab_size, units, "lm_head_")
+            self.lm_head = dense(vocab_size, units, "lm_head_")
 
     def hybrid_forward(self, F, token_ids):
         x = self.embed(token_ids)
@@ -122,7 +122,7 @@ class SmallThinkerLM(HybridBlock):
     def loss(self):
         """The next token's cross-entropy of whichever this model
         returns (logits, or the head's input)."""
-        return TiedHeadLoss(None if self._return_logits else self.lm_head)
+        return NextTokenLoss(None if self._return_logits else self.lm_head)
 
 
 def smallthinker_from_config(cfg, held=None, num_experts_total=None,

@@ -43,22 +43,18 @@ source              pulls
 ``moe``             load of every live expert layer that holds a share
                     of the experts (``moe.<layer>.held.<i>``,
                     ``.elsewhere``, ``.held_sum.<i>``,
-                    ``.elsewhere_sum`` — models/kimi_linear.py
-                    ``expert_loads``; reads a few numbers a layer
-                    from the device)
+                    ``.elsewhere_sum`` — ``ExpertShare``)
 ``mtp``             the multi-token-prediction module's counters
                     (``mtp.positions``, ``mtp.loss_sum``,
-                    ``mtp.main_loss_sum`` — models/glm4_moe_lite.py
-                    ``mtp_counts``; three numbers from the device)
+                    ``mtp.main_loss_sum`` — ``PredictionModule``)
 ``dsa``             the indexed-attention layers' counters
                     (``dsa.selected_pairs``, ``dsa.kl_sum``,
-                    ``dsa.<layer>.kept_mean`` — models/keye_vl.py
-                    ``dsa_counts``; three numbers a layer from the
-                    device)
+                    ``dsa.<layer>.kept_mean`` — ``IndexedAttention``)
 ``conv``            the gated short convolutions' counter
-                    (``conv.positions`` — models/lfm2_moe.py
-                    ``conv_counts``; one number a layer from the
-                    device)
+                    (``conv.positions`` — ``ShortConv``); these four
+                    read a few numbers a block from the device, from
+                    the one registry of models/parts.py
+                    (``read_counters``)
 ==================  ====================================================
 
 Live objects (engines, gateways, supervisors, routers) register with
@@ -74,6 +70,7 @@ records cite registry deltas as evidence instead of wall clocks.
 
 from __future__ import annotations
 
+import functools
 import json
 import re
 from typing import Any, Callable, Dict, Iterable, List, Optional
@@ -271,47 +268,24 @@ def _src_remat() -> dict:
     return remat.counts()
 
 
-def _src_moe() -> dict:
-    """Load of every live expert layer that holds a share of the experts
-    (``moe.<layer>.held`` — pairs each held expert received in the
-    newest forward pass —, ``.elsewhere``, and their running sums
-    ``.held_sum`` / ``.elsewhere_sum``): kept on the device by the layer,
-    read here (models/kimi_linear.py ``expert_loads``)."""
-    from ..models.kimi_linear import expert_loads
-    return {layer: {name: {str(i): x for i, x in enumerate(v)}
-                    if isinstance(v, list) else v
-                    for name, v in load.items()}
-            for layer, load in expert_loads().items()}
+#: the sources of the counters that the models' blocks keep on the
+#: device and the training step hands back (models/parts.py)
+_COUNTED_SOURCES = ("moe", "mtp", "dsa", "conv")
 
 
-def _src_mtp() -> dict:
-    """The prediction modules' counters since their start:
-    ``mtp.positions`` that entered the module's loss, and the sums of
-    the module's and the main cross-entropies (``mtp.loss_sum``,
-    ``mtp.main_loss_sum``): a program that drops the second loss is seen
-    without a reference (models/glm4_moe_lite.py ``mtp_counts``)."""
-    from ..models.glm4_moe_lite import mtp_counts
-    return mtp_counts()
+def _src_counted(source: str) -> dict:
+    """Every live block counted under ``source``, read now
+    (models/parts.py ``read_counters``; module docstring for the keys),
+    a list's entries keyed by their index."""
+    from ..models.parts import read_counters
 
-
-def _src_dsa() -> dict:
-    """The indexed-attention layers' counters: ``dsa.selected_pairs``
-    (query, key) pairs the indexers' selections kept and ``dsa.kl_sum``
-    the indexers' loss, both summed over the layers since their start,
-    and ``dsa.<layer>.kept_mean`` the keys a query kept in that layer's
-    newest pass: a program that drops the selection counts every causal
-    pair (models/keye_vl.py ``dsa_counts``)."""
-    from ..models.keye_vl import dsa_counts
-    return dsa_counts()
-
-
-def _src_conv() -> dict:
-    """The gated short convolutions' counter: ``conv.positions`` that
-    went through a ``ShortConv`` mixer, summed over the layers since
-    their start: a program that drops the convolution counts none
-    (models/lfm2_moe.py ``conv_counts``)."""
-    from ..models.lfm2_moe import conv_counts
-    return conv_counts()
+    def keyed(value):
+        if isinstance(value, dict):
+            return {k: keyed(v) for k, v in value.items()}
+        if isinstance(value, list):
+            return {str(i): x for i, x in enumerate(value)}
+        return value
+    return keyed(read_counters(source))
 
 
 def default_registry() -> MetricsRegistry:
@@ -327,10 +301,8 @@ def default_registry() -> MetricsRegistry:
     reg.register_source("kernel_invocations", _src_kernel_invocations)
     reg.register_source("remat", _src_remat)
     reg.register_source("lifecycle", _src_lifecycle)
-    reg.register_source("moe", _src_moe)
-    reg.register_source("mtp", _src_mtp)
-    reg.register_source("dsa", _src_dsa)
-    reg.register_source("conv", _src_conv)
+    for source in _COUNTED_SOURCES:
+        reg.register_source(source, functools.partial(_src_counted, source))
     return reg
 
 

@@ -56,6 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...base import register_op
 from ..remat import keep
+from ..short_conv import causal_short_conv
 from . import counters
 
 __all__ = ["kda", "kda_mixer", "kernel_specs"]
@@ -551,14 +552,6 @@ def kda(q, k, v, g, beta, chunk=CHUNK):
     return keep(o.astype(v.dtype))
 
 
-def _short_conv(x, filt):
-    """Causal depthwise convolution over time: x (B, T, heads, K), filt
-    (heads, K, W), one filter a channel, left-padded with zeros."""
-    W, T = filt.shape[-1], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0), (0, 0)))
-    return sum(padded[:, j:j + T] * filt[..., j] for j in range(W))
-
-
 def _l2_normalize(x, eps):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
 
@@ -591,7 +584,7 @@ def kda_mixer(q, k, v, f, gate, beta, q_conv, k_conv, v_conv, a_log,
     def heads(seqs, per_head):
         q, k, v, f, gate, beta = seqs
         q_conv, k_conv, v_conv, a_log, dt_bias = per_head
-        q, k, v = (jax.nn.silu(_short_conv(x, w)) for x, w in
+        q, k, v = (jax.nn.silu(causal_short_conv(x, w)) for x, w in
                    ((q, q_conv), (k, k_conv), (v, v_conv)))
         q = _l2_normalize(q, l2_eps) * K ** -0.5
         k = _l2_normalize(k, l2_eps)

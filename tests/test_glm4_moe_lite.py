@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 import mxtpu as mx
 from mxtpu import gluon
-from mxtpu.models import glm4_moe_lite, kimi_linear
+from mxtpu.models import glm4_moe_lite, kimi_linear, parts
 from mxtpu.observability.metrics import get_registry
 from mxtpu.parallel import SPMDTrainer, make_mesh
 
@@ -238,7 +238,7 @@ def _attention_pair(**cfg_more):
          if kind == "matrix" else
          (1 + 0.1 * rng.standard_normal(s)).astype("float32")
          for k, (s, kind) in shapes.items()}
-    mixer = kimi_linear.LatentAttention(
+    mixer = parts.LatentAttention(
         cfg["hidden_size"], cfg["num_attention_heads"], cfg["kv_lora_rank"],
         cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"], cfg["v_head_dim"],
         cfg["rms_norm_eps"], q_rank=cfg["q_lora_rank"],
@@ -302,7 +302,7 @@ def test_the_query_is_low_rank_with_a_norm_of_its_own():
     assert mixer.q_b_proj.weight.shape == (heads, cfg["q_lora_rank"])
     assert not hasattr(mixer, "q_proj")
     # and the full-rank, position-free form is as it was
-    plain = kimi_linear.LatentAttention(48, 2, 16, 16, 8, 24)
+    plain = parts.LatentAttention(48, 2, 16, 16, 8, 24)
     assert plain.q_proj.weight.shape == (heads, 48)
     assert not hasattr(plain, "q_a_proj")
 
@@ -331,7 +331,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
     total = shared = None
     for first in range(0, E, held):
-        layer = kimi_linear.ExpertShare(
+        layer = parts.ExpertShare(
             C, F, E, CFG["num_experts_per_tok"], held=(first, held),
             routed_scale=CFG["routed_scaling_factor"],
             renormalize=CFG["norm_topk_prob"],
@@ -413,9 +413,9 @@ def test_from_config_builds_the_share_and_refuses_group_limits():
     net = glm4_moe_lite.glm4_moe_lite_from_config(
         CFG, held=(4, 4), num_experts_total=16)
     assert net.num_layers == 3 and net.num_dense == 1
-    assert isinstance(net.decoder_layer(0)[1].inner, kimi_linear.GatedMLP)
+    assert isinstance(net.decoder_layer(0)[1].inner, parts.GatedMLP)
     assert isinstance(net.decoder_layer(1)[1].inner,
-                      kimi_linear.ExpertShare)
+                      parts.ExpertShare)
     assert net.mtp.eh_proj.weight.shape == (48, 96)
     with pytest.raises(ValueError, match="group"):
         glm4_moe_lite.glm4_moe_lite_from_config(dict(CFG, n_group=2))

@@ -1,7 +1,7 @@
 """The three losses that take a head's cross-entropy through
 ``F.linear_cross_entropy`` — ``MultiTokenLoss`` (GLM-4.7-Flash, two
 heads on one weight), ``IndexedAttentionLoss`` (Keye-VL) and
-``TiedHeadLoss`` (LFM2) — in ``SPMDTrainer``'s step at toy widths: the
+``NextTokenLoss`` (LFM2) — in ``SPMDTrainer``'s step at toy widths: the
 cotangent that reaches the op is the same on every row with a target,
 so the backward pass scales the gradient the forward formed; the step
 is the whole logits' step; the trace counts the heads whose forward

@@ -20,8 +20,8 @@ import jax
 import jax.numpy as jnp
 
 import mxtpu as mx
-from mxtpu.models import lfm2_moe
-from mxtpu.models.kimi_linear import ExpertShare, GatedMLP
+from mxtpu.models import lfm2_moe, parts
+from mxtpu.models.parts import ExpertShare, GatedMLP
 from mxtpu.observability.metrics import get_registry
 from mxtpu.ops.pallas import counters
 from mxtpu.ops.short_conv import gated_short_conv
@@ -270,7 +270,7 @@ def test_the_attentions_head_norms_and_rotation_match_the_reference():
          for k, (n, s) in zip(ks, shapes.items())}
     u = jax.random.normal(ks[6], (2, 33, C))
     want = ref._attention(cfg, w, "l.", u, "highest")
-    block = lfm2_moe.GroupedQueryAttention(
+    block = parts.GroupedQueryAttention(
         C, A, G, D, rope_base=cfg["rope_theta"], eps=cfg["norm_eps"])
     block.initialize(mx.init.Zero())
     for name in shapes:
@@ -287,7 +287,7 @@ def test_the_attentions_head_norms_and_rotation_match_the_reference():
     assert np.abs(np.asarray(ref._attention(still, w, "l.", u, "highest"))
                   - got).max() > 1e-3
     with pytest.raises(ValueError, match="no multiple"):
-        lfm2_moe.GroupedQueryAttention(C, 4, 3, D)
+        parts.GroupedQueryAttention(C, 4, 3, D)
 
 
 def test_from_config_builds_the_kinds_from_the_lists():
@@ -304,7 +304,7 @@ def test_from_config_builds_the_kinds_from_the_lists():
         for i, kind in enumerate(cfg["layer_types"]):
             mix, ff = (half.inner for half in net.decoder_layer(i))
             assert isinstance(mix, lfm2_moe.ShortConv if kind == "conv"
-                              else lfm2_moe.GroupedQueryAttention)
+                              else parts.GroupedQueryAttention)
             assert isinstance(ff, GatedMLP if i < cfg["num_dense_layers"]
                               else ExpertShare)
             if isinstance(ff, ExpertShare):

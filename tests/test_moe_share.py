@@ -220,7 +220,8 @@ def test_the_selection_bias_chooses_and_takes_no_gradient():
 
 
 def test_expert_share_block_counts_its_load_and_rejects_a_bad_share():
-    from mxtpu.models.kimi_linear import ExpertShare, expert_loads
+    from mxtpu.models.kimi_linear import expert_loads
+    from mxtpu.models.parts import ExpertShare
 
     layer = ExpertShare(D, F, E, K, held=(4, 4), routed_scale=SCALE,
                         prefix="probe_moe_")

@@ -441,14 +441,15 @@ def _toy_step_text(model):
     builds it."""
     import importlib
     from chipbench import harness, models as bench_models, models_glm, \
-        models_keye, models_lfm2, models_lm
+        models_keye, models_lfm2, models_lm, models_smallthinker
 
     config, reference, seq = {
         "bert": ("bert-tiny.json", "bert", 128),
         "kimi_linear": ("kimi-linear-tiny.json", "kimi_linear", 40),
         "glm4_moe_lite": ("glm-tiny.json", "glm4_moe_lite", 40),
         "keye_vl": ("keye-tiny.json", "keye_vl", 40),
-        "lfm2_moe": ("lfm2-tiny.json", "lfm2_moe", 40)}[model]
+        "lfm2_moe": ("lfm2-tiny.json", "lfm2_moe", 40),
+        "smallthinker": ("smallthinker-tiny.json", "smallthinker", 40)}[model]
     cfg = harness.load_json(harness.HERE, "tests", "configs", config)
     ref = importlib.import_module("chipbench.references." + reference)
     tokens = mx.nd.array(np.random.default_rng(0).integers(
@@ -464,7 +465,9 @@ def _toy_step_text(model):
     else:
         build = {"kimi_linear": models_lm.kimi_linear_trainer,
                  "glm4_moe_lite": models_glm.glm4_moe_lite_trainer,
-                 "lfm2_moe": models_lfm2.lfm2_moe_trainer}[model]
+                 "lfm2_moe": models_lfm2.lfm2_moe_trainer,
+                 "smallthinker": models_smallthinker.smallthinker_trainer,
+                 }[model]
         trainer, _ = build(cfg, train, weights, ref.selection_bias(cfg),
                            devices)
     return trainer.lower_step(tokens, tokens).as_text()
@@ -481,6 +484,8 @@ def _toy_step_text(model):
      "df0622d00606045d5b379d2d29408f05ea5a4fd7a4f35a51fdbce85ac86e74f3"),
     ("lfm2_moe", 6, 2,
      "fc24031d2c4acb975eb5ba7346ab6f18dd8ed6777ec1a0b5d566502edfa3130e"),
+    ("smallthinker", 30, 2,
+     "2077640151fc6131b19258495151189eb12f67e7ac60fe6b9b53fa3e439d193a"),
 ])
 def test_the_accepted_models_steps_lower_as_before_the_kept_keys(
         model, whiles, sorts, parent):
@@ -513,7 +518,9 @@ def test_the_accepted_models_steps_lower_as_before_the_kept_keys(
     product turned they are 296b929's to the byte.  LFM2's toy heads of 12 take the dense path,
     and its digest stands.  The cells' heads of 128 and wider keep the
     parent's kernel
-    (``test_the_flash_backward_at_wide_heads_lowers_as_before``)."""
+    (``test_the_flash_backward_at_wide_heads_lowers_as_before``).
+    SmallThinker's digest is of commit d0c7d64, taken the same way, before
+    the decoders' shared parts moved into ``models/parts.py``."""
     import hashlib
     import re
 

@@ -18,8 +18,7 @@ import jax.numpy as jnp
 
 import mxtpu as mx
 from mxtpu.models import smallthinker
-from mxtpu.models.kimi_linear import ExpertShare
-from mxtpu.models.lfm2_moe import GroupedQueryAttention
+from mxtpu.models.parts import ExpertShare, GroupedQueryAttention
 from mxtpu.ops import moe
 
 from chipbench import harness, models, models_smallthinker
